@@ -842,10 +842,13 @@ class API:
     def info(self) -> dict:
         import jax
 
+        from pilosa_tpu import native, platform
         from pilosa_tpu.shardwidth import SHARD_WIDTH
 
         return {
             "shardWidth": SHARD_WIDTH,
-            "devices": [str(d) for d in jax.devices()],
+            **platform.device_facts(),
+            "compileCacheDir": jax.config.jax_compilation_cache_dir,
+            "native": native.available(),
             "indexes": sorted(self.holder.indexes),
         }

@@ -1,7 +1,7 @@
 """Version-keyed query result cache with single-flight dedup.
 
-Every read-path p50 sits on the ~67ms per-dispatch floor (BENCH_r05);
-repeated reads of unchanged fragments can skip the device entirely.
+Every read pays at least one dispatch and one result fetch; repeated
+reads of unchanged fragments can skip the device entirely.
 Entries are keyed on (index, canonical PQL, frozen shard set, fragment
 version fingerprint) so writes self-invalidate them — see keys.py for
 the key scheme and result_cache.py for the LRU + single-flight core.

@@ -11,6 +11,7 @@ from __future__ import annotations
 
 import dataclasses
 import os
+import tomllib
 from typing import Any, Dict, List, Optional
 
 _ENV_PREFIX = "PILOSA_TPU_"
@@ -28,44 +29,6 @@ def env_bool(name: str, default: bool = False) -> bool:
 
     raw = os.environ.get(name)
     return default if raw is None else _truthy(raw)
-
-
-def _toml_value(val: str):
-    if val.startswith("[") and val.endswith("]"):
-        inner = val[1:-1].strip()
-        return [_toml_value(p.strip()) for p in inner.split(",")
-                if p.strip()] if inner else []
-    if len(val) >= 2 and val[0] == val[-1] and val[0] in ("'", '"'):
-        return val[1:-1]
-    if val in ("true", "false"):
-        return val == "true"
-    for conv in (int, float):
-        try:
-            return conv(val)
-        except ValueError:
-            pass
-    return val
-
-
-def _parse_toml_subset(text: str) -> Dict[str, Any]:
-    """Minimal TOML reader for Pythons without stdlib tomllib (< 3.11):
-    [section] headers, key = string / int / float / bool /
-    array-of-strings, full-line # comments — the dialect ``to_toml``
-    emits and the docs use. Real tomllib is preferred when present."""
-    doc: Dict[str, Any] = {}
-    cur = doc
-    for raw in text.splitlines():
-        line = raw.strip()
-        if not line or line.startswith("#"):
-            continue
-        if line.startswith("[") and line.endswith("]"):
-            cur = doc.setdefault(line[1:-1].strip(), {})
-            continue
-        key, sep, val = line.partition("=")
-        if not sep:
-            raise ValueError(f"unparsable config line: {raw!r}")
-        cur[key.strip()] = _toml_value(val.strip())
-    return doc
 
 
 @dataclasses.dataclass
@@ -327,36 +290,21 @@ class Config:
 
     @staticmethod
     def _load_toml(path: str) -> Dict[str, Any]:
-        try:
-            import tomllib
-        except ModuleNotFoundError:  # Python < 3.11: stdlib has no tomllib
-            tomllib = None
-        if tomllib is not None:
-            with open(path, "rb") as f:
-                doc = tomllib.load(f)
-        else:
-            with open(path, encoding="utf-8") as f:
-                doc = _parse_toml_subset(f.read())
-        # [section] key -> section_key; dotted sections nest with real
-        # tomllib ([cluster.resilience] -> {"cluster": {"resilience":
-        # ...}}) but stay dotted flat keys in the subset parser — both
+        with open(path, "rb") as f:
+            doc = tomllib.load(f)
+        # [section] key -> section_key; dotted sections nest
+        # ([cluster.resilience] -> {"cluster": {"resilience": ...}}) and
         # flatten to cluster_resilience_*
         flat: Dict[str, Any] = {}
 
         # [tenants.<id>] stanzas are per-tenant override MAPS, not
-        # scalar config fields — lift them out before flattening (real
-        # tomllib nests them under "tenants"; the subset parser keeps
-        # the dotted header as a flat "tenants.<id>" key)
+        # scalar config fields — lift them out before flattening
         overrides: Dict[str, Dict[str, Any]] = {}
         tsec = doc.get("tenants")
         if isinstance(tsec, dict):
             for k in [k for k, v in tsec.items() if isinstance(v, dict)]:
                 overrides[k] = {ik.replace("-", "_"): iv
                                 for ik, iv in tsec.pop(k).items()}
-        for k in [k for k in doc if k.startswith("tenants.")
-                  and isinstance(doc[k], dict)]:
-            overrides[k[len("tenants."):]] = {
-                ik.replace("-", "_"): iv for ik, iv in doc.pop(k).items()}
 
         def _flatten(prefix: str, d: Dict[str, Any]) -> None:
             for k, v in d.items():

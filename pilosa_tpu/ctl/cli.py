@@ -33,12 +33,17 @@ def cmd_server(args) -> int:
         "bind": args.bind, "port": args.port, "data_dir": args.data_dir,
         "wal_sync": args.wal_sync,
     })
+    from pilosa_tpu import platform
     from pilosa_tpu.api import API
     from pilosa_tpu.server.http import serve
 
     from pilosa_tpu.obs.logger import configure as configure_logging
 
     configure_logging(cfg.log_level, cfg.log_path or None)
+    platform.configure_compile_cache()
+    # resolve the backend before the listener opens: a server that
+    # cannot reach its device fails here, not on the first query
+    dev = platform.device_facts()
     api = API(cfg.data_dir or None, wal_sync=cfg.wal_sync,
               segment_bytes=cfg.storage_recovery_segment_bytes)
     # [storage.recovery] checkpoint interval wins when set; the legacy
@@ -72,7 +77,9 @@ def cmd_server(args) -> int:
                     allowed_networks=cfg.auth_allowed_networks,
                     secure_cookies=cfg.auth_secure_cookies)
     print(f"pilosa-tpu serving on {cfg.bind}:{cfg.port} "
-          f"(data-dir={cfg.data_dir or '<memory>'}"
+          f"(platform={dev['platform']}, device-kind={dev['deviceKind']}, "
+          f"devices={len(dev['devices'])}, "
+          f"data-dir={cfg.data_dir or '<memory>'}"
           f"{', auth on' if auth else ''})", file=sys.stderr)
     serve(api, host=cfg.bind, port=cfg.port,
           maintenance_interval_s=cfg.ttl_removal_interval_s, auth=auth)
@@ -176,8 +183,11 @@ def cmd_datagen(args) -> int:
 
     src = scenario(args.scenario, rows=args.rows, seed=args.seed)
     if not args.host:
+        from pilosa_tpu import platform
         from pilosa_tpu.api import API
         from pilosa_tpu.ingest.ingest import Ingester
+
+        platform.configure_compile_cache()
 
         n = Ingester(API(), args.index, src).run()
         print(f"datagen: ingested {n} {args.scenario!r} records "

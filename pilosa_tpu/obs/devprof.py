@@ -76,19 +76,20 @@ ENABLED = env_bool("PILOSA_TPU_DEVPROF", False)
 WORD_BYTES = 4   # planes are uint32 words
 BIT_LANES = 32   # one uint32 bitwise op = 32 bit-ops ("flops" here)
 
-#: Per-backend (peak bit-op TFLOPS, peak HBM GB/s). The TPU row is the
-#: v5e figure bench config 3 already normalizes against; CPU is an
-#: order-of-magnitude host default (MFU on CPU is a relative gauge, not
-#: a datasheet claim). Override per deployment with
+#: (peak int8 TOP/s, peak HBM GB/s) keyed by ``device_kind`` exactly as
+#: JAX reports it. "TPU v5 lite" is one TPU v5e chip: 393 TOP/s int8,
+#: 819 GB/s of HBM (Google Cloud documentation, "TPU v5e"). ``cpu`` is an
+#: order-of-magnitude host figure: MFU on CPU is a relative gauge for the
+#: tests, not a datasheet claim. A device that is not in the table is an
+#: error, not a default — read its kind on the chip and add the row with
+#: its source. Override per deployment with
 #: PILOSA_TPU_DEVPROF_PEAK_TFLOPS / PILOSA_TPU_DEVPROF_PEAK_GBPS.
 PEAK_TABLE: Dict[str, Tuple[float, float]] = {
-    "tpu": (394.0, 819.0),
-    "gpu": (312.0, 2039.0),
+    "TPU v5 lite": (393.0, 819.0),
     "cpu": (0.5, 25.0),
 }
-_DEFAULT_PEAK = (1.0, 25.0)
 
-_BACKEND: Optional[str] = None
+_DEVICE_KIND: Optional[str] = None
 
 # Cost-model evaluation counter: the "exactly zero cost-model work when
 # disabled" gates (bench --configs 16, tier1 devprof lane) snapshot it.
@@ -101,24 +102,32 @@ _TLS = threading.local()
 NULL_SCOPE = contextlib.nullcontext()
 
 
-def backend_name() -> str:
-    """Active JAX backend, resolved lazily and cached (jax must not be
-    imported just because devprof was)."""
-    global _BACKEND
-    if _BACKEND is None:
-        try:
-            import jax
+def device_kind() -> str:
+    """``device_kind`` of the device this process computes on, resolved
+    lazily and cached (jax must not be imported just because devprof
+    was)."""
+    global _DEVICE_KIND
+    if _DEVICE_KIND is None:
+        import jax
 
-            _BACKEND = jax.default_backend()
-        except Exception:
-            _BACKEND = "cpu"
-    return _BACKEND
+        _DEVICE_KIND = jax.devices()[0].device_kind
+    return _DEVICE_KIND
+
+
+def peaks_for(kind: str) -> Tuple[float, float]:
+    """The table row for ``kind``; an unknown device raises."""
+    try:
+        return PEAK_TABLE[kind]
+    except KeyError:
+        raise LookupError(
+            f"devprof has no peak figures for device kind {kind!r}; add "
+            f"its row (with the source) to PEAK_TABLE") from None
 
 
 def peaks() -> Tuple[float, float]:
-    """(peak bit-op TFLOPS, peak HBM GB/s) for the active backend with
-    env overrides applied."""
-    tf, gb = PEAK_TABLE.get(backend_name(), _DEFAULT_PEAK)
+    """(peak int8 TOP/s, peak HBM GB/s) for the active device with env
+    overrides applied."""
+    tf, gb = peaks_for(device_kind())
     try:
         tf = float(os.environ.get("PILOSA_TPU_DEVPROF_PEAK_TFLOPS", tf))
         gb = float(os.environ.get("PILOSA_TPU_DEVPROF_PEAK_GBPS", gb))
@@ -530,7 +539,7 @@ def stats_json() -> dict:
     peak_tf, peak_gb = peaks()
     return {
         "enabled": bool(ENABLED),
-        "backend": backend_name(),
+        "device_kind": device_kind(),
         "peak_tflops": peak_tf,
         "peak_gbps": peak_gb,
         "ridge_flops_per_byte": round((peak_tf * 1e12) / (peak_gb * 1e9),

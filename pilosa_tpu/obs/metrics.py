@@ -86,8 +86,8 @@ METRIC_CACHE_ENTRIES = "cache_entries"
 METRIC_CACHE_BYTES = "cache_resident_bytes"
 METRIC_CACHE_HIT_LATENCY = "cache_hit_seconds"  # histogram
 METRIC_CACHE_DISPATCH_LATENCY = "cache_dispatch_seconds"  # histogram
-# hit path is sub-ms; dispatch path sits at the ~67ms device floor —
-# one bucket layout spans both so the two histograms compare directly
+# hit path is sub-ms; the dispatch path pays a device round trip — one
+# bucket layout spans both so the two histograms compare directly
 CACHE_LATENCY_BUCKETS = (0.0005, 0.001, 0.005, 0.01, 0.025, 0.05,
                          0.1, 0.25, 1.0)
 # cluster fan-out resilience (cluster/resilience.py): hedged remote legs
@@ -170,7 +170,7 @@ METRIC_TRACE_STORE_DROPPED = "trace_store_dropped_total"
 METRIC_TRACE_SLOW_QUERIES = "trace_slow_queries_total"
 METRIC_TRACE_DURATION = "trace_duration_ms"  # histogram
 METRIC_TRACE_STAGE_LATENCY = "trace_stage_latency_ms"  # histogram
-# sub-ms cache hits up through the ~67ms dispatch floor and slow remote
+# sub-ms cache hits up through device dispatches and slow remote
 # fan-outs — one layout for both the root and per-stage histograms so
 # a stage's share of the root is readable bucket-for-bucket
 TRACE_DURATION_BUCKETS_MS = (0.5, 1.0, 5.0, 10.0, 25.0, 50.0, 100.0,

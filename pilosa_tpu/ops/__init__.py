@@ -2,8 +2,7 @@
 
 These are the TPU-native equivalents of the reference's roaring container
 kernels (reference: roaring/roaring.go:711-1660) and fragment scan loops
-(reference: fragment.go:283-1305) — the components BASELINE.md marks as the
-XLA/Pallas kernel targets.
+(reference: fragment.go:283-1305) — the XLA/Pallas kernel targets.
 """
 
 from pilosa_tpu.ops.bitmap import (

@@ -25,6 +25,7 @@ import numpy as np
 from jax import lax
 
 from pilosa_tpu import native, platform
+from pilosa_tpu.ops import pallas_util as PU
 from pilosa_tpu.shardwidth import BITS_PER_WORD, SHARD_WIDTH, WORDS_PER_SHARD
 
 # ---------------------------------------------------------------------------
@@ -169,24 +170,13 @@ def _popcount_i32(x):
     return lax.population_count(x).astype(jnp.int32)
 
 
-def _mark_varying(x, axes):
-    """Mark an array as varying over shard_map mesh axes, so literal-zero
-    scan carries type-match inputs traced inside shard_map. Uses the
-    current API with fallback for older jax."""
-    if hasattr(lax, "pcast"):
-        return lax.pcast(x, axes, to="varying")
-    return lax.pvary(x, axes)
-
-
 def zeros_varying_like(ref, shape, dtype):
     """Zeros carrying the same varying-manual-axes type as ``ref`` — the
-    correct scan-carry init for code that may trace inside shard_map."""
+    correct scan-carry init for code that may trace inside shard_map
+    (a literal-zero carry must type-match inputs traced there)."""
     z = jnp.zeros(shape, dtype=dtype)
-    typeof = getattr(jax, "typeof", None)
-    if typeof is None:  # pre-typeof jax: avals carry no varying-axes type
-        return z
-    vma = getattr(typeof(ref), "vma", frozenset())
-    return _mark_varying(z, tuple(vma)) if vma else z
+    vma = jax.typeof(ref).vma
+    return lax.pcast(z, tuple(vma), to="varying") if vma else z
 
 
 def host_popcount(x: np.ndarray) -> int:
@@ -202,7 +192,7 @@ def plane_count(a):
     return jnp.sum(_popcount_i32(a))
 
 
-#: word-block per grid step of the Pallas popcount reduce (VPU tile)
+#: lane width of the Pallas popcount reduce's 2-D view of a flat plane
 _PALLAS_POP_BW = 512
 
 
@@ -221,20 +211,29 @@ def _popcount_sum_kernel(x_ref, out_ref):
         out_ref[0, 0] += s
 
 
+def pallas_count_eligible(total_words: int) -> bool:
+    """Whether a flat plane of ``total_words`` splits into the blocks
+    :func:`plane_count_pallas_traced` streams."""
+    return (total_words % _PALLAS_POP_BW == 0
+            and PU.block_rows(total_words // _PALLAS_POP_BW) is not None)
+
+
 def plane_count_pallas_traced(plane, interpret: bool):
-    """Traceable Pallas popcount-sum of a flat plane (length a multiple
-    of 512 words): the count-tape terminal used by
-    ``parallel/mesh.compile_tape_count``. A 1-D grid streams (1, 512)
-    VMEM tiles through the VPU popcount and accumulates into one SMEM
-    scalar — the tape's bitwise ops fuse into the same pass upstream."""
+    """Traceable Pallas popcount-sum of a flat plane (a length
+    :func:`pallas_count_eligible` accepts): the count-tape terminal used
+    by ``parallel/mesh.compile_tape_count``. A 1-D grid streams
+    (rows, 512) VMEM blocks — one shard's 32768 words per step at shard
+    width — through the VPU popcount and accumulates into one SMEM
+    scalar."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     x = plane.reshape(-1, _PALLAS_POP_BW)
+    rows = PU.block_rows(x.shape[0])
     out = pl.pallas_call(
         _popcount_sum_kernel,
-        grid=(x.shape[0],),
-        in_specs=[pl.BlockSpec((1, _PALLAS_POP_BW), lambda g: (g, 0))],
+        grid=(x.shape[0] // rows,),
+        in_specs=[pl.BlockSpec((rows, _PALLAS_POP_BW), lambda g: (g, 0))],
         out_specs=pl.BlockSpec(memory_space=pltpu.SMEM),
         out_shape=jax.ShapeDtypeStruct((1, 1), jnp.int32),
         interpret=interpret,

@@ -48,6 +48,10 @@ MAX_FAILURES = 3
 #: exercise every kernel body well under this cap.
 INTERPRET_MAX_WORDS = 1 << 13
 
+#: most sublane rows one grid step of a row-blocked kernel takes
+#: (64 x 512 uint32 words = one shard plane, 128 KiB of VMEM per buffer)
+MAX_BLOCK_ROWS = 64
+
 _FAILURES: dict = {}
 _LOCK = threading.Lock()
 
@@ -80,10 +84,15 @@ def use_interpret() -> bool:
 def why_not(kernel: str, *arrays, max_rows: Optional[int] = None
             ) -> Optional[str]:
     """``None`` when the Pallas path should run for ``kernel``, else the
-    fallback reason: ``disabled`` | ``failures`` | ``tracer`` | ``shape``
-    | ``interpret`` | ``backend``. Shape rules: every array 2-D with a
-    non-zero minor axis; the first at most ``max_rows`` rows when given;
-    in interpret mode no array wider than :data:`INTERPRET_MAX_WORDS`."""
+    fallback reason: ``disabled`` | ``failures`` | ``tracer`` | ``mesh``
+    | ``shape`` | ``interpret`` | ``backend``. Shape rules: every array
+    2-D with a non-zero minor axis; the first at most ``max_rows`` rows
+    when given; in interpret mode no array wider than
+    :data:`INTERPRET_MAX_WORDS`. Mesh rule: a compiled ``pallas_call``
+    whose operand is sharded over several devices is refused at lowering
+    ("Mosaic kernels cannot be automatically partitioned. Please wrap
+    the call in a shard_map."), so those take the partitionable XLA path;
+    the interpreter lowers to ordinary XLA ops and is exempt."""
     if disabled():
         return "disabled"
     with _LOCK:
@@ -94,6 +103,11 @@ def why_not(kernel: str, *arrays, max_rows: Optional[int] = None
     for x in arrays:
         if isinstance(x, jax.core.Tracer):
             return "tracer"
+    if not use_interpret():
+        for x in arrays:
+            sharding = getattr(x, "sharding", None)
+            if sharding is not None and len(sharding.device_set) > 1:
+                return "mesh"
     if arrays:
         a = arrays[0]
         for x in arrays:
@@ -107,6 +121,24 @@ def why_not(kernel: str, *arrays, max_rows: Optional[int] = None
     if platform.default_backend() == "tpu" or forced():
         return None
     return "backend"
+
+
+def block_rows(n_rows: int) -> Optional[int]:
+    """Sublane rows per grid step for a kernel streaming an
+    ``(n_rows, lanes)`` array in row blocks. Mosaic refuses a block whose
+    second-minor dim is neither a multiple of 8 nor the whole array dim,
+    so: the whole array when it fits one block, else the largest
+    power-of-two divisor in 8..:data:`MAX_BLOCK_ROWS` (96 rows stream as
+    32, not 48); ``None`` when there is none (caller routes to the
+    classic path, ``why="shape"``)."""
+    if n_rows <= MAX_BLOCK_ROWS:
+        return n_rows
+    r = MAX_BLOCK_ROWS
+    while r >= 8:
+        if n_rows % r == 0:
+            return r
+        r //= 2
+    return None
 
 
 def mode_token() -> str:

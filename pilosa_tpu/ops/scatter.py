@@ -12,8 +12,8 @@ gather+scatter once per row. The device formulation splits the work:
 2. **scatter** (device): one ``.at[addr].set(masks)`` builds the update
    plane U (addresses are unique, so a plain set is exact), then a
    Pallas VPU kernel fuses ``merged = planes | U`` with the changed-bit
-   count ``Σ popcount(U & ~planes)`` in a single pass over (1, 512)
-   VMEM tiles.
+   count ``Σ popcount(U & ~planes)`` in a single pass over (rows, 512)
+   VMEM blocks.
 
 The per-row native loop stays as the classic path and bit-identity
 oracle; eligibility (size caps + backend/kill-switch rules) lives in
@@ -33,7 +33,9 @@ from jax import lax
 from pilosa_tpu import platform
 from pilosa_tpu.ops import pallas_util as PU
 
-#: word-block per grid step of the merge+count kernel
+#: lane width of the merge+count kernel's 2-D view of the flat planes.
+#: Flat inputs are padded to a power of two >= _BW, so the row count is a
+#: power of two and PU.block_rows always finds a block.
 _BW = 512
 #: gathered sub-plane words per device round trip. Imports touching more
 #: rows than fit one chunk stream through a chunked grid — each chunk
@@ -115,13 +117,13 @@ def _scatter_merge_pallas(flat, addr, masks, interpret):
     upd = jnp.zeros_like(flat).at[addr].set(masks)
     x = flat.reshape(-1, _BW)
     u = upd.reshape(-1, _BW)
+    rows = PU.block_rows(x.shape[0])
+    block = pl.BlockSpec((rows, _BW), lambda g: (g, 0))
     merged, cnt = pl.pallas_call(
         _merge_count_kernel,
-        grid=(x.shape[0],),
-        in_specs=[pl.BlockSpec((1, _BW), lambda g: (g, 0)),
-                  pl.BlockSpec((1, _BW), lambda g: (g, 0))],
-        out_specs=[pl.BlockSpec((1, _BW), lambda g: (g, 0)),
-                   pl.BlockSpec(memory_space=pltpu.SMEM)],
+        grid=(x.shape[0] // rows,),
+        in_specs=[block, block],
+        out_specs=[block, pl.BlockSpec(memory_space=pltpu.SMEM)],
         out_shape=(jax.ShapeDtypeStruct(x.shape, flat.dtype),
                    jax.ShapeDtypeStruct((1, 1), jnp.int32)),
         interpret=interpret,

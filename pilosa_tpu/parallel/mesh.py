@@ -27,12 +27,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax import shard_map as _shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:  # jax >= 0.4.35 exposes shard_map at top level
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
 
 from pilosa_tpu import platform
 from pilosa_tpu.ops.bitmap import _popcount_i32, zeros_varying_like
@@ -243,8 +239,7 @@ def _groupby_counts(mesh, a, b):
 # the four bitmap combinators — and the whole tape plus its terminal
 # (popcount-reduce or plane materialization) compiles to ONE executable.
 # The warm path then launches exactly one program per query instead of a
-# Python loop of per-op dispatches: that loop, not data volume, is the
-# ~67ms floor BENCH_r05 measured.
+# Python loop of per-op dispatches, each paying its own launch.
 # ---------------------------------------------------------------------------
 
 def _tape_eval(tape, leaves):
@@ -288,7 +283,8 @@ def compile_tape_count(tape, masked: bool, total_words: int):
     to be placed). Callers cache the returned fn per (tape, shape
     bucket, mesh epoch)."""
     from pilosa_tpu.ops import pallas_util as PU
-    from pilosa_tpu.ops.bitmap import _PALLAS_POP_BW, plane_count_pallas_traced
+    from pilosa_tpu.ops.bitmap import (pallas_count_eligible,
+                                       plane_count_pallas_traced)
 
     mesh = engine_mesh()
     use_mesh = (mesh.devices.size > 1
@@ -312,7 +308,7 @@ def compile_tape_count(tape, masked: bool, total_words: int):
         # once per compile; programs.py keys its cache on PU.mode_token
         # so flipping the kill switch recompiles.
         why = PU.why_not("tape_count")
-        if why is None and total_words % _PALLAS_POP_BW:
+        if why is None and not pallas_count_eligible(total_words):
             why = "shape"
         if why is None:
             interpret = PU.use_interpret()

@@ -1,6 +1,6 @@
 """Pre-compiled per-query-family programs over resident device planes.
 
-The warm-path answer to the ~67ms dispatch floor (BENCH_r05): instead of
+The warm path is one dispatch and one fetch per query: instead of
 the executor's per-op Python loop — each ``B.plane_*`` a separate jitted
 dispatch, each paying launch setup — a maskable bitmap call tree lowers
 to an *op tape* (a register machine whose initial registers are resident

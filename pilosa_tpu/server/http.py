@@ -1408,9 +1408,19 @@ class Handler(BaseHTTPRequestHandler):
                         holder_bytes += frag.planes.nbytes
                 for frag in list(fld.bsi.values()):
                     holder_bytes += frag.planes.nbytes
+        import jax
+
+        # per-device allocator view, so "everything on device 0" under a
+        # mesh is visible; backends without memory_stats() report null
+        device_bytes = {}
+        for d in jax.devices():
+            stats = d.memory_stats()
+            device_bytes[str(d)] = (
+                stats.get("bytes_in_use") if stats else None)
         self._send(200, {
             "maxRSSBytes": ru.ru_maxrss * 1024,  # linux reports KiB
             "holderPlaneBytes": holder_bytes,
+            "deviceBytesInUse": device_bytes,
         })
 
     def get_disk_usage(self, index: str = None):

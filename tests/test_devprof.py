@@ -272,7 +272,7 @@ class TestGating:
             assert r["mfu_pct"] > 0
             assert r["achieved_gbps"] > 0
         s = devprof.stats_json()
-        assert s["enabled"] and s["backend"]
+        assert s["enabled"] and s["device_kind"]
         assert s["peak_tflops"] > 0 and s["peak_gbps"] > 0
         assert s["cost_evals"] >= 3
 
@@ -292,6 +292,12 @@ class TestGating:
         monkeypatch.setenv("PILOSA_TPU_DEVPROF_PEAK_TFLOPS", "2.0")
         monkeypatch.setenv("PILOSA_TPU_DEVPROF_PEAK_GBPS", "50.0")
         assert devprof.peaks() == (2.0, 50.0)
+
+    def test_peaks_keyed_by_device_kind_unknown_raises(self):
+        # the chip reports itself as "TPU v5 lite" (one v5e chip)
+        assert devprof.peaks_for("TPU v5 lite") == (393.0, 819.0)
+        with pytest.raises(LookupError, match="TPU v9"):
+            devprof.peaks_for("TPU v9")
 
 
 # ---------------------------------------------------------------------------

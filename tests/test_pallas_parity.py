@@ -264,6 +264,42 @@ def test_plane_count_pallas_2d(rng, forced):
     assert got == int(np.unpackbits(x.view(np.uint8)).sum())
 
 
+def test_block_rows_always_lowerable():
+    """Mosaic takes a row block only when it is the whole array or a
+    multiple of 8 rows — never the (1, 512) row the kernels once used."""
+    assert PU.block_rows(1) == 1  # whole array
+    assert PU.block_rows(64) == 64
+    assert PU.block_rows(6 * 64) == 64  # 6 shards of 64 x 512 words
+    assert PU.block_rows(72) == 8
+    assert PU.block_rows(96) == 32  # power-of-two divisors only, not 48
+    assert PU.block_rows(65) is None
+    assert B.pallas_count_eligible(6 * 32768)
+    assert not B.pallas_count_eligible(65 * 512)
+    assert not B.pallas_count_eligible(100)
+
+
+def test_sharded_operands_route_to_xla_when_compiled(rng, forced,
+                                                     monkeypatch):
+    """A compiled pallas_call refuses mesh-sharded operands ("Mosaic
+    kernels cannot be automatically partitioned"), so why_not answers
+    "mesh" for them; the interpreter (plain XLA ops) keeps running."""
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from pilosa_tpu.parallel import mesh
+
+    m = mesh.analytics_mesh(jax.devices())
+    assert m.devices.size > 1
+    sharded = jax.device_put(
+        rand_planes(rng, 8, WORDS),
+        NamedSharding(m, P(None, (mesh.SHARD_AXIS, mesh.COL_AXIS))))
+    local = jax.device_put(rand_planes(rng, 8, WORDS), jax.devices()[0])
+    assert PU.why_not("pair_counts", sharded, local) is None  # interpret
+    monkeypatch.setattr(PU, "use_interpret", lambda: False)
+    assert PU.why_not("pair_counts", sharded, local) == "mesh"
+    assert PU.why_not("pair_counts", local, local) is None
+
+
 # ---------------------------------------------------------------------------
 # Kill switch + metrics exposition
 # ---------------------------------------------------------------------------
