@@ -21,6 +21,7 @@ from pilosa_tpu.pql.executor import Executor
 from pilosa_tpu.obs import ExecutionRequestsAPI, get_tracer
 from pilosa_tpu.obs import metrics as M
 from pilosa_tpu.obs.tenants import current_tenant_id
+from pilosa_tpu.obs.tracing import annotate
 from pilosa_tpu.pql.result import result_to_json
 from pilosa_tpu.storage import save_holder_data
 from pilosa_tpu.storage.txn import TxFactory
@@ -389,7 +390,12 @@ class API:
             span.set_tag("tenant", tenant)
         t0 = _time.monotonic()
         try:
-            parsed = parse(pql) if isinstance(pql, str) else pql
+            if isinstance(pql, str):
+                with annotate("pql.parse"), \
+                        get_tracer().start_span("pql.parse"):
+                    parsed = parse(pql)
+            else:
+                parsed = pql
             # Writes hold the holder write lock for the request and
             # group-commit their WAL records at finish (the reference's
             # write-Tx half of Qcx); pure reads take no lock — they see

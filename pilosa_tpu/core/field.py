@@ -17,7 +17,8 @@ from typing import Dict, Iterable, List, Optional, Set
 import numpy as np
 
 from pilosa_tpu.core import timeq
-from pilosa_tpu.obs import devprof
+from pilosa_tpu.obs.stages import record_stage
+from pilosa_tpu.obs.tracing import annotate
 from pilosa_tpu.core.fragment import BSIFragment, SetFragment, group_sorted
 from pilosa_tpu.core.schema import (
     BOOL_FALSE_ROW,
@@ -192,19 +193,11 @@ class Field:
         return out - self.options.base
 
     def set_values(self, cols: Iterable[int], values: Iterable) -> None:
-        if not devprof.ENABLED:
-            return self._set_values(cols, values)
-        if not isinstance(cols, (list, tuple, np.ndarray)):
-            cols = list(cols)
+        # the ``fragment_advance`` stage spans the whole bulk call
+        # (conversion, WAL append, fragment writes); the profiler leaf
+        # starts after the WAL append, which flushes under
+        # wal_sync=always and has a leaf of its own there
         t0 = time.perf_counter()
-        out = self._set_values(cols, values)
-        # "fragment advance": WAL append buffering + per-shard fragment
-        # writes for one bulk call — the device-side half of ingest
-        devprof.record_stage("fragment_advance", time.perf_counter() - t0,
-                             rows=len(cols))
-        return out
-
-    def _set_values(self, cols: Iterable[int], values: Iterable) -> None:
         if not isinstance(cols, (list, tuple, np.ndarray)):
             cols = list(cols)  # generators/iterators per the signature
         cols = np.asarray(cols, dtype=np.int64).ravel()
@@ -220,8 +213,11 @@ class Field:
         self._log("set_values", self.name, cols, np.asarray(values))
         shards = cols >> SHARD_WIDTH_EXP
         pos = cols & (SHARD_WIDTH - 1)
-        for shard, (p, v) in group_sorted(shards, pos, stored):
-            self.bsi_fragment(shard, create=True).set_values(p, v)
+        with annotate("import.fragment_advance"):
+            for shard, (p, v) in group_sorted(shards, pos, stored):
+                self.bsi_fragment(shard, create=True).set_values(p, v)
+        record_stage("fragment_advance", time.perf_counter() - t0,
+                     rows=cols.size)
 
     def clear_value(self, col: int) -> bool:
         self._log("clear_value", self.name, col)
@@ -234,18 +230,7 @@ class Field:
         """Bulk (row, col) import with IDs already translated (reference:
         fragment.go:1498 bulkImport; mutex variant :1787). Returns changed
         bit count. The one bulk WAL record replaces per-bit logging."""
-        if not devprof.ENABLED:
-            return self._import_bits(rows, cols, clear)
-        if not isinstance(cols, (list, tuple, np.ndarray)):
-            cols = list(cols)
         t0 = time.perf_counter()
-        changed = self._import_bits(rows, cols, clear)
-        devprof.record_stage("fragment_advance", time.perf_counter() - t0,
-                             rows=len(cols))
-        return changed
-
-    def _import_bits(self, rows: Iterable[int], cols: Iterable[int],
-                     clear: bool = False) -> int:
         if not isinstance(rows, (list, tuple, np.ndarray)):
             rows = list(rows)  # generators/iterators per the signature
         if not isinstance(cols, (list, tuple, np.ndarray)):
@@ -254,33 +239,40 @@ class Field:
         cols = np.asarray(cols, dtype=np.int64).ravel()
         if rows.size != cols.size:
             raise ValueError("rows and cols must be the same length")
-        changed = 0
-        if clear:
-            # per-bit so every view is cleared; clear_bit logs itself
-            for r, c in zip(rows, cols):
-                changed += self.clear_bit(int(r), int(c))
+        n, changed = cols.size, 0
+        try:
+            if clear:
+                # per-bit so every view is cleared; clear_bit logs itself
+                for r, c in zip(rows, cols):
+                    changed += self.clear_bit(int(r), int(c))
+                return changed
+            mutex = self.options.type in (FieldType.MUTEX, FieldType.BOOL)
+            if mutex and rows.size < 256:
+                # Small interactive batches: per-bit keeps fine-grained
+                # device deltas (reference: fragment.go:1787
+                # bulkImportMutex).
+                for r, c in zip(rows, cols):
+                    changed += self.set_bit(int(r), int(c))
+                return changed
+            if mutex:
+                # Bulk mutex: later duplicates win per column, then one
+                # vectorized clear-and-set per shard.
+                _, last = np.unique(cols[::-1], return_index=True)
+                idx = cols.size - 1 - last
+                rows, cols = rows[idx], cols[idx]
+            self._log("import_bits", self.name, rows, cols)
+            shards = cols >> SHARD_WIDTH_EXP
+            pos = cols & (SHARD_WIDTH - 1)
+            # a profiler leaf like set_values': after the WAL append
+            with annotate("import.fragment_advance"):
+                for shard, (r, p) in group_sorted(shards, rows, pos):
+                    frag = self.fragment(shard, create=True)
+                    changed += frag.set_mutex_many(r, p) if mutex \
+                        else frag.set_many(r, p)
             return changed
-        mutex = self.options.type in (FieldType.MUTEX, FieldType.BOOL)
-        if mutex and rows.size < 256:
-            # Small interactive batches: per-bit keeps fine-grained device
-            # deltas (reference: fragment.go:1787 bulkImportMutex).
-            for r, c in zip(rows, cols):
-                changed += self.set_bit(int(r), int(c))
-            return changed
-        if mutex:
-            # Bulk mutex: later duplicates win per column, then one
-            # vectorized clear-and-set per shard.
-            _, last = np.unique(cols[::-1], return_index=True)
-            idx = cols.size - 1 - last
-            rows, cols = rows[idx], cols[idx]
-        self._log("import_bits", self.name, rows, cols)
-        shards = cols >> SHARD_WIDTH_EXP
-        pos = cols & (SHARD_WIDTH - 1)
-        for shard, (r, p) in group_sorted(shards, rows, pos):
-            frag = self.fragment(shard, create=True)
-            changed += frag.set_mutex_many(r, p) if mutex \
-                else frag.set_many(r, p)
-        return changed
+        finally:
+            record_stage("fragment_advance", time.perf_counter() - t0,
+                         rows=n)
 
     def write_row_plane(self, shard: int, row: int, plane,
                         clear: bool = False,

@@ -9,10 +9,13 @@ from __future__ import annotations
 
 import json
 import os
+import time
 from typing import Dict, List, Optional
 
 from pilosa_tpu.core.index import Index
 from pilosa_tpu.core.schema import FieldOptions, IndexOptions
+from pilosa_tpu.obs import metrics as M
+from pilosa_tpu.obs.tracing import annotate
 from pilosa_tpu.shardwidth import SHARD_WIDTH
 
 
@@ -45,6 +48,10 @@ class Holder:
         import threading
 
         self.write_lock = threading.RLock()
+        # snapshot path -> (fragment, version) as the disk holds it: set
+        # by load_holder_data and by every completed save_holder_data,
+        # which counts its files as changed or unchanged against it
+        self.saved_versions: Dict[str, tuple] = {}
         self.indexes: Dict[str, Index] = {}
         if path:
             os.makedirs(path, exist_ok=True)
@@ -179,9 +186,6 @@ class Holder:
         Qcx)."""
         if not self.path or self.readonly:
             return
-        import time
-
-        from pilosa_tpu.obs import metrics as M
         from pilosa_tpu.storage.recovery import (
             crash_scope, write_checkpoint_meta,
         )
@@ -190,10 +194,21 @@ class Holder:
         plan = self.crash_plan
         if plan is not None and plan.dead:
             return
+
+        def phase(name: str, since: float) -> float:
+            # counted as each phase ends, never once at the end of the
+            # checkpoint: a window's delta is the window's. ``serialize``
+            # and ``fsync`` accrue per file in store._atomic_savez.
+            now = time.perf_counter()
+            M.REGISTRY.count(M.METRIC_RECOVERY_CHECKPOINT_PHASE_SECONDS,
+                             now - since, phase=name)
+            return now
+
         t0 = time.perf_counter()
         pruned = 0
         with self.write_lock:
             self.flush_wals()
+            phase("wal_flush", t0)
             lsns = {name: idx.wal.last_lsn
                     for name, idx in self.indexes.items()
                     if idx.wal is not None}
@@ -207,13 +222,19 @@ class Holder:
                 save_holder_data(self)
                 if plan is not None and not plan.fire("checkpoint.mid"):
                     return
+                t = time.perf_counter()
+                with annotate("checkpoint.meta"):
+                    for name, lsn in lsns.items():
+                        write_checkpoint_meta(
+                            self._index_path(name), lsn,
+                            stream_offsets=offsets.get(name))
+                t = phase("meta", t)
+            with annotate("checkpoint.prune"):
                 for name, lsn in lsns.items():
-                    write_checkpoint_meta(self._index_path(name), lsn,
-                                          stream_offsets=offsets.get(name))
-            for name, lsn in lsns.items():
-                idx = self.indexes.get(name)
-                if idx is not None and idx.wal is not None:
-                    pruned += idx.wal.prune(lsn)
+                    idx = self.indexes.get(name)
+                    if idx is not None and idx.wal is not None:
+                        pruned += idx.wal.prune(lsn)
+            phase("prune", t)
         M.REGISTRY.observe(M.METRIC_RECOVERY_CHECKPOINT_SECONDS,
                            time.perf_counter() - t0)
         if pruned:
@@ -258,12 +279,15 @@ class Holder:
         field-level write methods that produced them (reference:
         rbf/db.go WAL replay on open; op-level like dax/storage
         snapshot+log resume)."""
-        from pilosa_tpu.obs import metrics as M
         from pilosa_tpu.storage.recovery import (read_checkpoint_meta,
                                                  read_checkpoint_offsets)
         from pilosa_tpu.storage.store import load_holder_data
 
+        t0 = time.perf_counter()
         load_holder_data(self)
+        t1 = time.perf_counter()
+        M.REGISTRY.gauge(M.METRIC_STARTUP_PHASE_SECONDS, t1 - t0,
+                         phase="load_checkpoint")
         for name, idx in self.indexes.items():
             if idx.wal is None:
                 continue
@@ -288,6 +312,8 @@ class Holder:
                 M.REGISTRY.count(M.METRIC_RECOVERY_REPLAY_BYTES, nbytes[0])
             # chop any torn tail so post-recovery appends are readable
             idx.wal.repair()
+        M.REGISTRY.gauge(M.METRIC_STARTUP_PHASE_SECONDS,
+                         time.perf_counter() - t1, phase="wal_replay")
 
     @staticmethod
     def _apply_wal_record(idx: Index, rec) -> None:

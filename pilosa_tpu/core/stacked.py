@@ -46,6 +46,7 @@ import contextlib
 import itertools
 import os
 import threading
+import time
 from collections import OrderedDict
 from typing import Dict, Iterator, List, Optional, Sequence, Tuple
 
@@ -54,6 +55,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from pilosa_tpu import platform
+from pilosa_tpu.obs import metrics as M
 from pilosa_tpu.ops import bitmap as bitops
 from pilosa_tpu.ops import bsi as bsiops
 from pilosa_tpu.ops import ctiles
@@ -182,8 +184,6 @@ class DeviceBudget:
         self._lru: "OrderedDict[Tuple, Tuple[int, object]]" = OrderedDict()
 
     def charge(self, key: Tuple, nbytes: int, evict_cb) -> None:
-        from pilosa_tpu.obs import metrics as M
-
         with self._lock:
             old = self._lru.pop(key, None)
             if old is not None:
@@ -217,8 +217,6 @@ class DeviceBudget:
             old = self._lru.pop(key, None)
             if old is not None:
                 self.used -= old[0]
-                from pilosa_tpu.obs import metrics as M
-
                 M.REGISTRY.gauge(M.METRIC_DEVICE_HBM_RESIDENT_BYTES,
                                  self.used)
                 M.REGISTRY.gauge(M.METRIC_DEVICE_BUDGET_RESIDENT_BYTES,
@@ -353,7 +351,7 @@ class StackedSet:
         # check AND the host copy: checking versions without excluding
         # writers would let a bulk import that mutates planes before its
         # single version bump produce a torn block.
-        with self._write_lock, self._lock:
+        with writer_wait(self._write_lock), self._lock:
             blk = self._blocks[bi]
             if blk is not None:
                 return blk
@@ -551,7 +549,7 @@ class StackedBSI:
         if blk is not None:
             BUDGET.touch((self.serial, 0))
             return blk
-        with self._write_lock, self._lock:
+        with writer_wait(self._write_lock), self._lock:
             blk = self._planes
             if blk is not None:
                 return blk
@@ -622,8 +620,6 @@ def _cache_get(field, group, subset, vers):
         hit = inner.get(subset)
         if hit is not None and hit[0] == vers:
             inner.move_to_end(subset)
-            from pilosa_tpu.obs import metrics as M
-
             M.REGISTRY.count(M.METRIC_DEVICE_RESIDENT_HITS)
             return hit[1]
         return None
@@ -989,6 +985,23 @@ def _writer_lock(field):
     return lock if lock is not None else contextlib.nullcontext()
 
 
+@contextlib.contextmanager
+def writer_wait(lock):
+    """``with lock:`` for a read that must exclude writers (a stack or
+    block build, the last StackStale retry), counting how long it stood
+    behind a writer or a checkpoint. Never on a warm read: cache hits
+    take no lock. A counter and no profiler leaf: a wait lasts as long as
+    the work it waits for, and the longest event covering an idle gap
+    owns it, so a waiting reader would take the checkpoint's seconds
+    from ``checkpoint.serialize`` on the writer's thread."""
+    t0 = time.perf_counter()
+    with lock:
+        M.REGISTRY.count(M.METRIC_STACK_WRITER_WAIT_SECONDS,
+                         time.perf_counter() - t0)
+        M.REGISTRY.count(M.METRIC_STACK_WRITER_WAIT_COUNT)
+        yield
+
+
 def stacked_set(field, shards: Sequence[int], view: str) -> StackedSet:
     """Build-or-reuse the stacked view of ``field``'s ``view`` fragments.
 
@@ -1006,7 +1019,7 @@ def stacked_set(field, shards: Sequence[int], view: str) -> StackedSet:
     hit = _cache_get(field, group, subset, _versions(fragments))
     if hit is not None:
         return hit
-    with _writer_lock(field):
+    with writer_wait(_writer_lock(field)):
         fragments = [field.fragment(s, view) for s in shards]
         vers = _versions(fragments)
         hit = _cache_get(field, group, subset, vers)
@@ -1025,7 +1038,7 @@ def stacked_bsi(field, shards: Sequence[int]) -> StackedBSI:
     hit = _cache_get(field, group, subset, _versions(fragments))
     if hit is not None:
         return hit
-    with _writer_lock(field):
+    with writer_wait(_writer_lock(field)):
         fragments = [field.bsi_fragment(s) for s in shards]
         vers = _versions(fragments)
         hit = _cache_get(field, group, subset, vers)

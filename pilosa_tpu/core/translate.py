@@ -14,7 +14,11 @@ from __future__ import annotations
 import json
 import os
 import threading
+import time
 from typing import Dict, Iterable, List, Optional, Tuple
+
+from pilosa_tpu.obs.stages import record_stage
+from pilosa_tpu.obs.tracing import annotate
 
 
 class TranslateStore:
@@ -264,12 +268,18 @@ def bulk_translate_ids(store, keys) -> "object":
     """Vectorized find-or-create: ONE create_keys round on the unique
     keys, mapped back through a LUT (reference: batch.go:860
     doTranslation batches unique keys the same way). Returns an
-    ``np.int64`` array aligned with ``keys``."""
+    ``np.int64`` array aligned with ``keys``. Every bulk ingest path
+    translates here, so this is where the ``key_translate`` stage is
+    taken."""
     import numpy as np
 
-    arr = np.asarray(keys)
-    uniq, inverse = np.unique(arr, return_inverse=True)
-    uniq_l = [str(k) for k in uniq.tolist()]
-    m = store.create_keys(uniq_l)
-    lut = np.array([m[k] for k in uniq_l], dtype=np.int64)
-    return lut[inverse]
+    t0 = time.perf_counter()
+    with annotate("import.key_translate"):
+        arr = np.asarray(keys)
+        uniq, inverse = np.unique(arr, return_inverse=True)
+        uniq_l = [str(k) for k in uniq.tolist()]
+        m = store.create_keys(uniq_l)
+        lut = np.array([m[k] for k in uniq_l], dtype=np.int64)
+        out = lut[inverse]
+    record_stage("key_translate", time.perf_counter() - t0, rows=arr.size)
+    return out

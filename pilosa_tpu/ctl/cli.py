@@ -15,6 +15,7 @@ import argparse
 import csv
 import io
 import sys
+import time
 import urllib.request
 from typing import List, Optional
 
@@ -37,15 +38,25 @@ def cmd_server(args) -> int:
     from pilosa_tpu.api import API
     from pilosa_tpu.server.http import serve
 
+    from pilosa_tpu.obs import metrics as M
     from pilosa_tpu.obs.logger import configure as configure_logging
+    from pilosa_tpu.obs.logger import get_logger
 
     configure_logging(cfg.log_level, cfg.log_path or None)
+    # start-up by phase, as ``startup_phase_seconds{phase}``: ``backend``
+    # here (importing JAX, reaching the device), ``load_checkpoint`` and
+    # ``wal_replay`` in Holder.recover, ``listen`` from a recovered holder
+    # to a listening socket
+    t0 = time.perf_counter()
     platform.configure_compile_cache()
     # resolve the backend before the listener opens: a server that
     # cannot reach its device fails here, not on the first query
     dev = platform.device_facts()
+    M.REGISTRY.gauge(M.METRIC_STARTUP_PHASE_SECONDS,
+                     time.perf_counter() - t0, phase="backend")
     api = API(cfg.data_dir or None, wal_sync=cfg.wal_sync,
               segment_bytes=cfg.storage_recovery_segment_bytes)
+    t_recovered = time.perf_counter()
     # [storage.recovery] checkpoint interval wins when set; the legacy
     # top-level checkpoint-bytes knob stays the fallback
     api.holder.checkpoint_bytes = (
@@ -81,8 +92,19 @@ def cmd_server(args) -> int:
           f"devices={len(dev['devices'])}, "
           f"data-dir={cfg.data_dir or '<memory>'}"
           f"{', auth on' if auth else ''})", file=sys.stderr)
+
+    def listening():
+        M.REGISTRY.gauge(M.METRIC_STARTUP_PHASE_SECONDS,
+                         time.perf_counter() - t_recovered, phase="listen")
+        took = {p: M.REGISTRY.value(M.METRIC_STARTUP_PHASE_SECONDS, phase=p)
+                for p in ("backend", "load_checkpoint", "wal_replay",
+                          "listen")}
+        get_logger("server").info("start-up: %s", ", ".join(
+            f"{p} {s:.3f}s" for p, s in took.items()))
+
     serve(api, host=cfg.bind, port=cfg.port,
-          maintenance_interval_s=cfg.ttl_removal_interval_s, auth=auth)
+          maintenance_interval_s=cfg.ttl_removal_interval_s, auth=auth,
+          on_listening=listening)
     return 0
 
 

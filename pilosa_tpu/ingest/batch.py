@@ -15,7 +15,8 @@ import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 from pilosa_tpu.core.schema import FieldType
-from pilosa_tpu.obs import devprof
+from pilosa_tpu.obs.stages import record_stage
+from pilosa_tpu.obs.tracing import annotate
 
 
 class Batch:
@@ -56,9 +57,7 @@ class Batch:
         if not self._records:
             return 0
         n = len(self._records)
-        scope = devprof.ingest_scope() if devprof.ENABLED \
-            else devprof.NULL_SCOPE
-        with scope, self.api.txf.qcx():  # one group commit per flush
+        with self.api.txf.qcx():  # one group commit per flush
             ids = self._translate_ids()
             self._import_fields(ids)
             if self._idx.options.track_existence:
@@ -76,13 +75,11 @@ class Batch:
         raw = [r[self.id_column] for r in self._records]
         if self._idx.options.keys:
             keys = [str(v) for v in raw]
-            if not devprof.ENABLED:
-                m = self._idx.translate.create_keys(keys)
-                return [m[k] for k in keys]
             t0 = time.perf_counter()
-            m = self._idx.translate.create_keys(keys)
-            devprof.record_stage("key_translate",
-                                 time.perf_counter() - t0, rows=len(keys))
+            with annotate("import.key_translate"):
+                m = self._idx.translate.create_keys(keys)
+            record_stage("key_translate", time.perf_counter() - t0,
+                         rows=len(keys))
             return [m[k] for k in keys]
         return [int(v) for v in raw]
 

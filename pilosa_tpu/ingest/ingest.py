@@ -15,7 +15,7 @@ from typing import Optional
 from pilosa_tpu.ingest.batch import Batch
 from pilosa_tpu.ingest.idalloc import IDAllocator
 from pilosa_tpu.ingest.source import Source
-from pilosa_tpu.obs import devprof
+from pilosa_tpu.obs.stages import record_stage
 
 
 class Ingester:
@@ -86,13 +86,10 @@ class Ingester:
         from pilosa_tpu.ingest.source import coerce_column
         from pilosa_tpu.obs import metrics as M
 
-        if devprof.ENABLED:
-            # whole-column parse is the host-side front of the pipeline
-            t0 = time.perf_counter()
-            n, cols = self.source.columns()
-            devprof.record_stage("parse", time.perf_counter() - t0, rows=n)
-        else:
-            n, cols = self.source.columns()
+        # whole-column parse is the host-side front of the pipeline
+        t0 = time.perf_counter()
+        n, cols = self.source.columns()
+        record_stage("parse", time.perf_counter() - t0, rows=n)
         idx = self.api.holder.index(self.index)
         id_col = self.source.id_column()
         # -- record ids: bulk-translate keys or parse ints ----------------
@@ -108,9 +105,7 @@ class Ingester:
             ids = np.arange(rng.base, rng.base + n, dtype=np.int64)
             self.allocator.commit(session)
         imported = 0
-        scope = devprof.ingest_scope() if devprof.ENABLED \
-            else devprof.NULL_SCOPE
-        with scope, self.api.txf.qcx():  # one group commit per load
+        with self.api.txf.qcx():  # one group commit per load
             for name, (opts, raw) in cols.items():
                 fld = idx.field(name)
                 t = fld.options.type
@@ -182,13 +177,7 @@ class Ingester:
         doTranslation)."""
         from pilosa_tpu.core.translate import bulk_translate_ids
 
-        if not devprof.ENABLED:
-            return bulk_translate_ids(store, [str(k) for k in raw])
-        t0 = time.perf_counter()
-        out = bulk_translate_ids(store, [str(k) for k in raw])
-        devprof.record_stage("key_translate", time.perf_counter() - t0,
-                             rows=len(raw))
-        return out
+        return bulk_translate_ids(store, [str(k) for k in raw])
 
     def _flush_auto(self, batch: Batch, pending: list, session: str,
                     offset: int) -> int:

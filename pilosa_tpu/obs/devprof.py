@@ -34,10 +34,9 @@ synchronously on the calling thread). Dispatches outside any scope
 (BSI compare circuits, classic-path jits, collectives) aggregate under
 an ``other`` bucket so total device-time coverage stays visible.
 
-**Ingest stage accounting.** ``record_stage`` accumulates per-stage
-wall seconds / rows / bytes for parse, key_translate, h2d_copy,
-fragment_advance, and wal_commit; ``ingest_scope`` marks a thread so
-the h2d hook attributes transfer bytes to the ingest pipeline.
+Ingest stage accounting is not part of this plane any more: it is always
+on and lives in ``obs/stages.py``; ``stats_json`` and the timeline probe
+still show its snapshot beside the kernels.
 
 Zero-cost when disabled: ``ENABLED`` is False by default
 (``PILOSA_TPU_DEVPROF=1`` turns it on), every instrumentation site
@@ -67,10 +66,11 @@ from pilosa_tpu.analysis import locktrace
 from pilosa_tpu import platform
 from pilosa_tpu.config import env_bool
 from pilosa_tpu.obs import metrics as M
+from pilosa_tpu.obs.stages import INGEST
 
-#: Module switch consulted by every instrumentation site (programs,
-#: ingest, wal, bench). Flip via enable()/disable() so the platform
-#: hooks stay in sync; operators use the env var.
+#: Module switch consulted by every kernel instrumentation site
+#: (programs, Pallas dispatches, bench). Flip via enable()/disable() so
+#: the platform hooks stay in sync; operators use the env var.
 ENABLED = env_bool("PILOSA_TPU_DEVPROF", False)
 
 WORD_BYTES = 4   # planes are uint32 words
@@ -398,62 +398,7 @@ class KernelProfileRegistry:
             self.h2d_seconds = 0.0
 
 
-class IngestAccounting:
-    """Per-stage ingest throughput: cumulative wall seconds, rows, and
-    bytes per named stage, republished as ``ingest_stage_*`` rates."""
-
-    def __init__(self) -> None:
-        self._lock = locktrace.tracked_lock("obs.devprof.ingest")
-        # stage -> [seconds, rows, bytes, batches]
-        self._stages: Dict[str, list] = {}
-
-    def record(self, stage: str, seconds: float, rows: int = 0,
-               nbytes: int = 0) -> None:
-        with self._lock:
-            ent = self._stages.get(stage)
-            if ent is None:
-                ent = self._stages[stage] = [0.0, 0, 0, 0]
-            ent[0] += seconds
-            ent[1] += rows
-            ent[2] += nbytes
-            ent[3] += 1
-            tot_s, tot_rows, tot_bytes = ent[0], ent[1], ent[2]
-        reg = M.REGISTRY
-        reg.count(M.METRIC_INGEST_STAGE_SECONDS, seconds, stage=stage)
-        if rows:
-            reg.count(M.METRIC_INGEST_STAGE_ROWS, rows, stage=stage)
-        if nbytes:
-            reg.count(M.METRIC_INGEST_STAGE_BYTES, nbytes, stage=stage)
-        if tot_s > 0:
-            if tot_rows:
-                reg.gauge(M.METRIC_INGEST_STAGE_ROWS_PER_S,
-                          tot_rows / tot_s, stage=stage)
-            if tot_bytes:
-                reg.gauge(M.METRIC_INGEST_STAGE_BYTES_PER_S,
-                          tot_bytes / tot_s, stage=stage)
-
-    def snapshot(self) -> Dict[str, dict]:
-        with self._lock:
-            rows = {s: list(e) for s, e in self._stages.items()}
-        out: Dict[str, dict] = {}
-        for stage, (secs, nrows, nbytes, batches) in rows.items():
-            d = {"seconds": round(secs, 6), "rows": nrows,
-                 "bytes": nbytes, "batches": batches}
-            if secs > 0:
-                if nrows:
-                    d["rows_per_s"] = round(nrows / secs, 1)
-                if nbytes:
-                    d["bytes_per_s"] = round(nbytes / secs, 1)
-            out[stage] = d
-        return out
-
-    def reset(self) -> None:
-        with self._lock:
-            self._stages.clear()
-
-
 KERNELS = KernelProfileRegistry()
-INGEST = IngestAccounting()
 
 
 # ---------------------------------------------------------------------------
@@ -480,32 +425,12 @@ def kernel_scope(kind: str, tape: Tuple, n_leaves: int, masked: bool,
         _TLS.kernel = prev
 
 
-@contextlib.contextmanager
-def ingest_scope():
-    """Mark this thread as inside the ingest pipeline so h2d bytes land
-    in the ``h2d_copy`` ingest stage (callers gate on ``ENABLED``)."""
-    prev = getattr(_TLS, "ingest", 0)
-    _TLS.ingest = prev + 1
-    try:
-        yield
-    finally:
-        _TLS.ingest = prev
-
-
-def record_stage(stage: str, seconds: float, rows: int = 0,
-                 nbytes: int = 0) -> None:
-    """Module-level convenience for the ingest/wal call sites."""
-    INGEST.record(stage, seconds, rows=rows, nbytes=nbytes)
-
-
 def _on_dispatch(dispatch_s: float, block_s: float) -> None:
     KERNELS.record(getattr(_TLS, "kernel", None), dispatch_s, block_s)
 
 
 def _on_h2d(nbytes: int, seconds: float) -> None:
     KERNELS.record_h2d(nbytes, seconds)
-    if getattr(_TLS, "ingest", 0):
-        INGEST.record("h2d_copy", seconds, nbytes=nbytes)
 
 
 def enable() -> None:

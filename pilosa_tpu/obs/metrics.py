@@ -150,6 +150,15 @@ GOSSIP_STALENESS_BUCKETS_MS = (1.0, 5.0, 10.0, 25.0, 50.0, 100.0,
 METRIC_RECOVERY_REPLAY_RECORDS = "recovery_replay_records_total"
 METRIC_RECOVERY_REPLAY_BYTES = "recovery_replay_bytes_total"
 METRIC_RECOVERY_CHECKPOINT_SECONDS = "recovery_checkpoint_seconds"
+# the same checkpoint as it accrues, so that a window's delta is the
+# window's (the summary above lands once, when a 35 s checkpoint ends):
+# seconds by phase=wal_flush|serialize|fsync|meta|prune, snapshot files
+# by state=changed|unchanged since the last completed checkpoint, and
+# bytes by kind=raw (the arrays) | stored (the file written)
+METRIC_RECOVERY_CHECKPOINT_PHASE_SECONDS = \
+    "recovery_checkpoint_phase_seconds_total"
+METRIC_RECOVERY_CHECKPOINT_FRAGMENTS = "recovery_checkpoint_fragments_total"
+METRIC_RECOVERY_CHECKPOINT_BYTES = "recovery_checkpoint_bytes_total"
 METRIC_RECOVERY_SEGMENTS_PRUNED = "recovery_wal_segments_pruned_total"
 METRIC_RECOVERY_CATCHUP_SHARDS = "recovery_catchup_shards_total"
 METRIC_RECOVERY_CATCHUP_QUEUED = "recovery_catchup_queued_writes_total"
@@ -183,6 +192,22 @@ TRACE_DURATION_BUCKETS_MS = (0.5, 1.0, 5.0, 10.0, 25.0, 50.0, 100.0,
 METRIC_DEVICE_HBM_RESIDENT_BYTES = "device_hbm_resident_bytes"
 METRIC_DEVICE_STACK_EVICTIONS = "device_stack_evictions_total"
 METRIC_DEVICE_RESIDENT_HITS = "device_resident_hits_total"
+# reads that had to take the holder's write lock (a stack or block build,
+# the last StackStale retry): how long they stood behind a writer or a
+# checkpoint, and how many times
+METRIC_STACK_WRITER_WAIT_SECONDS = "stack_writer_wait_seconds_total"
+METRIC_STACK_WRITER_WAIT_COUNT = "stack_writer_wait_total"
+# programs built in this process (platform.configure_compile_cache's
+# jax.monitoring listener), labelled source=compiled|cache (fetched from
+# the persistent cache) and program=<function name>, and their seconds
+METRIC_DEVICE_PROGRAMS_BUILT = "device_programs_built_total"
+METRIC_DEVICE_PROGRAM_BUILD_SECONDS = "device_program_build_seconds_total"
+# the server's start-up by phase=backend|load_checkpoint|wal_replay|
+# listen (gauge: set once, before the first request)
+METRIC_STARTUP_PHASE_SECONDS = "startup_phase_seconds"
+# request-body bytes of the import routes (the user bytes under the WAL
+# bytes of ingest_stage_bytes_total{stage="wal_commit"})
+METRIC_HTTP_REQUEST_BODY_BYTES = "http_request_body_bytes_total"
 # DeviceBudget's own accounting exported directly (same numbers the LRU
 # enforces): bytes currently charged against the HBM cap, and entries it
 # has evicted to stay under it
@@ -247,16 +272,13 @@ METRIC_OPS_PALLAS_FALLBACK = "ops_pallas_fallback_total"
 KERNEL_DISPATCH_BUCKETS_US = (50.0, 100.0, 250.0, 500.0, 1000.0,
                               2500.0, 5000.0, 10000.0, 25000.0,
                               100000.0, 500000.0)
-# ingest stage accounting (ingest/ + storage/wal.py via obs/devprof.py):
-# per-stage wall seconds / rows / bytes counters and the derived
-# cumulative rows-per-s / bytes-per-s gauges, labelled
-# stage=parse|key_translate|h2d_copy|fragment_advance|wal_commit — the
-# overlap work reads these to see which stage hides which
+# ingest stage accounting (obs/stages.py, always on): per-stage wall
+# seconds / rows / bytes counters labelled stage=decode|parse|
+# key_translate|lock_wait|fragment_advance|h2d_copy|wal_commit|
+# checkpoint; a rate is a scrape delta over its seconds
 METRIC_INGEST_STAGE_SECONDS = "ingest_stage_seconds_total"
 METRIC_INGEST_STAGE_ROWS = "ingest_stage_rows_total"
 METRIC_INGEST_STAGE_BYTES = "ingest_stage_bytes_total"
-METRIC_INGEST_STAGE_ROWS_PER_S = "ingest_stage_rows_per_s"
-METRIC_INGEST_STAGE_BYTES_PER_S = "ingest_stage_bytes_per_s"
 # streaming ingest plane (stream/): rows/batches through the pipelined
 # path, hand-off credits + consumer lag gauges, shed device-stage
 # admissions (backpressure retries), and push-endpoint 429 rejections

@@ -179,6 +179,45 @@ _CURRENT: contextvars.ContextVar[Optional[Span]] = contextvars.ContextVar(
     "pilosa_trace_span", default=None)
 
 
+def _session_probe():
+    """``() -> bool``: is a ``jax.profiler`` session collecting right now.
+    The flag lives on a private path of this JAX (0.9), so it is resolved
+    in this one place; where it is missing every site annotates (a
+    ``TraceAnnotation`` outside a session records nothing, it only costs
+    its enter and exit)."""
+    try:
+        from jax._src.lib import _profiler
+
+        return _profiler.TraceMe.is_enabled
+    except (ImportError, AttributeError):
+        return lambda: True
+
+
+_session_active = None
+_TraceAnnotation = None
+
+
+def annotate(name: str):
+    """A leaf on the profiler's clock: a ``jax.profiler.TraceAnnotation``
+    while, and only while, a profiler session is running; the shared
+    ``NOP_SPAN`` otherwise (one flag read, nothing allocated).
+
+    Leaves only: the driver charges an idle gap of the device to the
+    longest host event covering it, so an annotation around a request,
+    around ``query.pql`` or around a JAX call the profiler already names
+    would swallow every owner inside it. Containers are spans of the
+    sampled ``?profile=true`` tree (``start_span``), never annotations,
+    and a wait for a lock is a counter: it lasts as long as the work it
+    waits for, on another thread, and would own that work's seconds."""
+    global _session_active, _TraceAnnotation
+    if _session_active is None:  # first use: jax stays a lazy import
+        from jax.profiler import TraceAnnotation
+
+        _TraceAnnotation = TraceAnnotation
+        _session_active = _session_probe()
+    return _TraceAnnotation(name) if _session_active() else NOP_SPAN
+
+
 def current_span() -> Optional[Span]:
     """The innermost live span in this context, or None outside a trace."""
     return _CURRENT.get()

@@ -1,7 +1,7 @@
 """Device-side ingest sort/scatter kernels.
 
 ``field.import_bits → SetFragment.set_many`` is the measured bottleneck
-of the pipelined ingest path (devprof's ``fragment_advance`` stage): the
+of the pipelined ingest path (the ``fragment_advance`` ingest stage): the
 classic path walks rows in Python, calling the native per-row
 gather+scatter once per row. The device formulation splits the work:
 
@@ -23,6 +23,7 @@ oracle; eligibility (size caps + backend/kill-switch rules) lives in
 from __future__ import annotations
 
 import functools
+import time
 from typing import Optional, Tuple
 
 import jax
@@ -31,6 +32,7 @@ import numpy as np
 from jax import lax
 
 from pilosa_tpu import platform
+from pilosa_tpu.obs.stages import record_stage
 from pilosa_tpu.ops import pallas_util as PU
 
 #: lane width of the merge+count kernel's 2-D view of the flat planes.
@@ -160,7 +162,9 @@ def _scatter_chunk(planes: np.ndarray, uslots: np.ndarray,
     pad = _next_pow2(max(n, _BW)) - n
     if pad:
         flat = np.concatenate([flat, np.zeros(pad, dtype=flat.dtype)])
+    t0 = time.perf_counter()
     dev = platform.h2d_copy(flat)
+    record_stage("h2d_copy", time.perf_counter() - t0, nbytes=flat.nbytes)
     with PU.kernel_scope("scatter", addr.size, uslots.size, 2,
                          flat.size):
         merged, cnt = _scatter_merge_pallas(
@@ -178,7 +182,8 @@ def scatter_new_bits_bulk(planes: np.ndarray, slots, cols) -> int:
 
     Gathers only the touched rows, pads each flattened chunk to a power
     of two (bounds jit shape variants), round-trips through
-    ``platform.h2d_copy`` so devprof's ingest h2d accounting sees it.
+    ``platform.h2d_copy``, whose seconds and bytes are the ``h2d_copy``
+    ingest stage.
     Imports wider than one :data:`MAX_FLAT_WORDS` chunk stream a chunked
     grid — the sort/dedup runs once, the sorted unique addresses
     partition cleanly at row-group boundaries, and per-chunk counts sum

@@ -20,6 +20,7 @@ from __future__ import annotations
 import contextlib
 import os
 import re
+import threading
 import time
 
 from pilosa_tpu.analysis import locktrace
@@ -44,6 +45,7 @@ def configure_compile_cache() -> str:
     """
     import jax
 
+    _count_program_builds()
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:
@@ -51,6 +53,43 @@ def configure_compile_cache() -> str:
     path = os.path.join(_CHECKOUT, ".jax_cache")
     jax.config.update("jax_compilation_cache_dir", path)
     return path
+
+
+_BUILD_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+_BUILDS = threading.local()
+_builds_counted = False
+
+
+def _count_program_builds() -> None:
+    """Count every program this process builds, once registered for good:
+    ``device_programs_built_total{source, program}`` and
+    ``device_program_build_seconds_total``. JAX reports ``_BUILD_EVENT``
+    for every program built, compiled or fetched from the persistent
+    cache, with the function's name; a fetch reports ``_CACHE_EVENT``
+    first, on the same thread."""
+    global _builds_counted
+    if _builds_counted:
+        return
+    _builds_counted = True
+    import jax.monitoring
+
+    from pilosa_tpu.obs import metrics as M
+
+    def on_duration(event, seconds, **kw):
+        if event == _CACHE_EVENT:
+            _BUILDS.fetched = True
+        elif event == _BUILD_EVENT:
+            fetched = getattr(_BUILDS, "fetched", False)
+            _BUILDS.fetched = False
+            # the name of a function of this program or of a jnp
+            # operation it calls eagerly: as many values as the code has
+            M.REGISTRY.count(M.METRIC_DEVICE_PROGRAMS_BUILT,
+                             source="cache" if fetched else "compiled",
+                             program=kw.get("fun_name", ""))
+            M.REGISTRY.count(M.METRIC_DEVICE_PROGRAM_BUILD_SECONDS, seconds)
+
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
 
 
 def device_facts() -> dict:

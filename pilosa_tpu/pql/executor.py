@@ -37,7 +37,9 @@ from pilosa_tpu.core.field import Field
 from pilosa_tpu.core.holder import Holder
 from pilosa_tpu.core.index import EXISTENCE_ROW, Index
 from pilosa_tpu.core.schema import FieldType
-from pilosa_tpu.core.stacked import StackedBSI, StackedSet, stacked_bsi, stacked_set
+from pilosa_tpu.core.stacked import (StackedBSI, StackedSet, stacked_bsi,
+                                     stacked_set, writer_wait)
+from pilosa_tpu.obs.tracing import get_tracer
 from pilosa_tpu.ops import bitmap as B
 from pilosa_tpu.ops import bsi as S
 from pilosa_tpu.ops import topk as T
@@ -291,14 +293,18 @@ class Executor:
                 return self._execute_query(idx, query, shards)
             except StackStale:
                 continue
-        with self.holder.write_lock:
+        with writer_wait(self.holder.write_lock):
             return self._execute_query(idx, query, shards)
 
     def _execute_query(self, idx: Index, query: Query, shards) -> List[Any]:
         raw = [self._execute_call(idx, call, shards) for call in query.calls]
-        # Overlap all device->host copies, then block once.
-        _start_copies(raw)
-        return [_resolve(r) for r in raw]
+        # Overlap all device->host copies, then block once. A span of
+        # the sampled tree only: the profiler already names what runs
+        # inside (``np.asarray(jax.Array)``), and an annotation of ours
+        # around it would take those seconds from their owner.
+        with get_tracer().start_span("pql.fetch"):
+            _start_copies(raw)
+            return [_resolve(r) for r in raw]
 
     # Capability flag for the scheduler's superset fusion (sched/batch.py
     # probes it before routing heterogeneous shard sets here).
@@ -382,7 +388,7 @@ class Executor:
                 return self._execute_many(idx, qs, shards, plans)
             except StackStale:
                 continue
-        with self.holder.write_lock:
+        with writer_wait(self.holder.write_lock):
             if plans is None:
                 return self._execute_many(idx, qs, shards)
             return self._execute_many(idx, qs, shards, plans)
@@ -453,9 +459,10 @@ class Executor:
             raw = [[self._execute_call(idx, call, s, mask)
                     for call in q.calls]
                    for q, (s, mask) in zip(qs, plans)]
-        for rq in raw:
-            _start_copies(rq)
-        return [[_resolve(r) for r in rq] for rq in raw]
+        with get_tracer().start_span("pql.fetch"):
+            for rq in raw:
+                _start_copies(rq)
+            return [[_resolve(r) for r in rq] for rq in raw]
 
     # -- dispatch (reference: executor.go:679 executeCall) --------------------
 

@@ -26,12 +26,16 @@ import base64
 import json
 import re
 import threading
+import time
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Optional, Tuple
 
 from pilosa_tpu.api import API
 from pilosa_tpu.errors import (AdmissionError, ClusterStateError,
                                QueryDeadlineError)
+from pilosa_tpu.obs import metrics as M
+from pilosa_tpu.obs.stages import record_stage
+from pilosa_tpu.obs.tracing import annotate
 
 _ROUTES = [
     # node-to-node endpoints (reference: http_handler.go:552-585 /internal/*)
@@ -240,13 +244,31 @@ class Handler(BaseHTTPRequestHandler):
 
     def _body(self) -> bytes:
         length = int(self.headers.get("Content-Length") or 0)
-        return self.rfile.read(length) if length else b""
+        if not length:
+            return b""
+        with annotate("http.read"):
+            return self.rfile.read(length)
 
     def _json_body(self) -> dict:
         raw = self._body()
         if not raw:
             return {}
         return json.loads(raw)
+
+    def _import_body(self, route: str) -> dict:
+        """``_json_body`` of an import route: the body's bytes are the
+        user bytes the WAL bytes are held against, and parsing it is the
+        write path's ``decode`` stage."""
+        raw = self._body()
+        M.REGISTRY.count(M.METRIC_HTTP_REQUEST_BODY_BYTES, len(raw),
+                         route=route)
+        if not raw:
+            return {}
+        t0 = time.perf_counter()
+        with annotate("import.decode"):
+            body = json.loads(raw)
+        record_stage("decode", time.perf_counter() - t0, nbytes=len(raw))
+        return body
 
     @staticmethod
     def _require(body: dict, key: str):
@@ -271,15 +293,17 @@ class Handler(BaseHTTPRequestHandler):
             if isinstance(payload, dict):
                 payload = dict(payload)
                 payload["trace"] = sp.to_json()
-        data = json.dumps(payload).encode()
-        self.send_response(code)
-        self.send_header("Content-Type", "application/json")
-        self.send_header("Content-Length", str(len(data)))
-        for k, v in (headers or {}).items():
-            self.send_header(k, v)
-        self._emit_cookies()
-        self.end_headers()
-        self.wfile.write(data)
+        with annotate("http.encode"):
+            data = json.dumps(payload).encode()
+        with annotate("http.write"):
+            self.send_response(code)
+            self.send_header("Content-Type", "application/json")
+            self.send_header("Content-Length", str(len(data)))
+            for k, v in (headers or {}).items():
+                self.send_header(k, v)
+            self._emit_cookies()
+            self.end_headers()
+            self.wfile.write(data)
 
     def _emit_cookies(self) -> None:
         for header in getattr(self, "_pending_cookies", ()):
@@ -623,7 +647,7 @@ class Handler(BaseHTTPRequestHandler):
                 shed()
 
     def post_import(self, index: str):
-        b = self._json_body()
+        b = self._import_body("post_import")
         self._degrade_shed_import(b)
         self._charge_tenant_ingest(len(b.get("cols") or []), b)
         peer = self._gossip_apply(b)
@@ -643,7 +667,7 @@ class Handler(BaseHTTPRequestHandler):
         """
         import base64
 
-        b = self._json_body()
+        b = self._import_body("post_import_roaring")
         # roaring blobs don't expose a row count pre-decode; charge one
         # unit per view as a coarse rate signal
         self._charge_tenant_ingest(len(b.get("views") or {}), b)
@@ -656,7 +680,7 @@ class Handler(BaseHTTPRequestHandler):
         self._send(200, self._gossip_reply(peer, {"success": True}))
 
     def post_import_values(self, index: str):
-        b = self._json_body()
+        b = self._import_body("post_import_values")
         self._degrade_shed_import(b)
         self._charge_tenant_ingest(len(b.get("cols") or []), b)
         peer = self._gossip_apply(b)
@@ -1574,14 +1598,16 @@ class Handler(BaseHTTPRequestHandler):
 
 def serve(api: API, host: str = "127.0.0.1", port: int = 10101,
           background: bool = False, maintenance_interval_s: Optional[float] = None,
-          auth=None
+          auth=None, on_listening=None
           ) -> Tuple[ThreadingHTTPServer, Optional[threading.Thread]]:
     """Start the HTTP server (reference: server.go:618 Open + listener).
     With background=True returns (server, thread) for in-process use —
     the test harness pattern (reference: test/cluster.go). A maintenance
     interval starts the TTL view-removal loop (reference: server.go:902
     ViewsRemoval ticker). ``auth`` (a server.auth.Auth) enables per-route
-    JWT gating (reference: http_handler.go chkAuthZ)."""
+    JWT gating (reference: http_handler.go chkAuthZ). ``on_listening`` is
+    called once the socket is bound and accepts connections, before the
+    first request is served."""
     handler = type("BoundHandler", (Handler,), {"api": api, "auth": auth})
 
     class _Server(ThreadingHTTPServer):
@@ -1603,6 +1629,8 @@ def serve(api: API, host: str = "127.0.0.1", port: int = 10101,
             super().shutdown()
 
     srv = _Server((host, port), handler)
+    if on_listening is not None:
+        on_listening()
     if maintenance_interval_s:
         from pilosa_tpu.server.maintenance import MaintenanceLoop
 

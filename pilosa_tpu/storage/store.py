@@ -16,11 +16,14 @@ from __future__ import annotations
 import glob
 import os
 import re
+import time
 from typing import TYPE_CHECKING
 
 import numpy as np
 
 from pilosa_tpu.core.fragment import BSIFragment, SetFragment
+from pilosa_tpu.obs import metrics as M
+from pilosa_tpu.obs.tracing import annotate
 from pilosa_tpu.ops import bsi as bsiops
 
 if TYPE_CHECKING:
@@ -38,37 +41,53 @@ def _bsi_dir(idx_path: str, field: str) -> str:
 
 
 def save_holder_data(holder: "Holder") -> None:
-    """Persist every fragment (plus schema). Atomic per-file via tmp+rename
-    (the coarse analog of the reference's RBF checkpoint, rbf/db.go:149)."""
+    """Persist every fragment (plus schema), dirty or not. Atomic per-file
+    via tmp+rename (the coarse analog of the reference's RBF checkpoint,
+    rbf/db.go:149). Each file counts as ``changed`` or ``unchanged``
+    against the versions the last completed save wrote
+    (``holder.saved_versions``, path -> (fragment, version): the fragment
+    itself, so that a field dropped and made again never passes for its
+    predecessor): how much of a checkpoint rewrites what the disk already
+    holds."""
     if not holder.path:
         raise ValueError("holder has no data dir")
     holder.save_schema()
+    before, saved = holder.saved_versions, {}
+
+    def save(path: str, frag, **arrays) -> None:
+        was, version = before.get(path, (None, None))
+        state = "unchanged" if was is frag and version == frag.version \
+            else "changed"
+        M.REGISTRY.count(M.METRIC_RECOVERY_CHECKPOINT_FRAGMENTS, state=state)
+        saved[path] = (frag, frag.version)
+        _atomic_savez(path, **arrays)
+
     for idx in holder.indexes.values():
         idx_path = holder._index_path(idx.name)
         for field in idx.fields.values():
             for view, frags in field.views.items():
                 for shard, frag in frags.items():
                     n = len(frag.row_ids)
-                    _atomic_savez(
-                        os.path.join(_views_dir(idx_path, field.name), view,
-                                     f"frag.{shard}.npz"),
-                        planes=frag.planes[:n],
-                        row_ids=np.asarray(frag.row_ids, dtype=np.uint64),
-                    )
+                    save(os.path.join(_views_dir(idx_path, field.name), view,
+                                      f"frag.{shard}.npz"), frag,
+                         planes=frag.planes[:n],
+                         row_ids=np.asarray(frag.row_ids, dtype=np.uint64))
             for shard, bfrag in field.bsi.items():
-                _atomic_savez(
-                    os.path.join(_bsi_dir(idx_path, field.name),
-                                 f"frag.{shard}.npz"),
-                    planes=bfrag.planes,
-                )
+                save(os.path.join(_bsi_dir(idx_path, field.name),
+                                  f"frag.{shard}.npz"), bfrag,
+                     planes=bfrag.planes)
         idx.dataframe.save()
+    holder.saved_versions = saved
 
 
 def load_holder_data(holder: "Holder") -> None:
     """Discover and load fragment files for all schema-known fields
-    (reference: dbshard.go:241 LoadExistingDBs + view.openWithShardSet)."""
+    (reference: dbshard.go:241 LoadExistingDBs + view.openWithShardSet).
+    What is loaded is what the disk holds, so it seeds
+    ``holder.saved_versions`` (see ``save_holder_data``)."""
     if not holder.path:
         return
+    saved = holder.saved_versions
     for idx in holder.indexes.values():
         idx_path = holder._index_path(idx.name)
         for field in idx.fields.values():
@@ -85,6 +104,7 @@ def load_holder_data(holder: "Holder") -> None:
                         frag = field.fragment(shard, view, create=True)
                         for slot, row in enumerate(row_ids.tolist()):
                             frag.import_row_plane(int(row), planes[slot], clear=True)
+                        saved[path] = (frag, frag.version)
             for path in glob.glob(os.path.join(_bsi_dir(idx_path, field.name),
                                                "frag.*.npz")):
                 m = _FRAG_RE.search(path)
@@ -97,6 +117,7 @@ def load_holder_data(holder: "Holder") -> None:
                 bfrag.depth = planes.shape[0] - bsiops.OFFSET
                 bfrag.planes = planes.copy()
                 bfrag.version += 1
+                saved[path] = (bfrag, bfrag.version)
         idx.dataframe.load()
 
 
@@ -184,16 +205,32 @@ def _atomic_savez(path: str, **arrays) -> None:
         return
     os.makedirs(os.path.dirname(path), exist_ok=True)
     tmp = path + ".tmp"
+    count = M.REGISTRY.count
+    t0 = time.perf_counter()
     with open(tmp, "wb") as f:
-        np.savez_compressed(f, **arrays)
-        f.flush()
-        os.fsync(f.fileno())
-    if plan is not None and not plan.fire("savez.pre_replace"):
-        return
-    os.replace(tmp, path)
-    if plan is not None and not plan.fire("savez.post_replace"):
-        return
-    fsync_dir(os.path.dirname(path))
+        with annotate("checkpoint.serialize"):
+            np.savez_compressed(f, **arrays)
+        t1 = time.perf_counter()
+        count(M.METRIC_RECOVERY_CHECKPOINT_PHASE_SECONDS, t1 - t0,
+              phase="serialize")
+        count(M.METRIC_RECOVERY_CHECKPOINT_BYTES,
+              sum(np.asarray(a).nbytes for a in arrays.values()), kind="raw")
+        count(M.METRIC_RECOVERY_CHECKPOINT_BYTES, f.tell(), kind="stored")
+        with annotate("checkpoint.fsync"):
+            f.flush()
+            os.fsync(f.fileno())
+    try:
+        if plan is not None and not plan.fire("savez.pre_replace"):
+            return
+        with annotate("checkpoint.fsync"):
+            os.replace(tmp, path)
+            if plan is not None and not plan.fire("savez.post_replace"):
+                return
+            fsync_dir(os.path.dirname(path))
+    finally:
+        # file fsync + rename + directory fsync, as they accrue
+        count(M.METRIC_RECOVERY_CHECKPOINT_PHASE_SECONDS,
+              time.perf_counter() - t1, phase="fsync")
 
 
 def export_shard_arrays(idx, shard: int) -> dict:

@@ -17,7 +17,11 @@ unit (the analog of StartAtomicWriteTx, txfactory.go:344).
 from __future__ import annotations
 
 import threading
+import time
 from typing import TYPE_CHECKING
+
+from pilosa_tpu.obs.stages import record_stage
+from pilosa_tpu.obs.tracing import get_tracer
 
 if TYPE_CHECKING:
     from pilosa_tpu.core.holder import Holder
@@ -52,8 +56,13 @@ class Qcx:
         # Exclude concurrent writers AND checkpoints for the request: a
         # checkpoint racing a half-applied multi-call write would snapshot
         # and truncate records it never persisted. RLock so nested Qcx
-        # (query -> import helpers) is fine.
+        # (query -> import helpers) is fine. The wait is the write
+        # path's ``lock_wait`` stage: behind another writer, a checkpoint
+        # or a reader's stack build (a counter and no profiler leaf, like
+        # stacked.writer_wait: the holder's work owns those seconds).
+        t0 = time.perf_counter()
         self.holder.write_lock.acquire()
+        record_stage("lock_wait", time.perf_counter() - t0)
         _WRITE_CTX.depth = getattr(_WRITE_CTX, "depth", 0) + 1
 
     def finish(self) -> int:
@@ -63,13 +72,13 @@ class Qcx:
         if self._done:
             return self.lsn
         self._done = True
-        from pilosa_tpu.obs.tracing import get_tracer
-
         try:
             with get_tracer().start_span("storage.wal.commit"):
                 self.holder.flush_wals()
                 self.lsn = self.holder.last_lsn()
-                self.holder.maybe_checkpoint()
+                t0 = time.perf_counter()
+                if self.holder.maybe_checkpoint():
+                    record_stage("checkpoint", time.perf_counter() - t0)
         finally:
             _WRITE_CTX.depth -= 1
             self.holder.write_lock.release()

@@ -47,7 +47,8 @@ import zlib
 from typing import Iterator, List, Optional, Tuple
 
 from pilosa_tpu.analysis import locktrace
-from pilosa_tpu.obs import devprof
+from pilosa_tpu.obs.stages import record_stage
+from pilosa_tpu.obs.tracing import annotate
 
 # crc32 over (lsn bytes || payload), payload length, lsn
 _HDR = struct.Struct("<IIQ")
@@ -174,7 +175,7 @@ class WAL:
         # barrier (None when clean) — the health plane's WAL-stall read
         self._dirty_since: Optional[float] = None
         # bytes appended since the last write barrier — the wal_commit
-        # ingest-stage byte count (devprof)
+        # ingest-stage byte count (obs/stages.py)
         self._pending_flush_bytes = 0
         self._open_existing()
 
@@ -309,20 +310,13 @@ class WAL:
     def _flush_locked(self) -> None:
         if not self._dirty:
             return
-        if not devprof.ENABLED:
+        t0 = time.perf_counter()
+        with annotate("import.wal_commit"):
             self._f.flush()
             if self.sync != "never":
                 os.fsync(self._f.fileno())
-            self._pending_flush_bytes = 0
-            self._dirty = False
-            self._dirty_since = None
-            return
-        t0 = time.perf_counter()
-        self._f.flush()
-        if self.sync != "never":
-            os.fsync(self._f.fileno())
-        devprof.record_stage("wal_commit", time.perf_counter() - t0,
-                             nbytes=self._pending_flush_bytes)
+        record_stage("wal_commit", time.perf_counter() - t0,
+                     nbytes=self._pending_flush_bytes)
         self._pending_flush_bytes = 0
         self._dirty = False
         self._dirty_since = None

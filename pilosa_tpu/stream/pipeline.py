@@ -50,7 +50,7 @@ import numpy as np
 from pilosa_tpu.core.schema import FieldType
 from pilosa_tpu.errors import AdmissionError
 from pilosa_tpu.ingest.idalloc import IDAllocator
-from pilosa_tpu.obs import devprof
+from pilosa_tpu.obs.stages import record_stage
 from pilosa_tpu.obs import metrics as M
 from pilosa_tpu.sched.clock import MonotonicClock
 from pilosa_tpu.storage.recovery import SimulatedCrash
@@ -181,14 +181,7 @@ class PipelinedIngester:
     def _translate(self, store, raw) -> np.ndarray:
         from pilosa_tpu.core.translate import bulk_translate_ids
 
-        keys = [str(k) for k in raw]
-        if not devprof.ENABLED:
-            return bulk_translate_ids(store, keys)
-        t0 = time.perf_counter()
-        out = bulk_translate_ids(store, keys)
-        devprof.record_stage("key_translate", time.perf_counter() - t0,
-                             rows=len(keys))
-        return out
+        return bulk_translate_ids(store, [str(k) for k in raw])
 
     def _record_ids(self, values, records):
         idf = self.id_field
@@ -364,13 +357,10 @@ class PipelinedIngester:
                     self.batch_rows, timeout_s=self.poll_timeout_s)
                 if not records:
                     break  # drained
-                if devprof.ENABLED:
-                    t0 = time.perf_counter()
-                    batch = self._prepare(records)
-                    devprof.record_stage(
-                        "parse", time.perf_counter() - t0, rows=batch.n)
-                else:
-                    batch = self._prepare(records)
+                t0 = time.perf_counter()
+                batch = self._prepare(records)
+                record_stage("parse", time.perf_counter() - t0,
+                             rows=batch.n)
                 self._fire("stream.handoff")
                 self._enqueue(batch)
                 n += 1
@@ -387,9 +377,7 @@ class PipelinedIngester:
 
     def _apply(self, batch: PreparedBatch) -> None:
         idx = self._idx
-        scope = devprof.ingest_scope() if devprof.ENABLED \
-            else devprof.NULL_SCOPE
-        with scope, self.api.txf.qcx():
+        with self.api.txf.qcx():
             self._fire("stream.apply")
             for kind, fname, a, b in batch.ops:
                 fld = idx.field(fname)

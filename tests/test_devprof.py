@@ -21,7 +21,8 @@ import pytest
 from pilosa_tpu import platform
 from pilosa_tpu.api import API
 from pilosa_tpu.config import Config
-from pilosa_tpu.obs import devprof
+from pilosa_tpu.obs import devprof, stages
+from pilosa_tpu.obs import metrics as M
 from pilosa_tpu.shardwidth import SHARD_WIDTH
 
 SHARDS = 2
@@ -224,7 +225,7 @@ class TestKernelProfileRegistry:
         assert len(reg.snapshot(limit=3)) == 3
 
     def test_ingest_accounting_rates(self):
-        acc = devprof.IngestAccounting()
+        acc = stages.IngestAccounting()
         acc.record("parse", 0.5, rows=1000)
         acc.record("parse", 0.5, rows=1000)
         acc.record("wal_commit", 0.25, nbytes=1 << 20)
@@ -234,6 +235,18 @@ class TestKernelProfileRegistry:
         assert snap["parse"]["rows_per_s"] == pytest.approx(2000.0)
         assert snap["wal_commit"]["bytes_per_s"] == pytest.approx(
             (1 << 20) / 0.25)
+
+    def test_ingest_accounting_publishes_counters_not_rates(self):
+        # the derived rate gauges are gone: a rate is a scrape delta of
+        # the counters they were computed from
+        before = M.REGISTRY.value(M.METRIC_INGEST_STAGE_ROWS, stage="parse")
+        stages.IngestAccounting().record("parse", 0.5, rows=1000)
+        assert M.REGISTRY.value(M.METRIC_INGEST_STAGE_ROWS,
+                                stage="parse") == before + 1000
+        text = M.REGISTRY.prometheus_text()
+        assert "ingest_stage_seconds_total" in text
+        assert "ingest_stage_rows_per_s" not in text
+        assert "ingest_stage_bytes_per_s" not in text
 
 
 # ---------------------------------------------------------------------------
@@ -306,16 +319,23 @@ class TestGating:
 
 
 class TestHooks:
-    def test_h2d_attributed_to_ingest_only_in_scope(self, profiled):
+    def test_h2d_attributed_to_ingest_only_in_scatter(self, profiled,
+                                                      monkeypatch):
+        from pilosa_tpu.core.fragment import SetFragment
+
         host = np.zeros(1024, dtype=np.uint32)
-        platform.h2d_copy(host)  # outside any ingest scope
+        platform.h2d_copy(host)  # a read-side staging copy
         assert devprof.KERNELS.h2d_copies == 1
-        assert "h2d_copy" not in devprof.INGEST.snapshot()
-        with devprof.ingest_scope():
-            platform.h2d_copy(host)
-        assert devprof.KERNELS.h2d_copies == 2
-        stage = devprof.INGEST.snapshot()["h2d_copy"]
-        assert stage["bytes"] == host.nbytes
+        assert "h2d_copy" not in stages.INGEST.snapshot()
+        # the ingest stage is the device scatter's own upload, taken at
+        # its call site with or without the kernel-profiling hooks
+        monkeypatch.setenv("PILOSA_TPU_PALLAS", "1")
+        monkeypatch.delenv("PILOSA_TPU_NO_PALLAS", raising=False)
+        rng = np.random.default_rng(5)
+        SetFragment(0, words=1 << 9).set_many(
+            rng.integers(0, 8, size=500), rng.integers(0, 1 << 14, size=500))
+        assert devprof.KERNELS.h2d_copies >= 2
+        assert stages.INGEST.snapshot()["h2d_copy"]["bytes"] > 0
 
     def test_kernel_scope_nests_and_restores(self, profiled):
         outer = ("count", (("and", 0, 1),), 2, False, 64)
@@ -345,7 +365,7 @@ class TestIngestStages:
         src = CSVSource(self.CSV, inline=True)
         n = Ingester(api, "cities", src).run()
         assert n == 300
-        snap = devprof.INGEST.snapshot()
+        snap = stages.INGEST.snapshot()
         assert snap["parse"]["rows"] == 300
         assert snap["parse"]["rows_per_s"] > 0
         # city__S is keyed -> bulk translation is timed
@@ -362,16 +382,25 @@ class TestIngestStages:
         # path: no whole-file parse stage, but fragment advance is timed
         api = API()
         Ingester(api, "cust", scenario("customer", rows=100)).run()
-        snap = devprof.INGEST.snapshot()
+        snap = stages.INGEST.snapshot()
         assert snap["fragment_advance"]["rows"] > 0
 
-    def test_disabled_ingest_records_nothing(self, unprofiled, tmp_path):
+    def test_disabled_devprof_still_records_ingest_stages(self, unprofiled,
+                                                          tmp_path):
+        # PILOSA_TPU_DEVPROF switches the kernel cost model only; the
+        # ingest stage counters are always on
         from pilosa_tpu.ingest.ingest import Ingester
         from pilosa_tpu.ingest.source import CSVSource
 
         api = API(str(tmp_path))
         Ingester(api, "cities", CSVSource(self.CSV, inline=True)).run()
-        assert devprof.INGEST.snapshot() == {}
+        snap = stages.INGEST.snapshot()
+        for stage in ("parse", "key_translate", "lock_wait",
+                      "fragment_advance", "wal_commit"):
+            assert snap[stage]["seconds"] > 0, stage
+        assert snap["parse"]["rows"] == 300
+        assert snap["wal_commit"]["bytes"] > 0
+        assert devprof.KERNELS.profile_count() == 0
 
 
 # ---------------------------------------------------------------------------

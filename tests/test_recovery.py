@@ -717,3 +717,109 @@ class TestRecoveryMetrics:
             assert reg.value(M.METRIC_RECOVERY_CATCHUP_SHARDS) > 0
             h = reg.histogram(M.METRIC_RECOVERY_CATCHUP_LAG_MS)
             assert h is not None and h["count"] == 1
+
+
+class TestCheckpointAccrual:
+    """The checkpoint's counters accrue per phase and per file, so that a
+    window's delta is the window's (``recovery_checkpoint_seconds`` lands
+    once, at the end)."""
+
+    PHASES = ("wal_flush", "serialize", "fsync", "meta", "prune")
+
+    @staticmethod
+    def _phases():
+        return {p: M.REGISTRY.value(
+            M.METRIC_RECOVERY_CHECKPOINT_PHASE_SECONDS, phase=p)
+            for p in TestCheckpointAccrual.PHASES}
+
+    @staticmethod
+    def _fragments():
+        return {s: M.REGISTRY.value(
+            M.METRIC_RECOVERY_CHECKPOINT_FRAGMENTS, state=s)
+            for s in ("changed", "unchanged")}
+
+    @staticmethod
+    def _bytes():
+        return {k: M.REGISTRY.value(
+            M.METRIC_RECOVERY_CHECKPOINT_BYTES, kind=k)
+            for k in ("raw", "stored")}
+
+    @staticmethod
+    def _holder(tmp_path, rows=40):
+        import numpy as np
+
+        api = API(str(tmp_path / "a"))
+        api.create_index("i")
+        api.create_field("i", "f")
+        api.create_field("i", "g")
+        api.create_field("i", "v", {"type": "int", "min": 0, "max": 1 << 20})
+        cols = np.arange(0, 2 * SHARD_WIDTH, 97)
+        api.import_bits("i", "f", rows=cols % rows, cols=cols)
+        api.import_bits("i", "g", rows=cols % 3, cols=cols)
+        api.import_values("i", "v", cols=cols, values=cols % 1000)
+        return api
+
+    def test_every_phase_moves_and_sums_to_the_summary(self, tmp_path):
+        api = self._holder(tmp_path)
+        phases = self._phases()
+        n, total = M.REGISTRY.summary(M.METRIC_RECOVERY_CHECKPOINT_SECONDS)
+        api.holder.checkpoint()
+        moved = {p: v - phases[p] for p, v in self._phases().items()}
+        assert all(v > 0 for v in moved.values()), moved
+        n1, total1 = M.REGISTRY.summary(M.METRIC_RECOVERY_CHECKPOINT_SECONDS)
+        assert n1 == n + 1
+        assert sum(moved.values()) == pytest.approx(total1 - total, rel=0.10)
+        assert sum(moved.values()) <= total1 - total
+
+    def test_untouched_fragment_counts_unchanged_written_one_changed(
+            self, tmp_path):
+        api = self._holder(tmp_path)
+        first = self._fragments()
+        api.holder.checkpoint()
+        after_first = self._fragments()
+        # nothing was on disk: every file is new
+        files = after_first["changed"] - first["changed"]
+        assert files == 8  # (f, g, _exists, v) x 2 shards
+        assert after_first["unchanged"] == first["unchanged"]
+        api.import_bits("i", "g", rows=[1], cols=[5])  # shard 0 of g
+        api.holder.checkpoint()
+        second = self._fragments()
+        # g and _exists of shard 0 advanced; the other six did not
+        assert second["changed"] - after_first["changed"] == 2
+        assert second["unchanged"] - after_first["unchanged"] == 6
+
+    def test_recovery_seeds_the_saved_versions(self, tmp_path):
+        api = self._holder(tmp_path)
+        api.holder.checkpoint()
+        api.import_bits("i", "f", rows=[2], cols=[SHARD_WIDTH + 9])
+        api.holder.flush_wals()
+        again = API(str(tmp_path / "a"))  # load checkpoint + replay tail
+        before = self._fragments()
+        again.holder.checkpoint()
+        after = self._fragments()
+        # the replayed record touched f and _exists of shard 1 only
+        assert after["changed"] - before["changed"] == 2
+        assert after["unchanged"] - before["unchanged"] == 6
+
+    def test_sparse_planes_store_smaller_than_raw(self, tmp_path):
+        api = self._holder(tmp_path)
+        before = self._bytes()
+        api.holder.checkpoint()
+        got = {k: v - before[k] for k, v in self._bytes().items()}
+        on_disk = sum(
+            os.path.getsize(os.path.join(d, f))
+            for d, _, fs in os.walk(str(tmp_path / "a" / "indexes"))
+            for f in fs if f.endswith(".npz"))
+        assert got["stored"] == on_disk
+        assert 0 < got["stored"] <= got["raw"]
+
+    def test_startup_phases_set_by_recover(self, tmp_path):
+        self._holder(tmp_path).holder.checkpoint()
+        M.REGISTRY.gauge(M.METRIC_STARTUP_PHASE_SECONDS, -1.0,
+                         phase="load_checkpoint")
+        M.REGISTRY.gauge(M.METRIC_STARTUP_PHASE_SECONDS, -1.0,
+                         phase="wal_replay")
+        API(str(tmp_path / "a"))
+        for phase in ("load_checkpoint", "wal_replay"):
+            assert M.REGISTRY.value(M.METRIC_STARTUP_PHASE_SECONDS,
+                                    phase=phase) >= 0

@@ -150,24 +150,21 @@ class TestPipelineIdentity:
     def test_devprof_stage_gauges(self, tmp_path):
         # the pipeline's host/device split shows up as distinct ingest
         # stages — the overlap evidence the kernel plane reports
-        from pilosa_tpu.obs import devprof
+        from pilosa_tpu.obs import stages
 
-        was = devprof.ENABLED
-        devprof.enable()
-        devprof.INGEST.reset()
+        stages.INGEST.reset()
         try:
             recs = customer_records(rows=600)
             src = scenario("customer", rows=600, seed=5)
             broker = make_broker(recs)
             api, p = pipelined_run(str(tmp_path), broker, src.schema())
             p.run()
-            stages = devprof.INGEST.snapshot()
-            assert "parse" in stages  # host side
-            assert "fragment_advance" in stages  # device side
-            assert "key_translate" in stages  # host-side bulk translate
+            seen = stages.INGEST.snapshot()
+            assert "parse" in seen  # host side
+            assert "fragment_advance" in seen  # device side
+            assert "key_translate" in seen  # host-side bulk translate
         finally:
-            devprof.INGEST.reset()
-            devprof.enable() if was else devprof.disable()
+            stages.INGEST.reset()
 
 
 # -- chunked messages (the Kafka batch-per-message production shape) ----------

@@ -466,3 +466,183 @@ class TestMetricsExposition:
                    if k.startswith("trace_duration_ms"))
         assert dur["count"] == 1
         assert any(k.startswith("trace_stage_latency_ms") for k in hists)
+
+
+# ---------------------------------------------------------------------------
+# Leaf annotations on the profiler's clock (tracing.annotate)
+# ---------------------------------------------------------------------------
+
+#: every annotation this program emits. Containers (query.pql,
+#: device.dispatch, pql.fetch, storage.wal.commit, stack.build) are spans
+#: of the sampled tree only, and waits (a reader behind the write lock, a
+#: writer behind a reader's build) are counters only: both would take an
+#: idle gap's seconds from the event that did the work
+READ_LEAVES = ("http.read", "pql.parse", "http.encode", "http.write")
+WRITE_LEAVES = ("import.decode", "import.key_translate",
+                "import.fragment_advance", "import.wal_commit",
+                "checkpoint.serialize", "checkpoint.fsync",
+                "checkpoint.meta", "checkpoint.prune")
+OUR_PREFIXES = ("http.", "pql.", "import.", "checkpoint.", "stack.",
+                "query.", "device.", "storage.")
+
+
+class TestAnnotateNopPath:
+    def test_no_session_returns_the_one_shared_span(self, nop_global):
+        t = T.get_tracer()
+        got = {id(T.annotate("http.read")), id(t.start_span("pql.parse")),
+               id(t.start_trace("query.pql")), id(NOP_SPAN)}
+        assert got == {id(NOP_SPAN)}
+
+    def test_no_session_allocates_nothing(self, nop_global):
+        import tracemalloc
+
+        t = T.get_tracer()
+
+        def request():
+            with T.annotate("http.read"):
+                pass
+            with T.annotate("pql.parse"), t.start_span("pql.parse"):
+                pass
+            with t.start_span("pql.fetch"):
+                pass
+
+        request()  # resolves the lazy imports
+        tracemalloc.start()
+        try:
+            before = tracemalloc.take_snapshot()
+            for _ in range(200):
+                request()
+            after = tracemalloc.take_snapshot()
+        finally:
+            tracemalloc.stop()
+        here = [tracemalloc.Filter(True, T.__file__)]
+        grown = sum(s.size_diff for s in after.filter_traces(here)
+                    .compare_to(before.filter_traces(here), "filename"))
+        assert grown == 0
+
+    def test_probe_missing_falls_back_to_always_annotate(self, monkeypatch):
+        import builtins
+
+        import jax
+
+        real = builtins.__import__
+
+        def no_private(name, *a, **kw):
+            if name == "jax._src.lib":
+                raise ImportError(name)
+            return real(name, *a, **kw)
+
+        monkeypatch.setattr(builtins, "__import__", no_private)
+        monkeypatch.setattr(T, "_session_active", None)
+        span = T.annotate("http.read")
+        assert isinstance(span, jax.profiler.TraceAnnotation)
+        with span:  # outside a session it records nothing and is harmless
+            pass
+
+
+@pytest.fixture(scope="module")
+def host_trace(tmp_path_factory):
+    """One ``jax.profiler`` session on the CPU backend over a served
+    import (keyed set field and int field), two served reads and a
+    checkpoint. Returns {thread line: [(name, start_ns, end_ns), ...]}
+    of the host plane."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    from pilosa_tpu.api import API
+    from pilosa_tpu.server.http import serve
+
+    root = tmp_path_factory.mktemp("host_trace")
+    api = API(str(root / "data"))
+    srv, _ = serve(api, port=0, background=True)
+    base = f"http://127.0.0.1:{srv.server_address[1]}"
+
+    def post(path, body):
+        data = body.encode() if isinstance(body, str) \
+            else json.dumps(body).encode()
+        with urllib.request.urlopen(urllib.request.Request(
+                base + path, data=data, method="POST")) as resp:
+            return json.loads(resp.read())
+
+    cols = list(range(4000))
+
+    def load(offset):
+        post("/index/i/import", {
+            "field": "f", "cols": [c + offset for c in cols],
+            "rowKeys": [f"k{c % 7}" for c in cols]})
+        post("/index/i/import-values", {
+            "field": "v", "cols": [c + offset for c in cols],
+            "values": [c % 1000 for c in cols]})
+        return (post("/index/i/query", "Count(Row(f=k1))")["results"],
+                post("/index/i/query", "Sum(field=v)")["results"][0]["count"])
+
+    try:
+        post("/index/i", {})
+        post("/index/i/field/f", {"options": {"type": "set", "keys": True}})
+        post("/index/i/field/v",
+             {"options": {"type": "int", "min": 0, "max": 1000}})
+        load(0)  # compiles outside the session
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(str(root / "trace"), profiler_options=opts)
+        try:
+            assert load(len(cols)) == ([2 * 572], 2 * len(cols))
+            api.holder.checkpoint()
+        finally:
+            jax.profiler.stop_trace()
+    finally:
+        srv.shutdown()
+        srv.server_close()
+    pb, = glob.glob(str(root / "trace" / "plugins" / "profile" / "*"
+                        / "*.xplane.pb"))
+    lines = {}
+    for plane in ProfileData.from_file(pb).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for i, line in enumerate(plane.lines):
+            events = [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+                      for e in line.events]
+            if events:
+                lines[f"{line.name}#{i}"] = events
+    return lines
+
+
+def _ours(events):
+    return [e for e in events if e[0].startswith(OUR_PREFIXES)]
+
+
+class TestAnnotationsOnTheProfilersClock:
+    @pytest.mark.parametrize("name", READ_LEAVES + WRITE_LEAVES)
+    def test_leaf_is_in_the_host_plane(self, host_trace, name):
+        assert any(e[0] == name for ev in host_trace.values() for e in ev)
+
+    def test_containers_stay_out_of_the_profile(self, host_trace):
+        seen = {e[0] for ev in host_trace.values() for e in _ours(ev)}
+        assert seen <= set(READ_LEAVES + WRITE_LEAVES), seen
+        # named explicitly: these would swallow every owner inside them
+        # or, for the waits, on the lock holder's thread
+        for container in ("query.pql", "device.dispatch", "pql.fetch",
+                          "storage.wal.commit", "stack.build",
+                          "stack.writer_wait", "import.lock_wait"):
+            assert container not in seen
+
+    def test_no_annotation_of_ours_encloses_another(self, host_trace):
+        for line, events in host_trace.items():
+            ours = sorted(_ours(events), key=lambda e: e[1])
+            for (a, _, a_end), (b, b_start, _) in zip(ours, ours[1:]):
+                assert a_end <= b_start, f"{a} overlaps {b} on {line}"
+
+    def test_no_annotation_of_ours_encloses_a_jax_call(self, host_trace):
+        # import.fragment_advance may enclose the device scatter's
+        # PjitFunction (accepted: it is the stage's own dispatch)
+        jax_named = ("PjitFunction(", "np.asarray(", "ArrayImpl.")
+        for line, events in host_trace.items():
+            inner = [e for e in events if e[0].startswith(jax_named)]
+            for name, start, end in _ours(events):
+                if name == "import.fragment_advance":
+                    continue
+                for j, j_start, j_end in inner:
+                    assert not (start <= j_start and j_end <= end), \
+                        f"{name} encloses {j} on {line}"
