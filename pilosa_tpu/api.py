@@ -836,8 +836,9 @@ class API:
     # -- persistence (reference: backup/restore ctl/backup.go) -------------
 
     def save(self) -> None:
-        """Checkpoint: snapshot all planes and truncate the WALs they
-        subsume (reference: rbf checkpoint, rbf/db.go:149)."""
+        """Checkpoint: snapshot the planes that moved since the disk last
+        held them and truncate the WALs the snapshot subsumes (reference:
+        rbf checkpoint, rbf/db.go:149)."""
         if self.holder.path:
             self.holder.checkpoint()
         else:

@@ -50,7 +50,7 @@ class Holder:
         self.write_lock = threading.RLock()
         # snapshot path -> (fragment, version) as the disk holds it: set
         # by load_holder_data and by every completed save_holder_data,
-        # which counts its files as changed or unchanged against it
+        # which skips the files it says the disk already holds
         self.saved_versions: Dict[str, tuple] = {}
         self.indexes: Dict[str, Index] = {}
         if path:
@@ -174,16 +174,20 @@ class Holder:
 
     def checkpoint(self) -> None:
         """Fuzzy checkpoint: flush, capture each index's LSN, snapshot
-        all planes, stamp ``checkpoint.json`` with the LSN, then prune
-        segments wholly below it (reference: rbf checkpoint copying WAL
-        pages into the DB file). A crash between ANY two steps is safe:
-        before the meta write, recovery replays from the old LSN over
-        mixed old/new npz files (every WAL op is plane-idempotent);
-        after it, the snapshot already covers everything the meta
-        claims, and stale segments fall to the next prune. Takes the
-        write lock so a concurrent writer can't append between snapshot
-        and stamp (RLock: a no-op when called from inside the owning
-        Qcx)."""
+        the planes that moved, stamp ``checkpoint.json`` with the LSN,
+        then prune segments wholly below it (reference: rbf checkpoint
+        copying WAL pages into the DB file). A fragment whose file the
+        disk already holds at its present version is not rewritten
+        (``store.save_holder_data``): no record above that file's
+        checkpoint touched it, so the file is the snapshot at the new
+        LSN too. A crash between ANY two steps is safe: before the meta
+        write, recovery replays from the old LSN over mixed old/new npz
+        files (every WAL op is plane-idempotent; a skipped file is an
+        old file that equals the new one); after it, the snapshot
+        already covers everything the meta claims, and stale segments
+        fall to the next prune. Takes the write lock so a concurrent
+        writer can't append between snapshot and stamp (RLock: a no-op
+        when called from inside the owning Qcx)."""
         if not self.path or self.readonly:
             return
         from pilosa_tpu.storage.recovery import (

@@ -153,8 +153,10 @@ METRIC_RECOVERY_CHECKPOINT_SECONDS = "recovery_checkpoint_seconds"
 # the same checkpoint as it accrues, so that a window's delta is the
 # window's (the summary above lands once, when a 35 s checkpoint ends):
 # seconds by phase=wal_flush|serialize|fsync|meta|prune, snapshot files
-# by state=changed|unchanged since the last completed checkpoint, and
-# bytes by kind=raw (the arrays) | stored (the file written)
+# by state=changed (written) | skipped (the disk holds the fragment at
+# its present version: left alone; ``unchanged``, rewritten all the same,
+# is counted by nothing any more), and bytes of the files written by
+# kind=raw (the arrays) | stored (the file)
 METRIC_RECOVERY_CHECKPOINT_PHASE_SECONDS = \
     "recovery_checkpoint_phase_seconds_total"
 METRIC_RECOVERY_CHECKPOINT_FRAGMENTS = "recovery_checkpoint_fragments_total"

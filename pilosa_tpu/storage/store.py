@@ -41,14 +41,22 @@ def _bsi_dir(idx_path: str, field: str) -> str:
 
 
 def save_holder_data(holder: "Holder") -> None:
-    """Persist every fragment (plus schema), dirty or not. Atomic per-file
-    via tmp+rename (the coarse analog of the reference's RBF checkpoint,
-    rbf/db.go:149). Each file counts as ``changed`` or ``unchanged``
-    against the versions the last completed save wrote
-    (``holder.saved_versions``, path -> (fragment, version): the fragment
-    itself, so that a field dropped and made again never passes for its
-    predecessor): how much of a checkpoint rewrites what the disk already
-    holds."""
+    """Persist the schema and every fragment whose file the disk does not
+    already hold. Atomic per file via tmp+rename (the coarse analog of
+    the reference's RBF checkpoint, rbf/db.go:149, which copies only the
+    pages the WAL touched).
+
+    ``holder.saved_versions`` (path -> (fragment, version)) says what the
+    disk holds. A file is ``skipped`` when its entry is this very
+    fragment (never a successor under the same name: a field dropped and
+    made again is another object) at its present version (every write
+    route bumps it, as the device stacks already require) and the file
+    still exists; anything else is ``changed`` and written. A path enters
+    the new map only with its file in place at that version: carried over
+    when skipped, recorded after the rename when written. The map is
+    replaced only by a save that ran to its end; one that died part-way
+    leaves the old map, whose entries for the files it did rewrite name
+    versions their fragments have left, so those are written again."""
     if not holder.path:
         raise ValueError("holder has no data dir")
     holder.save_schema()
@@ -56,11 +64,15 @@ def save_holder_data(holder: "Holder") -> None:
 
     def save(path: str, frag, **arrays) -> None:
         was, version = before.get(path, (None, None))
-        state = "unchanged" if was is frag and version == frag.version \
-            else "changed"
+        held = (frag, frag.version)
+        if was is frag and version == frag.version and os.path.exists(path):
+            state = "skipped"
+            saved[path] = held
+        else:
+            state = "changed"
+            if _atomic_savez(path, **arrays):
+                saved[path] = held
         M.REGISTRY.count(M.METRIC_RECOVERY_CHECKPOINT_FRAGMENTS, state=state)
-        saved[path] = (frag, frag.version)
-        _atomic_savez(path, **arrays)
 
     for idx in holder.indexes.values():
         idx_path = holder._index_path(idx.name)
@@ -190,19 +202,21 @@ def _dump_translate(key_to_id, path: str) -> None:
             f.write(_json.dumps([key, id_]) + "\n")
 
 
-def _atomic_savez(path: str, **arrays) -> None:
+def _atomic_savez(path: str, **arrays) -> bool:
     """tmp + fsync + rename + dir-fsync: the snapshot survives power
     loss, not just process death (rename alone only orders metadata on
     some filesystems). Kill sites bracket the rename — the atomicity
     claim under test is exactly "crash on either side leaves a complete
     old or complete new file" (storage/recovery.py CrashPlan; the plan
-    arrives thread-locally because array names own the kwargs)."""
+    arrives thread-locally because array names own the kwargs). Returns
+    whether the new file is in place: False when the crash plan is dead,
+    and the 'process' therefore wrote nothing or did not rename."""
     from pilosa_tpu.storage.recovery import scoped_plan
     from pilosa_tpu.storage.wal import fsync_dir
 
     plan = scoped_plan()
     if plan is not None and plan.dead:
-        return
+        return False
     os.makedirs(os.path.dirname(path), exist_ok=True)
     tmp = path + ".tmp"
     count = M.REGISTRY.count
@@ -221,12 +235,12 @@ def _atomic_savez(path: str, **arrays) -> None:
             os.fsync(f.fileno())
     try:
         if plan is not None and not plan.fire("savez.pre_replace"):
-            return
+            return False
         with annotate("checkpoint.fsync"):
             os.replace(tmp, path)
-            if plan is not None and not plan.fire("savez.post_replace"):
-                return
-            fsync_dir(os.path.dirname(path))
+            if plan is None or plan.fire("savez.post_replace"):
+                fsync_dir(os.path.dirname(path))
+        return True
     finally:
         # file fsync + rename + directory fsync, as they accrue
         count(M.METRIC_RECOVERY_CHECKPOINT_PHASE_SECONDS,

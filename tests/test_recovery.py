@@ -18,6 +18,7 @@ seeded kill point the same way PILOSA_TPU_FAULT_SEED steers RPC faults.
 
 import os
 
+import numpy as np
 import pytest
 
 from pilosa_tpu.api import API
@@ -719,6 +720,40 @@ class TestRecoveryMetrics:
             assert h is not None and h["count"] == 1
 
 
+def _snapshot_files(root):
+    """Every snapshot file under ``root``: path -> (inode, mtime_ns,
+    bytes). A file written again has another inode (tmp + rename)."""
+    out = {}
+    for d, _, fs in os.walk(str(root)):
+        for f in fs:
+            if f.startswith("frag.") and f.endswith(".npz"):
+                p = os.path.join(d, f)
+                st = os.stat(p)
+                with open(p, "rb") as fh:
+                    out[p] = (st.st_ino, st.st_mtime_ns, fh.read())
+    return out
+
+
+def _assert_saved_versions_hold(holder):
+    """What the skip rests on: wherever ``saved_versions`` says the disk
+    holds a fragment at its present version, the file is there and holds
+    exactly those planes."""
+    claims = 0
+    for path, (frag, version) in holder.saved_versions.items():
+        if version != frag.version:
+            continue  # advanced since: the next save writes it
+        claims += 1
+        with np.load(path) as z:
+            planes = z["planes"]
+            rows = z["row_ids"].tolist() if "row_ids" in z else None
+        if rows is None:
+            assert np.array_equal(planes, frag.planes), path
+        else:
+            assert rows == list(frag.row_ids), path
+            assert np.array_equal(planes, frag.planes[:len(rows)]), path
+    assert claims
+
+
 class TestCheckpointAccrual:
     """The checkpoint's counters accrue per phase and per file, so that a
     window's delta is the window's (``recovery_checkpoint_seconds`` lands
@@ -736,7 +771,11 @@ class TestCheckpointAccrual:
     def _fragments():
         return {s: M.REGISTRY.value(
             M.METRIC_RECOVERY_CHECKPOINT_FRAGMENTS, state=s)
-            for s in ("changed", "unchanged")}
+            for s in ("changed", "skipped", "unchanged")}
+
+    @classmethod
+    def _moved(cls, before):
+        return {s: v - before[s] for s, v in cls._fragments().items()}
 
     @staticmethod
     def _bytes():
@@ -746,8 +785,6 @@ class TestCheckpointAccrual:
 
     @staticmethod
     def _holder(tmp_path, rows=40):
-        import numpy as np
-
         api = API(str(tmp_path / "a"))
         api.create_index("i")
         api.create_field("i", "f")
@@ -771,35 +808,144 @@ class TestCheckpointAccrual:
         assert sum(moved.values()) == pytest.approx(total1 - total, rel=0.10)
         assert sum(moved.values()) <= total1 - total
 
-    def test_untouched_fragment_counts_unchanged_written_one_changed(
-            self, tmp_path):
+    def test_untouched_fragment_is_skipped_not_rewritten(self, tmp_path):
         api = self._holder(tmp_path)
         first = self._fragments()
         api.holder.checkpoint()
-        after_first = self._fragments()
         # nothing was on disk: every file is new
-        files = after_first["changed"] - first["changed"]
-        assert files == 8  # (f, g, _exists, v) x 2 shards
-        assert after_first["unchanged"] == first["unchanged"]
+        assert self._moved(first) == {
+            "changed": 8,  # (f, g, _exists, v) x 2 shards
+            "skipped": 0, "unchanged": 0}
+        on_disk = _snapshot_files(tmp_path / "a")
+        assert len(on_disk) == 8
         api.import_bits("i", "g", rows=[1], cols=[5])  # shard 0 of g
+        after_first = self._fragments()
         api.holder.checkpoint()
-        second = self._fragments()
-        # g and _exists of shard 0 advanced; the other six did not
-        assert second["changed"] - after_first["changed"] == 2
-        assert second["unchanged"] - after_first["unchanged"] == 6
+        # g and _exists of shard 0 advanced; the other six did not, and
+        # their files are the very files the first checkpoint wrote
+        assert self._moved(after_first) == {
+            "changed": 2, "skipped": 6, "unchanged": 0}
+        now = _snapshot_files(tmp_path / "a")
+        rewritten = sorted(p for p in now if now[p] != on_disk[p])
+        assert [p.split("/fields/")[1] for p in rewritten] == [
+            "_exists/views/standard/frag.0.npz",
+            "g/views/standard/frag.0.npz"]
+        _assert_saved_versions_hold(api.holder)
 
     def test_recovery_seeds_the_saved_versions(self, tmp_path):
         api = self._holder(tmp_path)
         api.holder.checkpoint()
+        on_disk = _snapshot_files(tmp_path / "a")
         api.import_bits("i", "f", rows=[2], cols=[SHARD_WIDTH + 9])
         api.holder.flush_wals()
         again = API(str(tmp_path / "a"))  # load checkpoint + replay tail
         before = self._fragments()
         again.holder.checkpoint()
-        after = self._fragments()
-        # the replayed record touched f and _exists of shard 1 only
-        assert after["changed"] - before["changed"] == 2
-        assert after["unchanged"] - before["unchanged"] == 6
+        # the replayed record touched f and _exists of shard 1 only:
+        # only those two are written
+        assert self._moved(before) == {
+            "changed": 2, "skipped": 6, "unchanged": 0}
+        now = _snapshot_files(tmp_path / "a")
+        assert sum(now[p] != on_disk[p] for p in now) == 2
+        assert API(str(tmp_path / "a")).checksum() == again.checksum()
+
+    def test_file_deleted_behind_the_holder_is_written_again(self, tmp_path):
+        api = self._holder(tmp_path)
+        api.holder.checkpoint()
+        gone = [p for p in _snapshot_files(tmp_path / "a")
+                if p.endswith("f/views/standard/frag.1.npz")]
+        assert len(gone) == 1
+        os.remove(gone[0])
+        before = self._fragments()
+        api.holder.checkpoint()
+        assert self._moved(before) == {
+            "changed": 1, "skipped": 7, "unchanged": 0}
+        assert os.path.exists(gone[0])
+        assert API(str(tmp_path / "a")).checksum() == api.checksum()
+
+    def test_field_made_again_under_its_name_is_never_skipped(
+            self, tmp_path):
+        import shutil
+
+        api = API(str(tmp_path / "a"))
+        api.create_index("i", {"trackExistence": False})
+        api.create_field("i", "f")
+        api.import_bits("i", "f", rows=[1], cols=[3])
+        api.holder.checkpoint()
+        (path,) = _snapshot_files(tmp_path / "a")
+        old = api.holder.index("i").field("f").views["standard"][0]
+        shutil.copy(path, str(tmp_path / "kept.npz"))
+        api.delete_field("i", "f")
+        api.create_field("i", "f")
+        api.import_bits("i", "f", rows=[7], cols=[9])
+        new = api.holder.index("i").field("f").views["standard"][0]
+        # the predecessor's file is back under the name, and its
+        # successor stands at the same version: only identity tells them
+        # apart
+        assert new is not old and new.version == old.version
+        os.makedirs(os.path.dirname(path), exist_ok=True)
+        shutil.copy(str(tmp_path / "kept.npz"), path)
+        before = self._fragments()
+        api.holder.checkpoint()
+        assert self._moved(before) == {
+            "changed": 1, "skipped": 0, "unchanged": 0}
+        again = API(str(tmp_path / "a"))
+        assert again.checksum() == api.checksum()
+        assert again.query("i", "Row(f=7)")[0].columns == [9]
+        assert again.query("i", "Row(f=1)")[0].columns == []
+
+    # a kill at each site of a checkpoint that has files to skip and
+    # files to write: savez hits 1..4 are the four files that moved
+    @pytest.mark.parametrize("site,at", [
+        ("savez.pre_replace", 1), ("savez.pre_replace", 3),
+        ("savez.post_replace", 1), ("savez.post_replace", 4),
+        ("checkpoint.mid", 1)])
+    def test_crash_in_a_skipping_checkpoint_recovers_to_the_oracle(
+            self, tmp_path, site, at):
+        api = self._holder(tmp_path)
+        api.holder.checkpoint()
+        api.import_bits("i", "g", rows=[1], cols=[5])
+        api.import_bits("i", "f", rows=[2], cols=[SHARD_WIDTH + 9])
+        api.holder.flush_wals()
+        oracle = api.checksum()
+        attach_crash_plan(api.holder, CrashPlan().kill(site, at=at))
+        with pytest.raises(SimulatedCrash):
+            api.holder.checkpoint()
+        # the save died part-way: the map claims no file that was never
+        # renamed into place
+        _assert_saved_versions_hold(api.holder)
+        abandon_holder(api.holder)
+        again = API(str(tmp_path / "a"))  # old stamp: replays the tail
+        assert again.checksum() == oracle
+        before = self._fragments()
+        again.holder.checkpoint()
+        # mixed old and new files on disk; the four the tail touched are
+        # written whichever they were, the four it did not are skipped
+        assert self._moved(before) == {
+            "changed": 4, "skipped": 4, "unchanged": 0}
+        _assert_saved_versions_hold(again.holder)
+        assert API(str(tmp_path / "a")).checksum() == oracle
+
+    def test_dead_plan_records_no_path_it_did_not_write(self, tmp_path):
+        from pilosa_tpu.storage.recovery import crash_scope
+        from pilosa_tpu.storage.store import save_holder_data
+
+        api = self._holder(tmp_path)
+        api.holder.checkpoint()
+        on_disk = _snapshot_files(tmp_path / "a")
+        api.import_bits("i", "g", rows=[1], cols=[5])
+        plan = CrashPlan()
+        plan.dead = True  # the 'process' died elsewhere: no IO from here
+        with crash_scope(plan):
+            save_holder_data(api.holder)
+        assert _snapshot_files(tmp_path / "a") == on_disk
+        # the six files that stand are carried; the two that moved were
+        # not written and are not claimed
+        assert len(api.holder.saved_versions) == 6
+        _assert_saved_versions_hold(api.holder)
+        api.holder.checkpoint()
+        assert len(api.holder.saved_versions) == 8
+        _assert_saved_versions_hold(api.holder)
 
     def test_sparse_planes_store_smaller_than_raw(self, tmp_path):
         api = self._holder(tmp_path)
@@ -823,3 +969,128 @@ class TestCheckpointAccrual:
         for phase in ("load_checkpoint", "wal_replay"):
             assert M.REGISTRY.value(M.METRIC_STARTUP_PHASE_SECONDS,
                                     phase=phase) >= 0
+
+
+# -- every write route moves fragment.version --------------------------------
+
+
+def _route_install_shard_arrays(api):
+    from pilosa_tpu.shardwidth import WORDS_PER_SHARD
+    from pilosa_tpu.storage.store import (export_shard_arrays,
+                                          install_shard_arrays)
+
+    idx = api.holder.index("i")
+    arrays = {k: np.array(v) for k, v in export_shard_arrays(idx, 0).items()}
+    arrays["set|f|standard"][0, 7] ^= np.uint32(1 << 3)
+    arrays["bsi|v"][0, WORDS_PER_SHARD - 1] ^= np.uint32(1)
+    with api.holder.write_lock:
+        install_shard_arrays(idx, 0, arrays)
+
+
+def _route_import_roaring(api):
+    from pilosa_tpu.storage.roaring import encode_positions
+
+    pos = np.array([3 * SHARD_WIDTH + 11, 90 * SHARD_WIDTH + 2],
+                   dtype=np.uint64)
+    api.import_roaring("i", "f", 1, {"standard": encode_positions(pos)})
+
+
+def _route_import_roaring_clear(api):
+    from pilosa_tpu.storage.roaring import encode_positions
+
+    col = (SHARD_WIDTH // 97 + 1) * 97  # the holder's first bit of shard 1
+    pos = np.array([(col % 40) * SHARD_WIDTH + col - SHARD_WIDTH],
+                   dtype=np.uint64)
+    api.import_roaring("i", "f", 1, {"standard": encode_positions(pos)},
+                       clear=True)
+
+
+def _route_wal_replay(api):
+    idx = api.holder.index("i")
+    with api.holder.write_lock:
+        assert api.holder.replay_records(idx, [
+            ("set_bit", "f", 4, 12345, ""),
+            ("set_values", "v", [SHARD_WIDTH + 1], [77])]) == 2
+
+
+def _route_restore_tar(api):
+    import io
+
+    src = API()
+    src.create_index("i")
+    src.create_field("i", "f")
+    src.create_field("i", "v", {"type": "int", "min": 0, "max": 1 << 20})
+    src.import_bits("i", "f", rows=[1, 2], cols=[3, SHARD_WIDTH + 4])
+    src.import_values("i", "v", cols=[3, SHARD_WIDTH + 4], values=[9, 1000])
+    buf = io.BytesIO()
+    src.backup_tar(buf)
+    buf.seek(0)
+    api.restore_tar(buf)
+    assert api.checksum() == src.checksum()
+    # the BSI planes are copied in directly: that write counts as one
+    for bfrag in api.holder.index("i").field("v").bsi.values():
+        assert bfrag.version > 0
+
+
+WRITE_ROUTES = {
+    "set_bit": lambda api: api.query("i", "Set(77, f=5)"),
+    "clear_bit": lambda api: api.query("i", "Clear(97, f=17)"),
+    "import_bits": lambda api: api.import_bits(
+        "i", "g", rows=[0, 2], cols=[1, SHARD_WIDTH + 1]),
+    "import_bits_clear": lambda api: api.import_bits(
+        "i", "g", rows=[1], cols=[97], clear=True),
+    "import_values": lambda api: api.import_values(
+        "i", "v", cols=[2, SHARD_WIDTH + 2], values=[5, 1 << 19]),
+    "set_value": lambda api: api.query("i", "Set(97, v=123)"),
+    "import_roaring": _route_import_roaring,
+    "import_roaring_clear": _route_import_roaring_clear,
+    "clear_row": lambda api: api.query("i", "ClearRow(f=1)"),
+    "delete_records": lambda api: api.query("i", "Delete(Row(g=1))"),
+    "install_shard_arrays": _route_install_shard_arrays,
+    "wal_replay": _route_wal_replay,
+    "restore_tar": _route_restore_tar,
+}
+
+
+class TestEveryWriteMovesTheVersion:
+    """A checkpoint skips a fragment whose version stands where the disk
+    holds it, so a write that changed planes and left the version alone
+    would be lost at the next restart."""
+
+    @staticmethod
+    def _fragments(holder):
+        out = {}
+        for idx in holder.indexes.values():
+            for field in idx.fields.values():
+                for view, frags in field.views.items():
+                    for shard, frag in frags.items():
+                        out[idx.name, field.name, view, shard] = (
+                            frag, frag.planes[:len(frag.row_ids)],
+                            list(frag.row_ids))
+                for shard, bfrag in field.bsi.items():
+                    out[idx.name, field.name, "bsi", shard] = (
+                        bfrag, bfrag.planes, None)
+        return out
+
+    @pytest.mark.parametrize("route", sorted(WRITE_ROUTES))
+    def test_route_moves_version_and_survives_a_checkpoint(
+            self, tmp_path, route):
+        api = TestCheckpointAccrual._holder(tmp_path)
+        api.holder.checkpoint()
+        before = {k: (frag, frag.version, planes.copy(), rows)
+                  for k, (frag, planes, rows)
+                  in self._fragments(api.holder).items()}
+        WRITE_ROUTES[route](api)
+        wrote = 0
+        for key, (frag, planes, rows) in self._fragments(api.holder).items():
+            was, version, old_planes, old_rows = before.get(
+                key, (None, None, None, None))
+            if was is not frag:
+                wrote += 1  # another object: identity keeps it from a skip
+            elif rows != old_rows or not np.array_equal(planes, old_planes):
+                wrote += 1
+                assert frag.version > version, key
+        assert wrote, "the route wrote nothing"
+        api.holder.checkpoint()
+        _assert_saved_versions_hold(api.holder)
+        assert API(str(tmp_path / "a")).checksum() == api.checksum()
