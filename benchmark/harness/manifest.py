@@ -3,8 +3,9 @@
 The check runs at the start of every run, so that a bad added file fails
 before any chip time is spent: every file under ``configs/``,
 ``queries/``, ``traffic/``, ``end_to_end/`` and ``layer_metrics/`` must
-parse, every name a cell or a mix refers to must exist, every ``moves``
-must name an end-to-end metric that the cell reports, and the names in
+parse, every name a cell or a mix refers to must exist, every family must
+name fields of the dataset it is asked of, every ``moves`` must name an
+end-to-end metric that the cell reports, and the names in
 ``BENCHMARK.json`` must be ones the driver takes.
 """
 
@@ -53,6 +54,21 @@ def load_dataset(name):
     return mod
 
 
+def family_fields(family):
+    """The field names a family's parameters and meaning refer to; the
+    ``{field}`` of a ``for_each_field`` family names none."""
+    names = {spec["field"] for spec in family.get("params", {}).values()
+             if "field" in spec}
+    meaning = family.get("meaning", {})
+    names |= {f for f, _, _ in meaning.get("filter", [])}
+    agg = meaning.get("agg")
+    if isinstance(agg, dict):
+        names |= set(agg.get("groupby", []))
+        names |= {agg["sum"]} if agg.get("sum") else set()
+        names |= {agg["topn"][0]} if "topn" in agg else set()
+    return {n for n in names if not n.startswith("{")}
+
+
 class Manifest:
     def __init__(self):
         try:
@@ -66,6 +82,42 @@ class Manifest:
         self.readers = {**_load_dir("end_to_end"),
                         **_load_dir("layer_metrics")}
         self.cells = {w["name"]: w for w in self.bench["workloads"]}
+        self._datasets = {}
+        self._fields = None
+
+    def dataset(self, name):
+        """The dataset module ``datasets/<name>.py``, loaded once."""
+        if name not in self._datasets:
+            self._datasets[name] = load_dataset(name)
+        return self._datasets[name]
+
+    def _dataset_fields(self):
+        """{dataset: its field names} for every dataset a configuration
+        file names and ``datasets/`` holds."""
+        if self._fields is None:
+            names = {c.get("dataset", "") for c in self.configs.values()}
+            self._fields = {
+                n: {f["name"] for f in self.dataset(n).fields()}
+                for n in sorted(names)
+                if os.path.isfile(os.path.join(BENCH, "datasets",
+                                               n + ".py"))}
+        return self._fields
+
+    def family_datasets(self, name):
+        """The datasets a family is asked of: that of every configuration
+        one of whose cells' mixes names it; for a family no cell's mix
+        names, every dataset that holds the fields it names. Empty: the
+        family can be asked of nothing the benchmark has."""
+        fields = self._dataset_fields()
+        named = {self.configs[w["config"]].get("dataset")
+                 for w in self.cells.values()
+                 if w["config"] in self.configs
+                 and name in self.mix_families(
+                     self.mixes.get(w["traffic"], {}))}
+        if named:
+            return sorted(named & set(fields))
+        need = family_fields(self.families[name])
+        return [d for d, have in fields.items() if need <= have]
 
     def metrics(self, cell, group):
         """The ``group`` ("end_to_end" | "per_layer") entries this cell
@@ -101,6 +153,24 @@ class Manifest:
             if fam.get("name") != name:
                 problems.append(f"queries/{name}.json: name is "
                                 f"{fam.get('name')!r}")
+        fields = self._dataset_fields()
+        for name, fam in self.families.items():
+            if not self.family_datasets(name):
+                problems.append(
+                    f"queries/{name}.json: no cell's mix names it and no "
+                    f"dataset holds its fields "
+                    f"{sorted(family_fields(fam))}")
+        for cell, w in self.cells.items():
+            dataset = self.configs.get(w["config"], {}).get("dataset")
+            mix = self.mixes.get(w["traffic"], {})
+            for name in sorted(self.mix_families(mix) & set(self.families)):
+                lacks = (family_fields(self.families[name])
+                         - fields.get(dataset, set()))
+                if dataset in fields and lacks:
+                    problems.append(
+                        f"cell {cell}: queries/{name}.json names "
+                        f"{sorted(lacks)}, which datasets/{dataset}.py "
+                        f"does not have")
         for name, spec in self.readers.items():
             if spec.get("kind") not in readers.KINDS:
                 problems.append(f"metric reader {name}: unknown kind "

@@ -87,7 +87,10 @@ def _weighted(rng, weights, n):
 
 def open_schedule(mix, families, fields, index, seed, seconds):
     """Requests with intended send times in [0, seconds): Poisson or
-    evenly spaced arrivals at the mix's fixed rate."""
+    evenly spaced arrivals at the mix's fixed rate. The families are
+    drawn iid by their weights, or, with ``cycle``, walk a fixed cycle of
+    that many places (``_cycle``) from its start: then the seed draws the
+    parameters and never which families a window holds."""
     rng = np.random.default_rng([int(seed), 1])
     rate = float(mix["rate"])
     if mix.get("arrivals", "poisson") == "poisson":
@@ -96,7 +99,11 @@ def open_schedule(mix, families, fields, index, seed, seconds):
     else:
         times = np.arange(int(rate * seconds)) / rate
     times = times[times < seconds]
-    names = _weighted(rng, mix["families"], times.size)
+    if "cycle" in mix:
+        cycle = _cycle(mix["families"], int(mix["cycle"]))
+        names = [cycle[i % len(cycle)] for i in range(times.size)]
+    else:
+        names = _weighted(rng, mix["families"], times.size)
     out = []
     for t, name in zip(times, names):
         req = instantiate(families[name], fields, index, rng)

@@ -97,18 +97,23 @@ def run_writer(cell, table, port, t0, seconds, first_batch, conn_metrics,
     return batches
 
 
-def ingest_rates(batches, seconds):
+def ingest_rates(batches, seconds, planned=None):
     """Records per second: ``ingest_rows_per_s`` over the window (records
     acknowledged inside it, over the time to the last acknowledgement
-    when the writer finished its fixed work, over the window's length
-    when the window cut it) and, where two checkpoints completed inside
-    the window, ``ingest_rows_per_s_cycles`` over whole cycles."""
+    when the writer finished its fixed work of ``planned`` batches, over
+    the window's length when the window cut it) and, where two
+    checkpoints completed inside the window, ``ingest_rows_per_s_cycles``
+    over whole cycles. ``writer_cut`` says which of the two it was: a cut
+    writer's count moves in steps of one batch and is no rate to compare
+    with a finished one's."""
     acked = [b for b in batches if b.acked and b.acked <= seconds]
     if not acked:
         return {}
-    finished = batches[-1].acked and batches[-1].acked < seconds
+    finished = (batches[-1].acked and batches[-1].acked < seconds
+                and len(acked) >= (planned or 0))
     span = batches[-1].acked if finished else seconds
-    out = {"ingest_rows_per_s": sum(b.records for b in acked) / span}
+    out = {"ingest_rows_per_s": sum(b.records for b in acked) / span,
+           "writer_cut": not finished}
     total, events = 0, []
     for b in acked:
         total += b.records
@@ -132,6 +137,12 @@ class Window:
     stats1: dict
     tracer: Tracer
     window_s: float
+
+    @property
+    def acked(self):
+        """The batches acknowledged inside the window."""
+        return [b for b in self.batches
+                if b.acked and b.acked <= self.seconds]
 
 
 def run_window(cell, table, child, conn, seconds, trace_dir=None,

@@ -14,10 +14,13 @@ query family of the cell's mix until answers equal the oracle and no new
 program appears. Then the window runs for ``--seconds``.
 
 The last line of standard output is one JSON object with ``correct``,
-``attempted``, ``failed``, ``metrics`` and ``device`` (and ``breakdown``
-when traced): the cell's end-to-end metrics with ``--trace 0``, its
-per-layer metrics with ``--trace 1``. Without a TPU, or with fewer chips
-than the cell states, the run fails and prints no result.
+``attempted``, ``failed``, ``metrics`` and ``device`` (``breakdown`` when
+traced, ``writer`` in a writer cell) and, last, ``checks``: every number
+that was compared beside its limit, which is also the last line of
+standard error. The metrics are the cell's end-to-end metrics with
+``--trace 0``, its per-layer metrics with ``--trace 1``. Without a TPU,
+or with fewer chips than the cell states, the run fails and prints no
+result.
 
 Three more flags serve whoever defines or debugs a cell, never the
 driver: ``--allow-cpu [--shards N]`` rehearses the control flow without a
@@ -59,7 +62,7 @@ class Cell:
         self.chips = w["chips"]
         self.config = man.configs[w["config"]]
         self.mix = man.mixes[w["traffic"]]
-        self.dataset = manifest.load_dataset(self.config["dataset"])
+        self.dataset = man.dataset(self.config["dataset"])
         self.fields = self.dataset.fields()
         self.by_name = {f["name"]: f for f in self.fields}
         self.index = self.dataset.INDEX
@@ -258,20 +261,40 @@ def readings(cell, w, warm):
     in_window = [d for d in w.done if d.ok and d.done <= seconds]
     values = {"setup_s": warm.setup_s,
               "read_qps": len(in_window) / seconds if in_window else None}
-    values.update(window.ingest_rates(w.batches, seconds))
+    values.update(window.ingest_rates(w.batches, seconds,
+                                      int(cell.mix.get("batches", 0))))
     series = {
         "read_ms": [(d.done - d.intended) * 1e3 for d in w.done if d.ok],
         "lateness_ms": [(d.sent - d.intended) * 1e3 for d in w.done]
         if cell.mix["loop"] == "open" else [],
         "side_read_ms": [(d.done - d.intended) * 1e3
                          for d in w.side if d.ok]}
-    acked = [b for b in w.batches if b.acked and b.acked <= seconds]
+    acked = w.acked
     counts = {"reads": float(sum(1 for d in reads if d.ok)),
               "window_s": w.window_s, "batches": float(len(acked)),
               "records": float(sum(b.records for b in acked))}
     return readers.Readings(values, series, counts, w.scrape0, w.scrape1,
                             verify.span_trees(reads), w.stats0, w.stats1,
                             device_kind=warm.device["kind"])
+
+
+def writer_summary(w, cut):
+    """What a writer's window held, for whoever reads the line: whether
+    the window cut the fixed work, the last acknowledgement, and which of
+    the window's batches (1, 2, ...) carried a whole checkpoint."""
+    acked = w.acked
+    before = seen = int(stats.series_sum(
+        w.scrape0, "recovery_checkpoint_seconds_count"))
+    carried = []
+    for i, b in enumerate(acked, 1):
+        if b.checkpoints > seen:
+            carried.append(i)
+            seen = b.checkpoints
+    return {"writer_cut": cut,
+            "batches_acked": len(acked),
+            "last_ack_s": acked[-1].acked if acked else None,
+            "in_flight_s": sum(b.acked - b.started for b in acked),
+            "checkpoints": seen - before, "checkpoint_batches": carried}
 
 
 # -- one run ---------------------------------------------------------------------
@@ -365,6 +388,9 @@ def measure(args, cell, child, work, t_spawn):
 
     r = readings(cell, w, warm)
     breakdown = None
+    writer = None
+    if cell.mix["loop"] == "writer":
+        writer = writer_summary(w, bool(r.values.get("writer_cut", True)))
     if w.tracer:
         device["window_s"] = w.tracer.ended - w.tracer.began
         r.trace = load_trace(trace_dir)
@@ -396,14 +422,21 @@ def measure(args, cell, child, work, t_spawn):
                        "counts": r.counts, "series": r.series,
                        "side": [[d.sent, d.done] for d in w.side],
                        "batches": [vars(b) for b in w.batches]}, fh)
+    # every comparison is exact: each number beside its limit, 0
+    checks = {"wrong_reads": [wrong, 0], "unanswered": [unanswered, 0],
+              "wrong_final": [wrong_final, 0],
+              "bad_batches": [bad_batches, 0],
+              "kernel_errors": [kernel_errors, 0],
+              "mesh_fallbacks": [mesh_fallbacks, 0]}
     result = {
-        "correct": not (wrong or wrong_final or bad_batches
-                        or kernel_errors or mesh_fallbacks),
+        "correct": not any(n > limit for n, limit in checks.values()),
         "attempted": len(reads) + len(w.batches) * len(cell.fields),
         "failed": wrong + wrong_final + unanswered + bad_batches,
         "metrics": metrics.get(reported, {}), "device": device}
     if breakdown:
         result["breakdown"] = breakdown
+    if writer:
+        result["writer"] = writer
     other = {k: v for k, v in metrics.items() if k != reported}
     print(f"also read: {json.dumps(other)} {json.dumps(r.values)}",
           file=sys.stderr)
@@ -414,6 +447,11 @@ def measure(args, cell, child, work, t_spawn):
         result["metrics"] = {}
         result.pop("breakdown", None)
         device.pop("busy_s", None)
+    # what was compared, each beside its limit: last on standard error
+    # and last in the line
+    result["checks"] = checks
+    print(f"compared (number, limit): verified_reads {verified} "
+          f"{json.dumps(checks)}", file=sys.stderr)
     return result
 
 

@@ -4,6 +4,8 @@ are each added by new files plus one manifest entry."""
 import json
 import os
 import shutil
+import subprocess
+import sys
 
 import pytest
 
@@ -70,8 +72,9 @@ def test_additions_are_files_plus_one_entry_each(copy):
     edit_manifest(copy, add)
     man = manifest.Manifest()
     man.check()
+    # a new cell reports what lists it and nothing else
     assert [m["name"] for m in man.metrics(cell, "per_layer")] == [
-        "compiles_in_window", "hbm_hits_per_read"]
+        "hbm_hits_per_read"]
     fields = {f["name"]: f
               for f in manifest.load_dataset("ssb_flat").fields()}
     reqs = traffic.open_schedule(man.mixes["nation-burst"], man.families,
@@ -84,6 +87,123 @@ def test_additions_are_files_plus_one_entry_each(copy):
     assert readers.read(man.readers["hbm_hits_per_read"], r) == 5.0
     # no file that was there has changed
     assert all(p.read_bytes() == data for p, data in before.items())
+
+
+def test_every_per_layer_metric_lists_its_cells():
+    """A metric without a list would join every cell a later PR adds,
+    whether or not that cell has anything for it to read."""
+    man = manifest.Manifest()
+    for m in man.bench["per_layer"]:
+        assert m.get("workloads"), m["name"]
+        assert set(m["workloads"]) <= set(man.cells), m["name"]
+
+
+TOY_DATASET = '''
+import numpy as np
+
+INDEX = "toy"
+INGEST_STREAM = 1 << 16
+
+
+def fields():
+    return [{"name": "kind", "type": "mutex", "rows": 4,
+             "ids": [10, 20, 30, 40], "keys": None},
+            {"name": "size", "type": "int", "min": 0, "max": 99}]
+
+
+def make(seed, stream, count):
+    rng = np.random.default_rng([int(seed), int(stream)])
+    return {"kind": rng.integers(0, 4, count).astype(np.int8),
+            "size": rng.integers(0, 100, count).astype(np.int64)}
+'''
+
+
+def test_a_second_dataset_is_files_and_entries_and_its_tests_are_green(
+        tmp_path):
+    """A toy second dataset with one configuration, one family, one mix
+    and one cell, added to a copy of the benchmark with its tests: the
+    self-check takes it, the family is drawn against its own table, and
+    ``pytest benchmark/tests`` is green on the copy."""
+    shutil.copytree(BENCH, tmp_path / "benchmark", ignore=shutil.
+                    ignore_patterns(".cache", "__pycache__",
+                                    ".pytest_cache"))
+    shutil.copy(os.path.join(manifest.ROOT, "BENCHMARK.json"), tmp_path)
+    b = tmp_path / "benchmark"
+    before = {p: p.read_bytes() for p in b.rglob("*") if p.is_file()}
+    (b / "datasets" / "toy_events.py").write_text(TOY_DATASET)
+    (b / "configs" / "toy-1.json").write_text(json.dumps({
+        "name": "toy-1", "dataset": "toy_events", "shards": 1, "chips": 1,
+        "server_toml": None, "reduced": []}))
+    (b / "queries" / "toy-sum.json").write_text(json.dumps({
+        "name": "toy-sum", "route": "pql",
+        "text": "Sum(Row(kind={k}), field=size)",
+        "params": {"k": {"field": "kind", "dist": "uniform"}},
+        "meaning": {"filter": [["kind", "==", "k"]],
+                    "agg": {"sum": "size"}}}))
+    # no mix names this one: it goes to the dataset that has its field
+    (b / "queries" / "toy-range.json").write_text(json.dumps({
+        "name": "toy-range", "route": "pql",
+        "text": "Count(Row(size < {v}))",
+        "params": {"v": {"field": "size", "dist": "uniform"}},
+        "meaning": {"filter": [["size", "<", "v"]], "agg": "count"}}))
+    (b / "traffic" / "toy-open.json").write_text(json.dumps({
+        "loop": "open", "arrivals": "uniform", "rate": 10,
+        "families": {"toy-sum": 1}}))
+    cell = "toy-1.toy-open"
+
+    def add(doc):
+        doc["configs"].append({
+            "name": "toy-1", "source": "rehearsal",
+            "file": "benchmark/configs/toy-1.json", "reduced": [],
+            "why": "rehearsal"})
+        doc["workloads"].append({
+            "name": cell, "config": "toy-1", "traffic": "toy-open",
+            "chips": 1, "why": "rehearsal"})
+        for m in doc["end_to_end"] + doc["per_layer"]:
+            if m["name"] in ("read_p50_ms", "http_server_ms", "parse_ms",
+                             "programs_built_in_window"):
+                m["workloads"].append(cell)
+
+    edit_manifest(tmp_path, add)
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    proc = subprocess.run(
+        [sys.executable, "-m", "pytest", "benchmark/tests", "-v",
+         "-p", "no:cacheprovider",
+         # the whole command on the CPU needs the program beside it, and
+         # this test would copy the copy
+         "--ignore", "benchmark/tests/test_rehearsal.py",
+         "--ignore", "benchmark/tests/test_control.py",
+         "--deselect", "benchmark/tests/test_manifest.py::"
+         "test_a_second_dataset_is_files_and_entries_and_its_tests_are_green"],
+        cwd=tmp_path, env=env, capture_output=True, text=True, timeout=600)
+    assert proc.returncode == 0, proc.stdout[-3000:] + proc.stderr[-2000:]
+    passed = [ln for ln in proc.stdout.splitlines() if "PASSED" in ln]
+    for family in ("toy-sum", "toy-range"):
+        assert any("test_family_equals_the_hand_computation" in ln
+                   and family in ln for ln in passed), family
+    assert all(p.read_bytes() == data for p, data in before.items())
+
+
+def test_a_family_of_no_dataset_and_a_mix_over_the_wrong_one_are_refused(
+        copy):
+    b = copy / "benchmark"
+    (b / "queries" / "count-rides.json").write_text(json.dumps({
+        "name": "count-rides", "text": "Count(Row(cab_type={c}))",
+        "params": {"c": {"field": "cab_type"}},
+        "meaning": {"filter": [["cab_type", "==", "c"]], "agg": "count"}}))
+    with pytest.raises(manifest.ManifestError,
+                       match=r"count-rides.json: no cell's mix names it "
+                             r"and no dataset holds its fields "
+                             r"\['cab_type'\]"):
+        manifest.Manifest().check()
+    mix = json.loads((b / "traffic" / "filter-open.json").read_text())
+    mix["families"] = {"count-rides": 1}
+    (b / "traffic" / "filter-open.json").write_text(json.dumps(mix))
+    with pytest.raises(manifest.ManifestError,
+                       match=r"cell ssb-flat-sf1.filter-open: queries/"
+                             r"count-rides.json names \['cab_type'\], "
+                             r"which datasets/ssb_flat.py does not have"):
+        manifest.Manifest().check()
 
 
 @pytest.mark.parametrize("break_it, says", [

@@ -1,5 +1,6 @@
 """The generic evaluator against answers worked out record by record in
-plain Python, for every family the benchmark ships."""
+plain Python, for every family the benchmark ships, each against the
+table of the dataset it is asked of."""
 
 import itertools
 import json
@@ -10,12 +11,34 @@ import pytest
 from harness import manifest, oracle, traffic
 
 MAN = manifest.Manifest()
-DATASET = manifest.load_dataset("ssb_flat")
-FIELDS = DATASET.fields()
-BY_NAME = {f["name"]: f for f in FIELDS}
-COLUMNS = DATASET.make(7, 0, 4000)
-TABLE = oracle.Table(FIELDS, COLUMNS)
-RECORDS = [{k: int(v[i]) for k, v in COLUMNS.items()} for i in range(4000)]
+
+
+class Hand:
+    """4,000 records of one dataset, as the oracle's table and as plain
+    Python records."""
+
+    def __init__(self, name):
+        self.dataset = MAN.dataset(name)
+        self.fields = self.dataset.fields()
+        self.by_name = {f["name"]: f for f in self.fields}
+        columns = self.dataset.make(7, 0, 4000)
+        self.table = oracle.Table(self.fields, columns)
+        self.records = [{k: int(v[i]) for k, v in columns.items()}
+                        for i in range(4000)]
+
+
+HANDS = {}
+
+
+def hand(name):
+    if name not in HANDS:
+        HANDS[name] = Hand(name)
+    return HANDS[name]
+
+
+SSB = hand("ssb_flat")
+DATASET, FIELDS, BY_NAME, TABLE = (SSB.dataset, SSB.fields, SSB.by_name,
+                                   SSB.table)
 
 _PY_OPS = {
     "==": lambda c, v: c == v, "!=": lambda c, v: c != v,
@@ -26,10 +49,10 @@ _PY_OPS = {
 }
 
 
-def by_hand(meaning):
+def by_hand(meaning, of=SSB):
     """The answer by a loop over records, sharing nothing with oracle.py
     but the form of the result."""
-    rows = [r for r in RECORDS
+    rows = [r for r in of.records
             if all(_PY_OPS[op](r[f], v) for f, op, v in meaning["filter"])]
     agg = meaning["agg"]
     if agg == "count":
@@ -49,7 +72,7 @@ def by_hand(meaning):
             out.append(row)
         return {"groups": out[:agg.get("limit")]}
     if "topn" in agg:
-        counts = [0] * BY_NAME[agg["topn"][0]]["rows"]
+        counts = [0] * of.by_name[agg["topn"][0]]["rows"]
         for r in rows:
             counts[r[agg["topn"][0]]] += 1
         return counts
@@ -57,21 +80,28 @@ def by_hand(meaning):
 
 
 def shipped_families():
+    """(dataset, family) for every family under ``queries/``, against
+    the dataset of a configuration whose mix names it, or, where no mix
+    does, against the dataset that holds the fields it names."""
     import run
 
-    return [fam for name in sorted(MAN.families)
-            for fam in run.expand_fields(MAN.families[name], FIELDS)]
+    return [pytest.param(dataset, fam,
+                         id=fam["name"] + ":" + fam["text"][:24])
+            for name in sorted(MAN.families)
+            for dataset in MAN.family_datasets(name)
+            for fam in run.expand_fields(MAN.families[name],
+                                         hand(dataset).fields)]
 
 
-@pytest.mark.parametrize(
-    "family", shipped_families(),
-    ids=lambda f: f["name"] + ":" + f["text"][:24])
-def test_family_equals_the_hand_computation(family):
+@pytest.mark.parametrize("dataset, family", shipped_families())
+def test_family_equals_the_hand_computation(dataset, family):
+    of = hand(dataset)
     rng = np.random.default_rng(11)
     for _ in range(3):
-        req = traffic.instantiate(family, BY_NAME, "ssb", rng)
-        got = TABLE.evaluate(req.meaning)
-        want = by_hand(req.meaning)
+        req = traffic.instantiate(family, of.by_name, of.dataset.INDEX,
+                                  rng)
+        got = of.table.evaluate(req.meaning)
+        want = by_hand(req.meaning, of)
         if isinstance(got, dict) and "counts" in got:
             assert got["counts"].tolist() == want
         else:

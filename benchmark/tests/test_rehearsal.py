@@ -10,7 +10,8 @@ import pytest
 
 from harness import manifest
 
-CONTRACT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+CONTRACT_KEYS = {"correct", "attempted", "failed", "metrics", "device",
+                 "checks"}
 
 
 def run_cell(cell, *extra):
@@ -25,14 +26,22 @@ def run_cell(cell, *extra):
 
 @pytest.mark.parametrize("cell, trace", [
     ("ssb-flat-sf1.filter-open", "0"),
-    ("ssb-flat-sf1.ingest", "1"),
+    ("ssb-flat-sf1.ingest-sustained", "1"),
 ])
 def test_last_line_has_exactly_the_contract_keys(cell, trace):
     proc = run_cell(cell, "--trace", trace, "--allow-cpu", "--shards", "1")
     assert proc.returncode == 0, proc.stderr[-3000:]
     last = json.loads(proc.stdout.strip().splitlines()[-1])
-    assert set(last) == CONTRACT_KEYS
+    assert set(last) - {"writer"} == CONTRACT_KEYS
     assert last["correct"] is True and last["failed"] == 0
+    # what was compared comes last, each number beside its limit, and is
+    # the end of standard error too
+    assert list(last)[-1] == "checks"
+    assert all(n <= limit for n, limit in last["checks"].values())
+    assert json.dumps(last["checks"]) in proc.stderr.splitlines()[-1]
+    if "ingest" in cell:
+        # three seconds cut the writer's fixed work, and the line says so
+        assert last["writer"]["writer_cut"] is True
     assert last["attempted"] > 0
     # a CPU run names its device and withholds every number
     assert last["device"]["platform"] == "cpu"

@@ -33,6 +33,25 @@ def test_even_arrivals_are_evenly_spaced():
     assert len(reqs) == 30
 
 
+def test_side_reads_walk_a_fixed_cycle_whatever_the_seed():
+    spec = MAN.mixes["ingest-sustained"]["side_reads"]
+    a = traffic.open_schedule(spec, MAN.families, FIELDS, "ssb", 4, 50.0)
+    b = traffic.open_schedule(spec, MAN.families, FIELDS, "ssb", 5, 50.0)
+    assert len(a) == len(b) == 250
+    assert np.allclose(np.diff([r.at for r in a]), 0.2)
+    # the seed draws the parameters, never which families a window holds
+    assert [r.family for r in a] == [r.family for r in b]
+    assert [r.text for r in a] != [r.text for r in b]
+    cycle = [r.family for r in a[:20]]
+    assert [r.family for r in a[20:40]] == cycle
+    # the shares of filter-open, exactly, in every 20 reads (4 s)
+    assert {k: cycle.count(k) for k in set(cycle)} == {
+        "count-intersect": 6, "bsi-range-count": 4, "sum-filter": 4,
+        "ssb-q1.1": 2, "ssb-q1.2": 1, "ssb-q1.3": 1, "sql-count": 2}
+    assert [k for k in cycle if k.startswith("ssb-q1")] == [
+        "ssb-q1.1", "ssb-q1.2", "ssb-q1.3", "ssb-q1.1"]
+
+
 def test_a_round_is_fixed_and_each_client_starts_elsewhere():
     mix = MAN.mixes["groupby-closed"]
     a = traffic.closed_sequences(mix, MAN.families, FIELDS, "ssb", 9)
