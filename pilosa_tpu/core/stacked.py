@@ -212,6 +212,11 @@ class DeviceBudget:
             if key in self._lru:
                 self._lru.move_to_end(key)
 
+    def room(self) -> int:
+        """Bytes that can still be charged without evicting anything."""
+        with self._lock:
+            return self.cap - self.used
+
     def release(self, key: Tuple) -> None:
         with self._lock:
             old = self._lru.pop(key, None)
@@ -240,6 +245,30 @@ BUDGET = DeviceBudget(_budget_bytes())
 #: Target bytes per row block. A stack pages when its full tensor would
 #: exceed one block. Tests override via env to exercise paging cheaply.
 _BLOCK_BYTES = _env_mb("PILOSA_TPU_BLOCK_BYTES_MB", 256) << 20
+
+
+def planes_per_block(total_words: int) -> int:
+    """How many planes of ``total_words`` words one row block's bytes
+    hold (at least one): what a layer above sizes a device working set
+    by, so that it blocks where a stack of that width would page."""
+    return max(1, _BLOCK_BYTES // max(total_words * 4, 1))
+
+
+def _decode_whole(blk: ctiles.CompressedBlock, kind: str):
+    """The dense words of a resident compressed block, decoded device-side
+    for a consumer that walks them whole (GroupBy, Sum), and whether the
+    budget has room for them: where it has, the caller keeps the dense
+    words beside the block and charges both, so such a block is decoded
+    once per residency, not once per read, while point reads and the
+    tile-skipping counts go on reading the small form; a budget too tight
+    for that keeps the small form alone and decodes per walk."""
+    from pilosa_tpu.obs.tracing import annotate
+
+    M.REGISTRY.count(M.METRIC_COMPRESS_DECODE, kind=kind)
+    M.REGISTRY.count(M.METRIC_COMPRESS_DECODE_BYTES, blk.dense_nbytes)
+    with annotate("stack.decode"):
+        dense = blk.decode()
+    return dense, BUDGET.room() >= blk.dense_nbytes
 
 _stack_serial = itertools.count()
 
@@ -270,7 +299,7 @@ class StackedSet:
         self.row_ids: List[int] = sorted(rows)
         self.row_index: Dict[int, int] = {r: i for i, r in enumerate(self.row_ids)}
         row_bytes = self.total_words * 4
-        per_block = max(_MIN_SLOTS, _BLOCK_BYTES // max(row_bytes, 1))
+        per_block = max(_MIN_SLOTS, planes_per_block(self.total_words))
         self.block_rows = min(_pow2(len(self.row_ids)),
                               _pow2(per_block) // 2 or _MIN_SLOTS)
         if self.block_rows * row_bytes > _BLOCK_BYTES:
@@ -291,6 +320,9 @@ class StackedSet:
         # published to the field cache) opt out of budget accounting —
         # they die with the request, and LRU entries would orphan
         self.ephemeral = False
+        # block index -> (compressed block, its dense words): what a
+        # whole walk decoded and the budget had room to keep
+        self._walked: Dict[int, Tuple[ctiles.CompressedBlock, jax.Array]] = {}
         if not self.paged:
             # unpaged stacks are resident (pinned until LRU-evicted)
             # and charged like any block, so BUDGET is the complete
@@ -378,11 +410,29 @@ class StackedSet:
         # eviction callback (paged blocks AND the unpaged block 0): the
         # next touch lazily rebuilds under the version check
         self._blocks[bi] = None
+        self._walked.pop(bi, None)
 
     def _block_dense(self, bi: int) -> jax.Array:
-        """Block ``bi`` as a dense device tensor (decoded on the fly when
-        resident in compressed form — no host transfer)."""
-        return _dense(self._ensure_block(bi))
+        """Block ``bi`` as a dense device tensor (a compressed-resident
+        block decodes device-side — no host transfer — and its dense
+        words stay beside it where the budget has room:
+        :func:`_decode_whole`)."""
+        blk = self._ensure_block(bi)
+        if not isinstance(blk, ctiles.CompressedBlock):
+            return blk
+        kept = self._walked.get(bi)
+        if kept is not None and kept[0] is blk:
+            return kept[1]
+        dense, stays = _decode_whole(blk, "set")
+        if stays and not self.ephemeral:
+            with self._lock:
+                stays = self._blocks[bi] is blk
+                if stays:
+                    self._walked[bi] = (blk, dense)
+            if stays:
+                BUDGET.charge((self.serial, bi), blk.nbytes + dense.nbytes,
+                              lambda s=self, i=bi: s._drop_block(i))
+        return dense
 
     def iter_blocks(self) -> Iterator[Tuple[int, jax.Array]]:
         """(start_slot, dense device block) over all blocks, built on
@@ -502,6 +552,9 @@ class StackedBSI:
                             else contextlib.nullcontext())
         self._lock = threading.Lock()
         self.ephemeral = False
+        # (compressed stack, its dense words) once a whole walk decoded
+        # it and the budget had room to keep them
+        self._walked: Optional[Tuple[ctiles.CompressedBlock, jax.Array]] = None
         self._fragments = list(fragments)
         self._built_vers = tuple(
             -1 if f is None else f.version for f in fragments)
@@ -531,11 +584,14 @@ class StackedBSI:
     def _charge(self) -> None:
         blk = self._planes
         if blk is not None and not self.ephemeral:
-            BUDGET.charge((self.serial, 0), blk.nbytes,
+            kept = self._walked
+            BUDGET.charge((self.serial, 0),
+                          blk.nbytes + (0 if kept is None else kept[1].nbytes),
                           lambda s=self: s._drop())
 
     def _drop(self) -> None:
         self._planes = None
+        self._walked = None
 
     def release_device(self) -> None:
         BUDGET.release((self.serial, 0))
@@ -565,7 +621,21 @@ class StackedBSI:
 
     @property
     def planes(self) -> jax.Array:
-        return _dense(self._entry())
+        blk = self._entry()
+        if not isinstance(blk, ctiles.CompressedBlock):
+            return blk
+        kept = self._walked
+        if kept is not None and kept[0] is blk:
+            return kept[1]
+        dense, stays = _decode_whole(blk, "bsi")
+        if stays and not self.ephemeral:
+            with self._lock:
+                stays = self._planes is blk
+                if stays:
+                    self._walked = (blk, dense)
+            if stays:
+                self._charge()
+        return dense
 
     def compare(self, op: str, value: int,
                 value2: Optional[int] = None) -> jax.Array:
@@ -832,6 +902,7 @@ def _advance_set(stack: "StackedSet", fragments, built_vers) -> Optional["Stacke
     new._lock = threading.Lock()
     new._write_lock = stack._write_lock
     new.ephemeral = False
+    new._walked = {}
     new._fragments = list(fragments)
     new._built_vers = tuple(
         -1 if f is None else f.version for f in fragments)
@@ -969,6 +1040,7 @@ def _advance_bsi(stack: "StackedBSI", fragments, built_vers) -> Optional["Stacke
     new._write_lock = stack._write_lock
     new._lock = threading.Lock()
     new.ephemeral = False
+    new._walked = None
     new._fragments = list(fragments)
     new._built_vers = tuple(
         -1 if f is None else f.version for f in fragments)

@@ -227,6 +227,19 @@ METRIC_COMPRESS_DENSE_BYTES = "device_compress_dense_bytes_total"
 METRIC_COMPRESS_STORED_BYTES = "device_compress_stored_bytes_total"
 METRIC_COMPRESS_RATIO = "device_compress_ratio"
 METRIC_COMPRESS_TILES_SKIPPED = "device_compress_tiles_skipped_total"
+# a resident compressed block turned dense, whole, for a consumer that
+# walks dense words (GroupBy; Sum, Min/Max, Percentile): how often
+# (kind=) and how many dense bytes; a row decoded for a point read
+# counts in neither
+METRIC_COMPRESS_DECODE = "device_compress_decode_total"
+METRIC_COMPRESS_DECODE_BYTES = "device_compress_decode_bytes_total"
+# GroupBy (pql/executor.py): which body served a GroupBy of how many
+# fields (route=dense|fold, fields=1|2|3|4+), trips to the host for
+# counts, and bytes of group planes (ANDs of rows of several fields)
+# materialised on the device
+METRIC_GROUPBY_ROUTE = "groupby_route_total"
+METRIC_GROUPBY_HOST_FETCHES = "groupby_host_fetches_total"
+METRIC_GROUPBY_GROUP_PLANE_BYTES = "groupby_group_plane_bytes_total"
 # cluster health plane (obs/timeline.py + slo.py + flight.py): samples
 # appended to the in-memory timeline ring, per-objective error-budget
 # burn rate over the fast/slow windows (gauge {slo=,window=}), and

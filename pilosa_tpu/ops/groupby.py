@@ -41,6 +41,9 @@ BLOCK_WORDS = 2048
 _PALLAS_BW = 512
 _PALLAS_TR2 = 256
 _PALLAS_MAX_R1 = 128  # larger outer sides would blow VMEM; swap or scan
+#: the most rows the Pallas route of :func:`pair_counts` takes as its
+#: first operand: a caller that blocks its group planes blocks them to this
+PAIR_COUNTS_MAX_ROWS = _PALLAS_MAX_R1
 
 
 def _expand_bits_i8(words):
@@ -131,17 +134,22 @@ def _pair_counts_traced(a, b, interpret: bool):
     r1p = max(8, -(-r1 // 8) * 8)  # sublane multiple, not just >= 8
     if r1p != r1:
         a = jnp.pad(a, ((0, r1p - r1), (0, 0)))
-    r2p = -(-r2 // _PALLAS_TR2) * _PALLAS_TR2
+    # a second operand narrower than one row tile is one tile of its own
+    # (sublane-rounded) height: padded to _PALLAS_TR2, an 8-row block of
+    # 66-shard rows (8.65 MB each) would be copied out as 2.2 GB and read
+    # back as such
+    tr2 = min(_PALLAS_TR2, -(-r2 // 8) * 8)
+    r2p = -(-r2 // tr2) * tr2
     if r2p != r2:
         b = jnp.pad(b, ((0, r2p - r2), (0, 0)))
     out = pl.pallas_call(
         _pallas_kernel,
-        grid=(r2p // _PALLAS_TR2, a.shape[1] // _PALLAS_BW),
+        grid=(r2p // tr2, a.shape[1] // _PALLAS_BW),
         in_specs=[
             pl.BlockSpec((r1p, _PALLAS_BW), lambda t, w: (0, w)),
-            pl.BlockSpec((_PALLAS_TR2, _PALLAS_BW), lambda t, w: (t, w)),
+            pl.BlockSpec((tr2, _PALLAS_BW), lambda t, w: (t, w)),
         ],
-        out_specs=pl.BlockSpec((r1p, _PALLAS_TR2), lambda t, w: (0, t)),
+        out_specs=pl.BlockSpec((r1p, tr2), lambda t, w: (0, t)),
         out_shape=jax.ShapeDtypeStruct((r1p, r2p), jnp.int32),
         interpret=interpret,
     )(a, b)
@@ -192,6 +200,20 @@ def _pair_counts_xla(a, b, block_words: int = BLOCK_WORDS):
     acc0 = zeros_varying_like(a, (r1, r2), jnp.int32)
     acc, _ = lax.scan(step, acc0, (a_blocks, b_blocks))
     return acc
+
+
+@platform.guarded_call
+@functools.partial(jax.jit, static_argnames=("gn", "rn"))
+def group_planes(planes, rows, g0, r0, gn: int, rn: int):
+    """``uint32[gn * rn, W]``: every AND of one of the ``gn`` planes from
+    ``planes[g0]`` on with one of the ``rn`` rows from ``rows[r0]`` on,
+    row-major (the planes of one group with all its rows side by side) —
+    the group planes a GroupBy of three or more fields counts against the
+    next field's rows, made a block at a time. Starts are dynamic, sizes
+    static: one program per block shape, whatever the block's place."""
+    g = lax.dynamic_slice_in_dim(planes, g0, gn)
+    r = lax.dynamic_slice_in_dim(rows, r0, rn)
+    return (g[:, None, :] & r[None, :, :]).reshape(gn * rn, planes.shape[1])
 
 
 @platform.guarded_call

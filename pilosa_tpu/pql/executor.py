@@ -37,13 +37,16 @@ from pilosa_tpu.core.field import Field
 from pilosa_tpu.core.holder import Holder
 from pilosa_tpu.core.index import EXISTENCE_ROW, Index
 from pilosa_tpu.core.schema import FieldType
-from pilosa_tpu.core.stacked import (StackedBSI, StackedSet, stacked_bsi,
-                                     stacked_set, writer_wait)
+from pilosa_tpu.core.stacked import (StackedBSI, StackedSet,
+                                     planes_per_block, stacked_bsi,
+                                     stacked_set, sync_part, writer_wait)
+from pilosa_tpu.obs import metrics as M
 from pilosa_tpu.obs.tracing import get_tracer
 from pilosa_tpu.ops import bitmap as B
 from pilosa_tpu.ops import bsi as S
 from pilosa_tpu.ops import topk as T
-from pilosa_tpu.ops.groupby import pair_counts, pair_sums
+from pilosa_tpu.ops.groupby import (PAIR_COUNTS_MAX_ROWS, group_planes,
+                                    pair_counts, pair_sums)
 from pilosa_tpu.pql.ast import Call, Condition, Query, ROW_OPTIONS, unwrap_options
 from pilosa_tpu.pql.parser import parse
 from pilosa_tpu.pql import programs
@@ -190,6 +193,29 @@ def _resolve(value):
 
 def _concat(parts, axis=0):
     return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=axis)
+
+
+#: GroupBy results up to this many cells (the product of the fields' row
+#: capacities, times the magnitude planes of a Sum) are counted whole on
+#: the device and fetched once; above it the pruning fold takes over
+_DENSE_MAX_CELLS = 1 << 24
+
+#: the ``fields`` label of ``groupby_route_total``: a bounded set
+_FIELDS_LABEL = ("1", "2", "3", "4+")
+
+
+def _group_block(total_words: int) -> int:
+    """How many group planes of ``total_words`` words a GroupBy holds at
+    a time: one row block's worth (``core/stacked.py``), at least a
+    sublane tile and at most what the Pallas pair-count takes as its
+    outer side."""
+    return max(8, min(PAIR_COUNTS_MAX_ROWS, planes_per_block(total_words)))
+
+
+def _made(planes):
+    """Count group planes just materialised on the device."""
+    M.REGISTRY.count(M.METRIC_GROUPBY_GROUP_PLANE_BYTES, planes.nbytes)
+    return sync_part(planes)
 
 
 def _start_copies(raw) -> None:
@@ -1121,25 +1147,37 @@ class Executor:
             filt = S.mask_filter(filt, mask.plane)
         agg_st = stacked_bsi(agg_field, shard_list) if agg_field is not None else None
 
-        if len(sts) <= 2 and self._groupby_dense_ok(sts, agg_st):
+        dense = self._groupby_dense_ok(sts, agg_st)
+        M.REGISTRY.count(M.METRIC_GROUPBY_ROUTE,
+                         route="dense" if dense else "fold",
+                         fields=_FIELDS_LABEL[min(len(sts), 4) - 1])
+        if dense:
             return self._groupby_dense(fields, sts, filt, agg_field, agg_st, limit)
         return self._groupby_fold(fields, sts, filt, agg_field, agg_st, limit)
 
     @staticmethod
     def _groupby_dense_ok(sts, agg_st) -> bool:
-        """The dense path materializes the full [RcapA, RcapB] count
-        tensor (and [D, RA, RB] sum tensors with a Sum aggregate) — cap
-        the cell product so high-cardinality GroupBy falls back to the
-        pruning fold instead of OOMing HBM (paged stacks stream their
-        INPUT blocks, but the dense OUTPUT is unbounded by paging)."""
+        """The dense path materializes the full count tensor over the
+        fields' row capacities (and [D, ...] sum tensors with a Sum
+        aggregate) — cap the cell product so high-cardinality GroupBy
+        falls back to the pruning fold instead of OOMing HBM (paged
+        stacks stream their INPUT blocks and group planes are made a
+        block at a time, but the dense OUTPUT is unbounded by either).
+        The product chooses the route of a count, whatever the number of
+        fields. A Sum over three or more fields stays with the fold:
+        dense, ``pair_sums`` scans the magnitude planes of every cell,
+        live or not, and the fold sums the live groups only (SSB Q3.1
+        keeps 25 of its 625 leading pairs behind its region filters)."""
         cells = 1
         for st in sts:
             cells *= st.cap
-        if cells > 1 << 24:  # 16M int32 cells = 64MB per tensor
+        if cells > _DENSE_MAX_CELLS:  # 16M int32 cells = 64MB per tensor
             return False
         if agg_st is None:
             return True
-        return cells * agg_st.planes.shape[0] <= 1 << 24
+        if len(sts) > 2:
+            return False
+        return cells * agg_st.planes.shape[0] <= _DENSE_MAX_CELLS
 
     def _field_row(self, field: Field, row: int) -> R.FieldRow:
         if field.options.keys and not self.remote:
@@ -1170,19 +1208,12 @@ class Executor:
         return mags, pos_m, neg_m
 
     def _groupby_dense(self, fields, sts, filt, agg_field, agg_st, limit):
-        """1- and 2-field GroupBy: the whole result is a dense count
-        tensor — streamed per row block for paged stacks, one dispatch
-        and one fetch otherwise. The MXU pair-count matmul replaces the
-        reference's per-pair container walk (executor.go:3176)."""
-
-        def a_blocks():
-            for lo, blk in sts[0].iter_blocks():
-                if filt is not None:
-                    blk = B.plane_and(blk, filt[None, :])
-                yield lo, blk
-
-        from pilosa_tpu.core.stacked import sync_part
-
+        """GroupBy whose whole result is a dense count tensor: counted on
+        the device block by block and fetched once. The MXU pair-count
+        matmul replaces the reference's per-pair container walk
+        (executor.go:3176). One field counts its rows; two or more count
+        group planes of all fields but the last (:meth:`_group_blocks`)
+        against the last field's rows, streamed per row block."""
         if len(sts) == 1:
             # one pass over the blocks computing counts (and, with an
             # aggregate, the signed per-plane pair counts) — a block is
@@ -1192,7 +1223,9 @@ class Executor:
                 mp = B.plane_and(mags, pos_m[None, :])
                 mn = B.plane_and(mags, neg_m[None, :])
             c_parts, p_parts, ng_parts = [], [], []
-            for _, blk in a_blocks():
+            for _, blk in sts[0].iter_blocks():
+                if filt is not None:
+                    blk = B.plane_and(blk, filt[None, :])
                 c_parts.append(sync_part(B.row_counts(blk)))
                 if agg_st is not None:
                     p_parts.append(pair_counts(blk, mp))
@@ -1203,6 +1236,7 @@ class Executor:
                 arrays += [_concat(p_parts), _concat(ng_parts)]
 
             def fin1(counts_np, p_np=None, ng_np=None):
+                M.REGISTRY.count(M.METRIC_GROUPBY_HOST_FETCHES)
                 keyed = []
                 for slot, row in enumerate(sts[0].row_ids):
                     agg = 0
@@ -1217,50 +1251,118 @@ class Executor:
 
         if agg_st is not None:
             mags, pos_m, neg_m = self._agg_masks(agg_st)
-        count_rows, p_rows, ng_rows = [], [], []
-        for _, a_blk in a_blocks():
+        last = sts[-1]
+        slot_rows, count_rows, p_rows, ng_rows = [], [], [], []
+
+        def count(slots, planes):
             c_cols, p_cols, ng_cols = [], [], []
-            for _, b_blk in sts[1].iter_blocks():
-                c_cols.append(sync_part(pair_counts(a_blk, b_blk)))
+            for _, b_blk in last.iter_blocks():
+                c_cols.append(sync_part(pair_counts(planes, b_blk)))
                 if agg_st is not None:
-                    p, ng = pair_sums(a_blk, b_blk, mags, pos_m, neg_m)
+                    p, ng = pair_sums(planes, b_blk, mags, pos_m, neg_m)
                     p_cols.append(sync_part(p))
                     ng_cols.append(ng)
+            slot_rows.append(slots)
             count_rows.append(_concat(c_cols, axis=1))
             if agg_st is not None:
                 p_rows.append(_concat(p_cols, axis=2))
                 ng_rows.append(_concat(ng_cols, axis=2))
-        counts = _concat(count_rows, axis=0)  # [capA, capB]
+
+        self._group_blocks(sts[:-1], filt, count)
+        counts = _concat(count_rows, axis=0)  # [groups, capLast]
         arrays = [counts]
         if agg_st is not None:
             arrays += [_concat(p_rows, axis=1), _concat(ng_rows, axis=1)]
 
-        def fin2(counts_np, p_np=None, ng_np=None):
-            keyed = []
-            ra = len(sts[0].row_ids)
-            rb = len(sts[1].row_ids)
-            gi, gj = np.nonzero(counts_np[:ra, :rb])
-            for i, j in zip(gi, gj):
-                agg = 0
-                if p_np is not None:
+        def fin(counts_np, p_np=None, ng_np=None):
+            M.REGISTRY.count(M.METRIC_GROUPBY_HOST_FETCHES)
+            slots = np.concatenate(slot_rows)  # [groups, fields - 1]
+            gi, gj = np.nonzero(counts_np[:, :len(last.row_ids)])
+            ids = [np.asarray(st.row_ids, dtype=np.uint64) for st in sts]
+            cols = [ids[f][slots[gi, f]] for f in range(len(sts) - 1)]
+            cols.append(ids[-1][gj])
+            order = np.lexsort(cols[::-1])
+            gi, gj = gi[order], gj[order]
+            keys = zip(*(c[order].tolist() for c in cols))
+            aggs = [0] * gi.size
+            if p_np is not None:
+                for n, (i, j) in enumerate(zip(gi, gj)):
                     for k in range(p_np.shape[0]):
-                        agg += (int(p_np[k, i, j]) - int(ng_np[k, i, j])) << k
-                keyed.append((
-                    (sts[0].row_ids[i], sts[1].row_ids[j]),
-                    int(counts_np[i, j]), agg))
-            keyed.sort(key=lambda kv: kv[0])
+                        aggs[n] += (int(p_np[k, i, j]) - int(ng_np[k, i, j])) << k
+            keyed = zip(keys, counts_np[gi, gj].tolist(), aggs)
             return self._groupby_emit(fields, keyed, agg_field, limit)
 
-        return _Deferred(arrays, fin2)
+        return _Deferred(arrays, fin)
+
+    def _group_blocks(self, sts, filt, sink) -> None:
+        """Feed ``sink(slots, planes)`` the group planes of the row product
+        of ``sts`` (filtered), a block at a time: ``planes[g]`` is the AND
+        of the rows whose slots ``slots[g]`` names, one per field; planes
+        past a field's last row are padding, all zero, and count nothing.
+        One field hands its own row blocks on as they are; from the second
+        on, each is crossed with the planes so far in blocks of at most
+        :func:`_group_block` planes, depth first, so what is on the device
+        at any time is a block a level, however many groups there are."""
+        st0 = sts[0]
+        n0 = len(st0.row_ids)
+        for lo, blk in st0.iter_blocks():
+            n = min(st0.block_rows, n0 - lo)
+            if n <= 0:
+                break
+            if len(sts) == 1:
+                if filt is not None:
+                    blk = B.plane_and(blk, filt[None, :])
+            elif filt is not None:
+                blk = _made(group_planes(filt[None, :], blk, 0, 0, 1, n))
+            elif n < blk.shape[0]:
+                # pad rows would multiply through every level below
+                blk = _made(blk[:n])
+            slots = np.arange(lo, lo + blk.shape[0])[:, None]
+            self._cross(sts, 1, slots, blk, sink)
+
+    def _cross(self, sts, level, slots, planes, sink) -> None:
+        """One level of :meth:`_group_blocks`: every block of ``planes``
+        AND rows of ``sts[level]``, handed to the next level as it is
+        made."""
+        if level == len(sts):
+            sink(slots, planes)
+            return
+        st = sts[level]
+        n = len(st.row_ids)
+        room = _group_block(st.total_words)
+        g = planes.shape[0]
+        with get_tracer().start_span(
+                "groupby.level", level=level, groups_in=g,
+                plane_bytes=planes.nbytes) as span:
+            blocks = 0
+            for lo, blk in st.iter_blocks():
+                r = min(st.block_rows, n - lo)
+                if r <= 0:
+                    break
+                rs = min(r, room)
+                gs = max(1, room // rs)
+                for g0 in range(0, g, gs):
+                    gn = min(gs, g - g0)
+                    for r0 in range(0, r, rs):
+                        rn = min(rs, r - r0)
+                        nxt = _made(group_planes(planes, blk, g0, r0, gn, rn))
+                        blocks += 1
+                        self._cross(sts, level + 1, np.concatenate(
+                            [np.repeat(slots[g0:g0 + gn], rn, axis=0),
+                             np.tile(np.arange(lo + r0, lo + r0 + rn),
+                                     gn)[:, None]], axis=1), nxt, sink)
+            span.set_tag("groups_live", g * n)
+            span.set_tag("blocks", blocks)
 
     def _groupby_fold(self, fields, sts, filt, agg_field, agg_st, limit):
-        """3+ field GroupBy: fold left-to-right keeping group planes on
-        device, pruning empty groups between levels (one fetch per level —
-        the reference pays a full nested iterator walk per shard instead,
-        executor.go:3918). The FIRST field streams per row block so a
-        paged (high-cardinality) leading field never materializes whole;
-        deeper levels operate on the pruned nonzero groups, whose size is
-        data-dependent exactly as in the reference's iterator walk."""
+        """GroupBy above the dense cell cap: fold left-to-right keeping
+        group planes on device, pruning empty groups between levels (one
+        fetch per level — the reference pays a full nested iterator walk
+        per shard instead, executor.go:3918). The FIRST field streams per
+        row block so a paged (high-cardinality) leading field never
+        materializes whole; deeper levels operate on the pruned nonzero
+        groups, whose size is data-dependent exactly as in the
+        reference's iterator walk."""
         keyed_all: List[Tuple] = []
         n0 = len(sts[0].row_ids)
         for lo, blk in sts[0].iter_blocks():
@@ -1269,7 +1371,7 @@ class Executor:
                 break
             group_planes = blk[: hi - lo]
             if filt is not None:
-                group_planes = B.plane_and(group_planes, filt[None, :])
+                group_planes = _made(B.plane_and(group_planes, filt[None, :]))
             keys = [(r,) for r in sts[0].row_ids[lo:hi]]
             keyed_all.extend(self._fold_levels(
                 sts, group_planes, keys, agg_st))
@@ -1281,20 +1383,25 @@ class Executor:
         fields; returns (key, count, agg) triples for nonzero groups."""
         for level, st in enumerate(sts[1:], start=1):
             nb = len(st.row_ids)
-            counts_matrix = np.concatenate(
-                [np.asarray(pair_counts(group_planes, blk))
-                 for _, blk in st.iter_blocks()], axis=1)[:, :nb]
+            with get_tracer().start_span(
+                    "groupby.level", level=level, groups_in=len(keys),
+                    plane_bytes=group_planes.nbytes) as span:
+                counts_matrix = np.concatenate(
+                    [np.asarray(pair_counts(group_planes, blk))
+                     for _, blk in st.iter_blocks()], axis=1)[:, :nb]
+                M.REGISTRY.count(M.METRIC_GROUPBY_HOST_FETCHES)
+                gi, gj = np.nonzero(counts_matrix)
+                span.set_tag("groups_live", int(gi.size))
+                span.set_tag("blocks", st.n_blocks)
             last = level == len(sts) - 1
             if last and agg_st is None:
-                gi, gj = np.nonzero(counts_matrix)
                 return [(keys[g] + (st.row_ids[r],),
                          int(counts_matrix[g, r]), 0)
                         for g, r in zip(gi, gj)]
-            gi, gj = np.nonzero(counts_matrix)
             if gi.size == 0:
                 return []
-            group_planes = group_planes[gi] & st.take_rows(
-                [st.row_ids[r] for r in gj])
+            group_planes = _made(_made(group_planes[gi]) & st.take_rows(
+                [st.row_ids[r] for r in gj]))
             keys = [keys[g] + (st.row_ids[r],) for g, r in zip(gi, gj)]
         counts = np.asarray(B.row_counts(group_planes))
         aggs = [0] * len(keys)
@@ -1307,6 +1414,7 @@ class Executor:
                 for k in range(p.shape[1]):
                     total += (int(p[g, k]) - int(ng[g, k])) << k
                 aggs[g] = total
+        M.REGISTRY.count(M.METRIC_GROUPBY_HOST_FETCHES)
         return [(keys[g], int(counts[g]), aggs[g]) for g in range(len(keys))]
 
     # -- Percentile (reference: executor.go:1310) ------------------------------
