@@ -63,7 +63,8 @@ READ_RUNS = 3
 #: code routes kernels with sharded operands (and compression) to the XLA
 #: path with why="mesh"; what still dispatches there is listed below.
 EXPECTED_KERNELS = ("tape_count", "bsi_compare", "bsi_sum", "topn",
-                    "pair_counts", "ingest_scatter", "ctile_count")
+                    "pair_counts", "pair_sums", "ingest_scatter",
+                    "ctile_count")
 EXPECTED_KERNELS_MESH = ("ingest_scatter",)
 
 
@@ -159,6 +160,24 @@ def read_checks(d):
             seen.add(b)
         return len(res) == 100 and len(seen) == 100
 
+    sel = d["shipmode"] == 2
+    cell = (year * BRANDS + brand)[sel]
+    sel_n = np.bincount(cell, minlength=YEARS * BRANDS)
+    # float64 weights are exact here: a sum stays far below 2^53
+    sel_rev = np.bincount(cell, weights=rev[sel].astype(np.float64),
+                          minlength=YEARS * BRANDS)
+
+    def groupby_sum(res):
+        # every non-empty (year, brand) cell of the filtered rows, once,
+        # with its count and the exact sum of its revenue
+        got = {(g["group"][0]["rowID"],
+                int(g["group"][1]["rowKey"][5:]) - 1000):
+               (g["count"], g["agg"]) for g in res}
+        want = {(int(c) // BRANDS, int(c) % BRANDS):
+                (int(sel_n[c]), int(sel_rev[c]))
+                for c in np.flatnonzero(sel_n)}
+        return len(got) == len(res) and got == want
+
     def topn_day(res):
         order = sorted(range(day_n.size), key=lambda r: (-day_n[r], r))[:5]
         return ([(p["id"], p["count"]) for p in res["rows"]]
@@ -175,6 +194,9 @@ def read_checks(d):
          == (int(rev[sel2].sum()), int(sel2.sum()))),
         ("topn", "TopN(brand, n=10)", topn_brand),
         ("groupby", "GroupBy(Rows(year), Rows(brand), limit=100)", groupby),
+        ("groupby_sum", "GroupBy(Rows(year), Rows(brand), "
+         "filter=Row(shipmode=2), aggregate=Sum(field=revenue))",
+         groupby_sum),
         ("compressed_row", "Count(Row(loadday=5))",
          lambda r: r == int(day_n[5]) == DAY_COLS),
         ("compressed_topn", "TopN(loadday, n=5)", topn_day),

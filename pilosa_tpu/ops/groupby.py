@@ -17,6 +17,12 @@ systolic array's native op — the core of BASELINE.json config 3
 
 Column blocking keeps the int8 expansion in VMEM-sized chunks instead of
 materializing ``rows x 2^20`` lanes in HBM.
+
+Two forms of the one matmul: a fused Pallas kernel that expands in VMEM
+(:func:`_pair_counts_traced`) and an XLA scan that any backend and any
+sharding takes (:func:`_pair_counts_xla`). :func:`pair_counts` and
+:func:`pair_sums` pick between them from what ``pallas_util.why_not``
+sees in their concrete operands: backend, sharding, rows.
 """
 
 from __future__ import annotations
@@ -64,9 +70,12 @@ def pair_counts(a, b, block_words: int = BLOCK_WORDS):
     ``PILOSA_TPU_PALLAS=1``, via the interpreter) take the fused Pallas
     expand+matmul kernel (~1.9x the XLA scan — the expansion stays in
     VMEM instead of staging int8 lanes through HBM); traced values
-    (inside jit/shard_map, e.g. the mesh path's psum reduction) and
-    other backends take the XLA scan. Outcomes are counted on the
-    ``ops_pallas_*`` metrics (ops/pallas_util.py)."""
+    (inside jit/shard_map, e.g. the mesh path's psum reduction),
+    mesh-sharded operands and other backends take the XLA scan: a
+    jitted program that wants the kernel chooses its route where its
+    operands are still concrete and calls :func:`_pair_counts_traced`
+    itself (:func:`pair_sums`, ops/bsi.py, ops/topk.py). Outcomes are
+    counted on the ``ops_pallas_*`` metrics (ops/pallas_util.py)."""
     why = PU.why_not("pair_counts", a, b, max_rows=_PALLAS_MAX_R1)
     if why is None:
         try:
@@ -224,28 +233,76 @@ def masked_pair_counts(a, b, filt):
     return pair_counts(a & filt[None, :], b & filt[None, :])
 
 
-@platform.guarded_call
-@jax.jit
 def pair_sums(a, b, mags, pos, neg):
     """Per-magnitude-plane pair counts for two-field GroupBy with a Sum
     aggregate: three-way popcounts as matmuls,
 
         pos_k[i, j] = popcount(A_i & B_j & M_k & pos)
 
-    since popcount(P & Q) = sum_c P[c]*Q[c] with P = A_i & pos,
-    Q = B_j & M_k. The host assembles the exact per-group sum
+    The host assembles the exact per-group sum
     ``sum_k 2^k (pos_k - neg_k)`` with Python ints (reference walks group
     bitmaps one at a time through fragment.sum, executor.go:3176 +
     fragment.go:724).
 
+    Dispatch as :func:`pair_counts`, decided here on the concrete
+    operands: one scan over the planes either way, each step a fused
+    Pallas pair count (:func:`_pair_sums_pallas`) or two XLA ones
+    (:func:`_pair_sums_xla`).
+
     Returns (pos int32[D, R1, R2], neg int32[D, R1, R2]).
     """
+    why = PU.why_not("pair_sums", a, b, mags, max_rows=_PALLAS_MAX_R1)
+    if why is None:
+        try:
+            with PU.kernel_scope("mm", 2 * mags.shape[0] * a.shape[0],
+                                 b.shape[0], 5, a.shape[1]):
+                out = _pair_sums_pallas(a, b, mags, pos, neg)
+            PU.dispatched("pair_sums")
+            return out
+        except Exception as e:
+            PU.failed("pair_sums", e)
+    else:
+        PU.fallback("pair_sums", why)
+    return _pair_sums_xla(a, b, mags, pos, neg)
+
+
+@platform.guarded_call
+@jax.jit
+def _pair_sums_xla(a, b, mags, pos, neg):
+    """The XLA route of :func:`pair_sums` (partitionable; all backends):
+    popcount(P & Q) = sum_c P[c]*Q[c] with P = A_i & sign, Q = B_j & M_k,
+    two pair counts a plane, which see tracers and take the XLA scan."""
     ap = a & pos[None, :]
     an = a & neg[None, :]
 
     def step(_, mk):
         bm = b & mk[None, :]
         return None, (pair_counts(ap, bm), pair_counts(an, bm))
+
+    _, (p, n) = lax.scan(step, None, mags)
+    return p, n
+
+
+@platform.guarded_call
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _pair_sums_pallas(a, b, mags, pos, neg, interpret=None):
+    """The Pallas route of :func:`pair_sums`: the plane's mask goes on
+    the small side, P = A_i & sign & M_k, so ``b`` reaches the kernel as
+    it is and no ``b & M_k`` is written and read back a step; both signs
+    stack into one first operand, so the kernel expands ``b`` once a
+    plane, not twice (two calls a step where the stack would pass the
+    kernel's row limit)."""
+    if interpret is None:  # static: resolved once per trace
+        interpret = PU.use_interpret()
+    r1 = a.shape[0]
+    firsts = [a & pos[None, :], a & neg[None, :]]
+    if 2 * r1 <= _PALLAS_MAX_R1:
+        firsts = [jnp.concatenate(firsts)]
+
+    def step(_, mk):
+        c = jnp.concatenate([_pair_counts_traced(x & mk[None, :], b, interpret)
+                             for x in firsts])
+        return None, (c[:r1], c[r1:])
 
     _, (p, n) = lax.scan(step, None, mags)
     return p, n

@@ -21,7 +21,7 @@ sys.path.insert(0, str(ROOT))
 import chip_smoke  # noqa: E402
 
 ORACLE_CHECKS = {"tape_count", "bsi_compare", "bsi_sum", "topn", "groupby",
-                 "compressed_row", "compressed_topn", "sql_count",
+                 "groupby_sum", "compressed_row", "compressed_topn", "sql_count",
                  "write_before", "write_readback"}
 
 

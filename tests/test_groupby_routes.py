@@ -12,6 +12,7 @@ from pilosa_tpu.core import FieldOptions, FieldType, Holder
 from pilosa_tpu.core import stacked as stx
 from pilosa_tpu.obs import metrics as M
 from pilosa_tpu.ops import ctiles
+from pilosa_tpu.ops import pallas_util as PU
 from pilosa_tpu.pql import Executor
 from pilosa_tpu.pql import executor as ex
 from pilosa_tpu.shardwidth import SHARD_WIDTH
@@ -89,11 +90,15 @@ MODES = {
     "above-cap": (False, False, None, False, False, True),
     "above-cap-filter-sum": (True, True, None, False, False, True),
     "above-cap-compressed-paged": (True, False, 7, True, True, True),
+    # the kernels' own bodies under the Pallas interpreter, at this width
+    # (two fields only: pair_sums' case, seconds a read)
+    "pallas-filter-sum": (True, True, None, False, False, False),
 }
 
 
-@pytest.mark.parametrize("mode", sorted(MODES))
-@pytest.mark.parametrize("n_fields", [1, 2, 3, 4])
+@pytest.mark.parametrize("n_fields,mode", [
+    (n, mode) for mode in sorted(MODES) for n in (1, 2, 3, 4)
+    if n == 2 or not mode.startswith("pallas")])
 def test_groupby_equals_the_numpy_reference(n_fields, mode, monkeypatch):
     filtered, with_sum, limit, compressed, paged, above = MODES[mode]
     if compressed:
@@ -104,6 +109,13 @@ def test_groupby_equals_the_numpy_reference(n_fields, mode, monkeypatch):
         monkeypatch.setattr(stx, "BUDGET", stx.DeviceBudget(14 << 20))
     if above:
         monkeypatch.setattr(ex, "_DENSE_MAX_CELLS", 2)
+    if mode.startswith("pallas"):
+        monkeypatch.setenv("PILOSA_TPU_PALLAS", "1")
+        monkeypatch.setattr(PU, "INTERPRET_MAX_WORDS",
+                            SHARDS * SHARD_WIDTH // 32)
+        PU.reset_failures()
+    sums0 = M.REGISTRY.value(M.METRIC_OPS_PALLAS_DISPATCH,
+                             kernel="pair_sums")
     h = Holder()
     cols, slots, v = load(h)
     names = ORDER[:n_fields]
@@ -129,6 +141,10 @@ def test_groupby_equals_the_numpy_reference(n_fields, mode, monkeypatch):
     assert len(got) > 1
     assert M.REGISTRY.value(M.METRIC_GROUPBY_ROUTE, route=route,
                             fields=label) == before + 1
+    # a two-field Sum is pair_sums': one kernel dispatch a pair of blocks
+    assert M.REGISTRY.value(
+        M.METRIC_OPS_PALLAS_DISPATCH, kernel="pair_sums") - sums0 == (
+            mode.startswith("pallas") and n_fields == 2)
     if paged and "d" in names:
         d = h.index("t").field("d")
         assert any(st.paged for inner in d._stacked_cache.values()

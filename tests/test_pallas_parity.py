@@ -84,6 +84,123 @@ def test_pair_counts_all_set_and_empty(rng, forced):
 
 
 # ---------------------------------------------------------------------------
+# pair_sums (two-field GroupBy with a Sum)
+# ---------------------------------------------------------------------------
+
+
+def sum_operands(rng, r1, r2, d, w):
+    """Two row sets, ``d`` magnitude planes and the two sign masks (pos
+    and neg disjoint, neither empty, some columns in neither)."""
+    exists, sign = rand_planes(rng, 2, w)
+    return (rand_planes(rng, r1, w), rand_planes(rng, r2, w),
+            rand_planes(rng, d, w), exists & ~sign, exists & sign)
+
+
+def pair_sums_numpy(a, b, mags, pos, neg):
+    """popcount(A_i & B_j & M_k & sign), nothing from the program."""
+    def popcount(x):
+        return np.unpackbits(x.view(np.uint8), axis=-1).sum(
+            -1, dtype=np.int32)
+
+    ab = a[:, None, :] & b[None, :, :]
+    return (np.stack([popcount(ab & (m & pos)) for m in mags]),
+            np.stack([popcount(ab & (m & neg)) for m in mags]))
+
+
+def fallback_count(kernel, why):
+    return M.REGISTRY.value(M.METRIC_OPS_PALLAS_FALLBACK, kernel=kernel,
+                            why=why)
+
+
+@pytest.mark.parametrize("r1,r2,d,w,why", [
+    (1, 1, 1, 1, None),        # single word, single plane
+    (7, 25, 3, 7, None),       # r1 not a sublane multiple, nothing aligned
+    (8, 256, 4, 512, None),    # the served shape: tile-aligned
+    (8, 300, 2, 512, None),    # two row tiles of the second operand
+    (70, 9, 2, 40, None),      # 2 * r1 > 128 >= r1: two calls a step
+    (128, 8, 1, 16, None),     # the kernel's row limit
+    (130, 4, 2, 16, "shape"),  # past it: the XLA scan
+])
+def test_pair_sums_parity(rng, forced, r1, r2, d, w, why):
+    ops = sum_operands(rng, r1, r2, d, w)
+    assert ops[3].any() and ops[4].any()
+    before = dispatch_count("pair_sums")
+    refused = fallback_count("pair_sums", "shape")
+    got = [np.asarray(x) for x in G.pair_sums(*ops)]
+    assert dispatch_count("pair_sums") == before + (why is None)
+    assert fallback_count("pair_sums", "shape") == refused + (
+        why == "shape")
+    assert [x.shape for x in got] == [(d, r1, r2)] * 2
+    assert [x.dtype for x in got] == [np.int32] * 2
+    for g, x, n in zip(got, G._pair_sums_xla(*ops), pair_sums_numpy(*ops)):
+        np.testing.assert_array_equal(g, np.asarray(x))
+        np.testing.assert_array_equal(g, n)
+
+
+def test_pair_sums_all_set_and_empty_planes(rng, forced):
+    ones = np.full((4, WORDS), 0xFFFFFFFF, dtype=np.uint32)
+    pos, neg = rand_planes(rng, 2)
+    neg &= ~pos
+    # plane 0 all set, plane 1 empty
+    mags = np.stack([ones[0], np.zeros(WORDS, dtype=np.uint32)])
+    p, n = (np.asarray(x) for x in G.pair_sums(ones, ones, mags, pos, neg))
+    for got, mask in ((p, pos), (n, neg)):
+        bits = int(np.unpackbits(mask.view(np.uint8)).sum())
+        np.testing.assert_array_equal(
+            got[0], np.full((4, 4), bits, dtype=np.int32))
+        np.testing.assert_array_equal(got[1], np.zeros((4, 4), np.int32))
+    # no negative value at all: the neg half of the stacked operand is empty
+    p, n = G.pair_sums(ones, ones, mags, pos, np.zeros_like(pos))
+    assert not np.asarray(n).any() and np.asarray(p)[0, 0, 0] > 0
+
+
+def test_pair_sums_keeps_the_scan_the_roofline_reads(rng):
+    """``pair_sums_roofline`` (benchmark/layer_metrics) finds the call by
+    its ``%while`` and reads D, R1, R2 from the stacked accumulators the
+    loop carries: the Pallas route is still one scan over the D planes
+    that stacks two ``int32[D, R1, R2]``, its step one kernel call."""
+    import jax
+
+    d, r1, r2 = 5, 8, 16
+    ops = sum_operands(rng, r1, r2, d, WORDS)
+    jaxpr = jax.make_jaxpr(
+        lambda *xs: G._pair_sums_pallas.__wrapped__(*xs, interpret=False)
+    )(*ops).jaxpr
+    (inner,) = [e for e in jaxpr.eqns if e.primitive.name in ("pjit", "jit")]
+    eqns = inner.params["jaxpr"].jaxpr.eqns
+    (scan,) = [e for e in eqns if e.primitive.name == "scan"]
+    assert not [e for e in eqns if e.primitive.name == "while"]
+    assert scan.params["length"] == d and scan.params["num_carry"] == 0
+    assert [(v.aval.shape, v.aval.dtype) for v in scan.outvars] == [
+        ((d, r1, r2), np.int32)] * 2
+    body = [e.primitive.name for e in scan.params["jaxpr"].jaxpr.eqns]
+    assert body.count("pallas_call") == 1
+    # the second operand reaches the kernel as the program's own argument
+    assert "scan" not in body and "while" not in body
+
+
+def test_pair_sums_mesh_sharded_operand_takes_xla(rng, forced, monkeypatch):
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from pilosa_tpu.parallel import mesh
+
+    m = mesh.analytics_mesh(jax.devices())
+    a, b, mags, pos, neg = sum_operands(rng, 8, 16, 3, WORDS)
+    sharded = jax.device_put(
+        b, NamedSharding(m, P(None, (mesh.SHARD_AXIS, mesh.COL_AXIS))))
+    want = pair_sums_numpy(a, b, mags, pos, neg)
+    monkeypatch.setattr(PU, "use_interpret", lambda: False)
+    before = dispatch_count("pair_sums")
+    refused = fallback_count("pair_sums", "mesh")
+    got = G.pair_sums(a, sharded, mags, pos, neg)
+    assert dispatch_count("pair_sums") == before
+    assert fallback_count("pair_sums", "mesh") == refused + 1
+    for g, n in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), n)
+
+
+# ---------------------------------------------------------------------------
 # BSI sum / plane popcounts
 # ---------------------------------------------------------------------------
 
@@ -318,6 +435,11 @@ def test_kill_switch_zero_dispatch_zero_overhead(rng, killed):
     np.testing.assert_array_equal(np.asarray(G.pair_counts(a, b)),
                                   np.asarray(G._pair_counts_xla(a, b)))
     S.bsi_compare(encode(np.random.default_rng(7))[2], S.GT, 0)
+    ops = sum_operands(rng, 4, 4, 2, WORDS)
+    sums_d = dispatch_count("pair_sums")
+    for got, want in zip(G.pair_sums(*ops), pair_sums_numpy(*ops)):
+        np.testing.assert_array_equal(np.asarray(got), want)
+    assert dispatch_count("pair_sums") == sums_d
     assert M.REGISTRY.value(M.METRIC_OPS_PALLAS_DISPATCH,
                             kernel="pair_counts") == snap_d
     # the switch must not even tick the fallback counter

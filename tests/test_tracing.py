@@ -12,6 +12,7 @@ leg's span must stay a child of the coordinator's query span).
 import json
 import random
 import threading
+import time
 import urllib.error
 import urllib.request
 
@@ -247,8 +248,14 @@ class TestCrossThreadParentage:
                 {"a": [1, 2]}, {"a": "A", "b": "B"}, run_remote,
                 lambda s, r: {"b": list(s)})
         assert parts == [("part", "B")] and failed == []
-        doc = root.to_json()
-        legs = _find(doc, "cluster.leg")
+        # run_legs does not wait for the cancelled loser (pool.shutdown
+        # without wait): its span joins the tree when its thread wakes
+        give_up = time.monotonic() + 10.0
+        while True:
+            legs = _find(root.to_json(), "cluster.leg")
+            if len(legs) == 2 or time.monotonic() > give_up:
+                break
+            time.sleep(0.01)
         assert len(legs) == 2  # primary + hedge, both under the root
         by_hedge = {leg["tags"]["hedge"]: leg for leg in legs}
         assert by_hedge[True]["tags"]["node"] == "b"
