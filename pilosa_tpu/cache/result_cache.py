@@ -8,8 +8,8 @@ concern, never a correctness one.
 Single flight: the first thread to miss on a key becomes the *leader*
 and computes; concurrent threads missing on the same key become
 *followers* and block on the leader's future instead of dispatching a
-duplicate kernel. Under the 64-way concurrent bench this collapses
-identical cold queries to one dispatch.
+duplicate kernel: identical cold queries that arrive together collapse
+to one dispatch.
 
 Values are deep-copied on insert and on every hit so callers can mutate
 their result (sql/engine.py stamps ``exec_ms`` on returned SQLResults)

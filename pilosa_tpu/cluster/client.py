@@ -135,9 +135,8 @@ class InternalClient:
         # extra round-trips. ClusterNode.enable_gossip wires this.
         self.gossip = None
         self.pool = _ConnPool(per_key=pool_size)
-        # wire-RPC accounting by op tag (one increment per actual send
-        # attempt, retries included) — bench.py compares batched vs
-        # unbatched fan-out RPC counts from these
+        # wire-RPC accounting by op tag: one increment per actual send
+        # attempt, retries included
         self.op_counts: Dict[str, int] = {}
         self._count_lock = locktrace.tracked_lock("cluster.client.counts")
 

@@ -1,41 +1,4 @@
-"""Open-loop standing-load soak harness.
-
-Closed-loop load generators (fire, wait, fire again) lie under
-overload: when the system slows down the generator slows with it, so
-the measured latency distribution silently drops every request the
-generator *would* have sent — coordinated omission. This package is the
-open-loop antidote: virtual users fire on a fixed schedule regardless
-of completion, and every operation's latency is measured from its
-**intended** send time, so queueing delay shows up as latency instead
-of vanishing.
-
-Layout:
-    driver.py     OpenLoopDriver — schedule generation, real-time and
-                  ManualClock (deterministic) execution, LoadReport
-    scenarios.py  ScenarioMix — weighted scenario kinds (interactive
-                  PQL, SQL SELECT, stream push, bulk import, quota
-                  churn) picked per-op from a seeded RNG
-    tenants.py    SyntheticTenants — 10^4..10^6 seeded tenant IDs with
-                  a skewed (Zipf-ish) pick distribution
-    chaos.py      ChaosSchedule — FaultPlan chaos + membership churn
-                  events applied at schedule offsets
-
-The driver is deliberately agnostic about *how* an operation executes:
-the caller supplies ``execute(op) -> outcome`` (HTTP against a
-LocalCluster, in-process API calls, ...), so the same harness drives
-the c22 bench gate, the tier-1 smoke lane, and unit tests.
-"""
-
-from pilosa_tpu.loadgen.chaos import ChaosSchedule
-from pilosa_tpu.loadgen.driver import LoadReport, OpenLoopDriver, Op
-from pilosa_tpu.loadgen.scenarios import (
-    KIND_BULK_IMPORT, KIND_INTERACTIVE, KIND_QUOTA_CHURN, KIND_SQL,
-    KIND_STREAM_PUSH, ScenarioMix,
-)
-from pilosa_tpu.loadgen.tenants import SyntheticTenants
-
-__all__ = [
-    "ChaosSchedule", "KIND_BULK_IMPORT", "KIND_INTERACTIVE",
-    "KIND_QUOTA_CHURN", "KIND_SQL", "KIND_STREAM_PUSH", "LoadReport",
-    "Op", "OpenLoopDriver", "ScenarioMix", "SyntheticTenants",
-]
+"""The Star Schema Benchmark star-schema generator and its numpy oracle
+(``ssb.py``): seeded lineorder + date / customer / supplier / part
+tables, the 13 queries Q1.1-Q4.3 as SQL, and an independent reference
+for every answer."""

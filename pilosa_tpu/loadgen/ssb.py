@@ -4,8 +4,8 @@ independent numpy oracle.
 Reference: O'Neil et al., "The Star Schema Benchmark" (the standard
 join workload derived from TPC-H) — lineorder fact plus date /
 customer / supplier / part dimensions, four query flights Q1–Q4. Sizes
-here are scale-factor-ish, parameterized by the lineorder row count so
-tier-1 smoke (tiny) and bench.py --configs 23 share one generator.
+here are scale-factor-ish, parameterized by the lineorder row count:
+tests/test_ssb.py runs the ``tiny`` scale.
 
 Dialect notes against the classic text:
 
@@ -170,8 +170,8 @@ def _sql_val(v) -> str:
 def load(run_sql: Callable[[str], Any], data: SSBData,
          batch: int = 500) -> None:
     """Create the five tables and insert ``data`` through ``run_sql``
-    (an engine.query or an HTTP /sql POST — transport-agnostic so the
-    cluster bench reuses it)."""
+    (``api.sql``, a cluster coordinator's ``sql`` or an HTTP /sql POST:
+    the transport is the caller's)."""
     for ddl in _DDL:
         run_sql(ddl)
     tables = [("ssb_date", data.date), ("customer", data.customer),

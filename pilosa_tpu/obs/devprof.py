@@ -4,8 +4,7 @@ The tracing plane attributes *latency*; this plane attributes
 *efficiency*. The two biggest ROADMAP items — the bulk-bitwise Pallas
 rewrite (c3: 15.4 TFLOPS at 3.9% MFU) and the streaming-ingest gap —
 need FLOPs, bytes moved, and achieved-vs-peak per kernel and per
-pipeline stage, which previously existed only as ad-hoc math inside
-``bench.py`` config 3. Three pieces:
+pipeline stage. Three pieces:
 
 **Analytic cost model.** A compiled op tape (pql/programs.py) is a
 register machine over uint32 word-planes: each binary op touches every
@@ -43,14 +42,14 @@ Zero-cost when disabled: ``ENABLED`` is False by default
 guards on the module flag before touching this module's state, and the
 platform hooks are only installed while enabled — the disabled path
 adds no allocations (``cost_evals()`` + ``KERNELS.allocations`` back
-the bench gate's zero-work assert). Hook callbacks run *after* the
+the zero-work assert of tests/test_devprof.py). Hook callbacks run *after* the
 dispatch guard is released and do pure in-memory appends, so the
 leaf-lock rule is untouched.
 
 Measurement caveat: on CPU the guard blocks until ready, so device time
 is real wall time; on async device backends the dispatch wall time is a
-launch-overhead floor and MFU is an upper bound until a blocking bench
-(configs 13/16) forces completion inside the measured window.
+launch-overhead floor and MFU is an upper bound unless the caller
+blocks on the result inside the measured window.
 """
 
 from __future__ import annotations
@@ -69,7 +68,7 @@ from pilosa_tpu.obs import metrics as M
 from pilosa_tpu.obs.stages import INGEST
 
 #: Module switch consulted by every kernel instrumentation site
-#: (programs, Pallas dispatches, bench). Flip via enable()/disable() so
+#: (programs, Pallas dispatches). Flip via enable()/disable() so
 #: the platform hooks stay in sync; operators use the env var.
 ENABLED = env_bool("PILOSA_TPU_DEVPROF", False)
 
@@ -92,7 +91,7 @@ PEAK_TABLE: Dict[str, Tuple[float, float]] = {
 _DEVICE_KIND: Optional[str] = None
 
 # Cost-model evaluation counter: the "exactly zero cost-model work when
-# disabled" gates (bench --configs 16, tier1 devprof lane) snapshot it.
+# disabled" checks (tests/test_devprof.py, tier1 devprof lane) snapshot it.
 _COST_EVALS = 0
 
 _TLS = threading.local()
@@ -446,8 +445,8 @@ def disable() -> None:
 
 
 def reset() -> None:
-    """Clear accumulated profiles/stages (bench phases; tests). Leaves
-    the enable state and the cost-eval counter alone."""
+    """Clear accumulated profiles/stages. Leaves the enable state and
+    the cost-eval counter alone."""
     KERNELS.reset()
     INGEST.reset()
 

@@ -24,9 +24,9 @@ Unknown/absent/garbage tenant values NEVER fail the request: they clamp
 to the ``"default"`` tenant and bump ``tenant_unattributed_total``.
 
 When the plane is disabled (``api.tenants is None``) the request path
-does no tenant work at all beyond one ``is None`` check — the bench
-(config 18) hard-asserts zero scopes entered in the disabled phase via
-the module-level ``SCOPE_COUNT``.
+does no tenant work at all beyond one ``is None`` check:
+tests/test_tenants.py asserts zero scopes entered while disabled via the
+module-level ``SCOPE_COUNT``.
 """
 
 from __future__ import annotations
@@ -58,7 +58,7 @@ _CURRENT: contextvars.ContextVar[Optional[str]] = contextvars.ContextVar(
     "pilosa_tenant", default=None)
 
 #: scopes entered since import — the disabled-path allocation proof
-#: (bench config 18 asserts this does not move when the plane is off)
+#: (tests/test_tenants.py asserts it does not move when the plane is off)
 SCOPE_COUNT = 0
 
 

@@ -68,8 +68,8 @@ def pair_counts(a, b, block_words: int = BLOCK_WORDS):
 
     Dispatch: concrete arrays on a TPU backend (or anywhere under
     ``PILOSA_TPU_PALLAS=1``, via the interpreter) take the fused Pallas
-    expand+matmul kernel (~1.9x the XLA scan — the expansion stays in
-    VMEM instead of staging int8 lanes through HBM); traced values
+    expand+matmul kernel (the expansion stays in VMEM instead of
+    staging int8 lanes through HBM); traced values
     (inside jit/shard_map, e.g. the mesh path's psum reduction),
     mesh-sharded operands and other backends take the XLA scan: a
     jitted program that wants the kernel chooses its route where its
@@ -89,12 +89,6 @@ def pair_counts(a, b, block_words: int = BLOCK_WORDS):
     else:
         PU.fallback("pair_counts", why)
     return _pair_counts_xla(a, b, block_words)
-
-
-def _pallas_eligible(a, b) -> bool:
-    """Shared eligibility rule (ops/pallas_util.py); bench.py pins its
-    kernel choice through this predicate."""
-    return PU.why_not("pair_counts", a, b, max_rows=_PALLAS_MAX_R1) is None
 
 
 def _expand_bitmajor(x):

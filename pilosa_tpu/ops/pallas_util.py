@@ -179,7 +179,7 @@ def disable_kernel(kernel: str) -> None:
 
 
 def reset_failures() -> None:
-    """Test/bench hook: forget strike counts."""
+    """Test hook: forget strike counts."""
     with _LOCK:
         _FAILURES.clear()
 

@@ -1,7 +1,7 @@
 """JAX platform selection helpers.
 
-One place for the CPU-pinning idiom used by tests, the bench driver, and
-the multichip dryrun, and for placing the persistent compile cache. JAX
+One place for the CPU-pinning idiom used by tests and the multichip
+dryrun, and for placing the persistent compile cache. JAX
 reads ``JAX_PLATFORMS`` once at import, so pinning a process that has
 already imported jax must override the ``jax_platforms`` *config* as
 well — and it must happen before the first ``jax.devices()`` call
@@ -9,10 +9,10 @@ initializes a backend.
 
 A chip belongs to one process: a parent that has touched JAX holds it
 and a child that needs it then fails or hangs. Orchestrators
-(``bench.py``, ``chip_smoke.py``) therefore stay off JAX and give the
-device to one child at a time; in-process multi-node harnesses
-(``cluster`` LocalCluster, ``dax`` DaxCluster, ``loadgen``) share the
-one chip by design.
+(``chip_smoke.py``, ``benchmark/run.py``) therefore stay off JAX and
+give the device to one child; in-process multi-node harnesses
+(``cluster`` LocalCluster, ``dax`` DaxCluster) share the one chip by
+design.
 """
 
 from __future__ import annotations

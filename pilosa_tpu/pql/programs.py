@@ -17,11 +17,9 @@ Lowering never re-stages data: leaves are slices of the budget-managed
 resident stacks (core/stacked.py), so a warm query's trace carries no
 ``stack.build`` / ``device.h2d_copy`` stage at all. Anything the tape
 cannot express bit-identically (ConstRow, UnionRows, Shift, Distinct,
-host-scan calls) bails to the executor's classic path — the oracle the
-bench compares against.
-
-Kill switch: ``PILOSA_TPU_RESIDENT_PROGRAMS=0`` disables lowering
-entirely (bench.py toggles the module flag for its oracle phase).
+host-scan calls) bails to the executor's classic path, which is also the
+reference tests/test_resident.py holds the programs to (it sets
+``ENABLED`` False for the reference pass).
 """
 
 from __future__ import annotations
@@ -33,7 +31,6 @@ from typing import List, Optional, Tuple
 import jax.numpy as jnp
 
 from pilosa_tpu import platform
-from pilosa_tpu.config import env_bool
 from pilosa_tpu.core import timeq
 from pilosa_tpu.obs import devprof
 from pilosa_tpu.core.stacked import stacked_set
@@ -42,9 +39,9 @@ from pilosa_tpu.ops import pallas_util as PU
 from pilosa_tpu.pql.ast import Condition, ROW_OPTIONS
 from pilosa_tpu.shardwidth import WORDS_PER_SHARD
 
-#: Module switch consulted per query (bench.py flips it to run the
-#: non-resident oracle; operators use the env var).
-ENABLED = env_bool("PILOSA_TPU_RESIDENT_PROGRAMS", True)
+#: Consulted per query: False sends every call tree down the classic
+#: per-op path.
+ENABLED = True
 
 
 class _Bail(Exception):

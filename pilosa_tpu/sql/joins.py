@@ -30,7 +30,7 @@ Shapes the rewriter can't prove safe (OUTER joins, non-FK ON
 conditions, unlowerable dimension predicates, cross-table residuals)
 return ``None`` and the planner falls back to the host hash join —
 never a silently wrong answer. ``PILOSA_TPU_SEMIJOIN=0`` disables the
-plane entirely (the bench baseline).
+plane entirely: the hash-join reference of tests/test_ssb.py.
 """
 
 from __future__ import annotations

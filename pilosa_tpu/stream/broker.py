@@ -3,8 +3,8 @@
 The continuous-ingest pipeline (stream/pipeline.py) consumes through the
 :class:`StreamConsumer` protocol — poll / commit / committed / seek /
 pause / resume — which both this broker's :class:`BrokerConsumer` and the
-gated ``ingest/kafka.KafkaSource`` implement, so tests, bench, and chaos
-lanes run without external Kafka while the real client drops in
+gated ``ingest/kafka.KafkaSource`` implement, so tests and chaos lanes
+run without external Kafka while the real client drops in
 unchanged.
 
 The broker is a durable-log *shape*, not a durable log: topics are
@@ -342,7 +342,7 @@ class BrokerSource(Source):
     """Adapts a :class:`StreamConsumer` to the classic ``Source``
     protocol so the single-threaded ``Ingester`` can drain the same
     stream — the bit-identity oracle the pipelined path is checked
-    against (bench ``--configs 17``, tests/test_stream.py)."""
+    against (tests/test_stream.py)."""
 
     def __init__(self, consumer: StreamConsumer, schema,
                  id_col: Optional[str] = "id", batch: int = 4096):

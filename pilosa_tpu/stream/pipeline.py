@@ -262,8 +262,7 @@ class PipelinedIngester:
         """Chunked fast path (broker.make_chunk): every message already
         carries equal-length columns, so parse + translate collapse to
         one numpy conversion per field instead of a Python loop per
-        cell — this is what holds the sustained-rate bound (bench
-        config 17). Chunk cells are dense scalars by contract."""
+        cell. Chunk cells are dense scalars by contract."""
         idx = self._idx
         # name -> list of column sequences (concatenated lazily so numpy
         # columns never round-trip through Python objects)

@@ -15,9 +15,9 @@ Usage:
     scripts/lint_invariants.py --list-rules
     scripts/lint_invariants.py --selftest             # exercises every rule
 
-``--selftest`` mirrors ``bench_compare.py --selftest``: it seeds one
-positive and one negative fixture per rule plus a baseline round-trip,
-so the gate logic itself is testable without the tree.
+``--selftest`` seeds one positive and one negative fixture per rule
+plus a baseline round-trip, so the gate logic itself is testable
+without the tree.
 
 Wired into tier1.sh as the analysis lane's first step.
 """

@@ -1,6 +1,11 @@
 #!/usr/bin/env bash
-# Tier-1 verification: syntax smoke, cache-key determinism gate, then the
-# full test suite (the exact command ROADMAP.md documents).
+# Tier-1 verification: syntax smoke, the analysis lanes, the suites
+# re-run under each plane's environment switch and under fixed fault /
+# crash / gossip / hash seeds, then the full test suite with the command
+# the PR driver runs (/root/TESTS_LAST_RUN.json, `commands`; the driver
+# also sets ALLOW_MULTIPLE_LIBTPU_LOAD=1 on its sandbox, which a repo
+# file must not). The driver runs only that last command; every lane
+# before it is run by hand.
 #
 # The determinism gate runs tests/test_cache.py under two different
 # PYTHONHASHSEED values: result-cache keys embed fragment-version
@@ -143,24 +148,6 @@ PILOSA_TPU_DEVICE_BUDGET=$((8 << 20)) PILOSA_TPU_BLOCK_BYTES_MB=4 \
     tests/test_stacked_merge.py -q -p no:cacheprovider \
     -p no:xdist -p no:randomly || exit $?
 
-echo "== resident warm-vs-cold bench gate (bench.py --configs 13) =="
-# Hard-asserts the ISSUE 8 acceptance bar in-process: warm resident p50
-# >= 5x below cold, results bit-identical to the non-resident oracle,
-# and no device.h2d_copy stage in any warm query's trace.
-JAX_PLATFORMS=cpu python bench.py --configs 13 || exit $?
-
-echo "== coalesced fan-out bench gate (bench.py --configs 14) =="
-# Hard-asserts the ISSUE 9 acceptance bar in-process: >=8x fewer
-# per-node RPCs at 64-way concurrency with the coalescer on, every
-# result bit-identical to the numpy oracle (including the chaos wave).
-JAX_PLATFORMS=cpu python bench.py --configs 14 || exit $?
-
-echo "== health-plane overhead bench gate (bench.py --configs 15) =="
-# Hard-asserts the ISSUE 10 acceptance bar in-process: bit-identical
-# results with the always-on piggyback plane, zero health-plane work
-# when disabled, and the sampler actually firing when enabled.
-JAX_PLATFORMS=cpu python bench.py --configs 15 || exit $?
-
 echo "== devprof lane (PILOSA_TPU_DEVPROF=1) =="
 # The kernel-attribution plane rides every compiled dispatch in these
 # suites: results must stay bit-identical with profiling on, and the
@@ -169,12 +156,6 @@ PILOSA_TPU_DEVPROF=1 JAX_PLATFORMS=cpu \
     python -m pytest tests/test_resident.py tests/test_tracing.py \
     tests/test_health.py tests/test_devprof.py -q -p no:cacheprovider \
     -p no:xdist -p no:randomly || exit $?
-
-echo "== devprof overhead bench gate (bench.py --configs 16) =="
-# Hard-asserts the ISSUE 11 acceptance bar in-process: bit-identical
-# results with PILOSA_TPU_DEVPROF=1, zero cost-model allocations when
-# disabled, and a profile with MFU/GB/s for every compiled family.
-JAX_PLATFORMS=cpu python bench.py --configs 16 || exit $?
 
 echo "== tenant lane (PILOSA_TPU_TENANTS=1, fault seeds 1 / 7) =="
 # The tenant attribution plane bootstraps on every API in these suites
@@ -189,22 +170,6 @@ for seed in 1 7; do
         -p no:xdist -p no:randomly || exit $?
 done
 
-echo "== noisy-neighbor bench gate (bench.py --configs 18) =="
-# Hard-asserts the ISSUE 14 acceptance bar in-process: with an abusive
-# tenant flooding a 3-node cluster under chaos, well-behaved tenants'
-# p99 stays within 1.5x their no-abuser baseline, results bit-identical,
-# the abuser alone trips the tenant SLO burn + a tenant_burn flight
-# bundle, and zero tenant-plane scopes are entered when disabled.
-JAX_PLATFORMS=cpu python bench.py --configs 18 || exit $?
-
-echo "== streaming ingest bench gate (bench.py --configs 17) =="
-# Hard-asserts the ISSUE 13 acceptance bar in-process: pipelined chunked
-# ingest >= 2x the classic c1 path on the same hardware, bit-identical
-# final state vs the classic-Ingester-over-broker oracle, and read
-# p50/p99 under concurrent full-rate ingest within 1.5x of the
-# no-ingest baseline (batch admission yields: writes shed, not reads).
-JAX_PLATFORMS=cpu python bench.py --configs 17 || exit $?
-
 echo "== dax crash lane (PILOSA_TPU_CRASH_SEED=1 / 7) =="
 # The elastic serverless plane must replay to bit-identical state for
 # ANY seeded kill point: the seed draws a site/hit-count from the dax
@@ -216,16 +181,6 @@ for seed in 1 7; do
         python -m pytest tests/test_dax.py tests/test_dax_elastic.py \
         -q -p no:cacheprovider -p no:xdist -p no:randomly || exit $?
 done
-
-echo "== elastic serverless bench gate (bench.py --configs 19) =="
-# Hard-asserts the ISSUE 16 acceptance bar in-process: a DaxCluster
-# under mixed load with a kill, a silence, and scale-ups mid-flight
-# loses zero acked writes (fresh-computer replay checksum bit-identical
-# to the single-node oracle), rebuilds a restarted computer via a FULL
-# resync, and serves from a freshly-directed node at p99 <= 2x the warm
-# fleet within 5s of its directive (warm handoff: replay + prewarm
-# before ack).
-JAX_PLATFORMS=cpu python bench.py --configs 19 || exit $?
 
 echo "== pallas-interpret lane (PILOSA_TPU_PALLAS=1) =="
 # Every Pallas kernel body executes on CPU via interpret=True across the
@@ -249,15 +204,6 @@ PILOSA_TPU_PALLAS=0 JAX_PLATFORMS=cpu \
     tests/test_pallas_parity.py -q -p no:cacheprovider \
     -p no:xdist -p no:randomly || exit $?
 
-echo "== pallas parity/speedup bench gate (bench.py --configs 20) =="
-# Hard-asserts the ISSUE 17 acceptance bar in-process: kill switch ->
-# zero dispatches and zero counter ticks; forced -> every kernel family
-# (pair counts, BSI sum/compare, TopN, ingest scatter, tape terminal)
-# dispatches Pallas and returns results bit-identical to the classic
-# oracle; on TPU backends the wide-shape phase additionally hard-asserts
-# >= 1.3x p50 speedup (CPU runs time it unenforced under interpret).
-JAX_PLATFORMS=cpu python bench.py --configs 20 || exit $?
-
 echo "== compressed-residency lane (PILOSA_TPU_COMPRESS=1 + PALLAS=1) =="
 # Every stacked read path (point reads, TopN/row_counts streaming,
 # GroupBy, BSI compare, the paging/eviction/advance protocols) consumes
@@ -279,15 +225,6 @@ PILOSA_TPU_COMPRESS=0 JAX_PLATFORMS=cpu \
     python -m pytest tests/test_compress.py tests/test_paging.py \
     -q -p no:cacheprovider -p no:xdist -p no:randomly || exit $?
 
-echo "== compressed residency bench gate (bench.py --configs 21) =="
-# Hard-asserts the ISSUE 18 acceptance bar in-process: kill switch ->
-# dense blocks, zero compress-metric/kernel movement; forced -> decode,
-# plain+filtered row_counts and BSI compare bit-identical to the dense
-# oracle AND >= 10x resident rows under the same DeviceBudget byte cap;
-# on TPU backends the tile-skipping scan additionally hard-asserts p50
-# no worse than the dense scan on sparse rows.
-JAX_PLATFORMS=cpu python bench.py --configs 21 || exit $?
-
 echo "== degrade lane (PILOSA_TPU_DEGRADE=1) =="
 # The graceful-degradation controller bootstraps on every API in these
 # suites (default edges, so a healthy test workload never escalates):
@@ -300,57 +237,38 @@ PILOSA_TPU_DEGRADE=1 JAX_PLATFORMS=cpu \
     -p no:xdist -p no:randomly || exit $?
 
 echo "== soak smoke lane (PILOSA_TPU_FAULT_SEED=1 / 7) =="
-# The open-loop driver's deterministic twin + bounded-table churn audit
-# must hold for ANY fault seed (seeds steer only prob-gated chaos
-# rules); two fixed seeds keep the replayed schedules reproducible
-# while exercising two distinct interleavings.
+# The bounded-table churn audit and the degradation ladder must hold
+# for ANY fault seed (seeds steer only prob-gated chaos rules); two
+# fixed seeds keep the runs reproducible.
 for seed in 1 7; do
     PILOSA_TPU_FAULT_SEED=$seed JAX_PLATFORMS=cpu \
-        python -m pytest tests/test_loadgen.py tests/test_bounded.py \
+        python -m pytest tests/test_bounded.py \
         tests/test_degrade.py -q -p no:cacheprovider \
         -p no:xdist -p no:randomly || exit $?
 done
 
-echo "== standing-load soak bench gate (bench.py --configs 22) =="
-# Hard-asserts the ISSUE 19 acceptance bar in-process: a CI-scaled
-# open-loop soak against a 3-node cluster with chaos + membership churn
-# keeps SLO burn bounded and loses zero acked writes (bit-identical to
-# the oracle after heal); a 2.4x overload ramp then engages the ladder
-# in order (batch shed before interactive), serves stale-tagged
-# brownout reads, keeps good-put above half the pre-overload rate, and
-# recovers to NORMAL — with every bounded table at its cap and zero
-# metric movement while the plane was disabled.
-JAX_PLATFORMS=cpu python bench.py --configs 22 || exit $?
-
-echo "== ssb smoke lane (tiny-scale flights vs numpy oracle) =="
-# One query per SSB flight (Q1.1/Q2.1/Q3.1/Q4.1) at tiny scale must be
-# bit-identical to the independent numpy oracle on BOTH the semi-join
-# plane and the PILOSA_TPU_SEMIJOIN=0 hash fallback, plus the JOIN
-# grammar battery and the semi-join plane's own test file.
+echo "== ssb lane (tiny-scale flights vs numpy oracle) =="
+# All 13 SSB queries at tiny scale must be bit-identical to the
+# independent numpy oracle on one node (semi-join plane AND the
+# PILOSA_TPU_SEMIJOIN=0 hash fallback) and on a 3-node cluster that
+# drops one request per query, plus the JOIN grammar battery and the
+# semi-join plane's own test file.
 JAX_PLATFORMS=cpu python -m pytest tests/test_ssb.py \
     tests/test_sql_parser.py tests/test_sql_joins.py -q \
     -p no:cacheprovider -p no:xdist -p no:randomly || exit $?
 
-echo "== star schema bench gate (bench.py --configs 23) =="
-# Hard-asserts the ISSUE 20 acceptance bar in-process: all 13 SSB
-# queries bit-identical to the oracle single-node AND on a 3-node
-# cluster under a seeded FaultPlan; p50 semi-join >=2x faster than the
-# hash fallback on every Q2/Q3 flight; no-JOIN queries leave every
-# sql_join_* counter untouched.
-JAX_PLATFORMS=cpu python bench.py --configs 23 || exit $?
-
-echo "== bench regression report (scripts/bench_compare.py --latest) =="
-# Non-fatal report step: diffs the two most recent BENCH_r*.json driver
-# wrappers when present. CI gates fatally against a pinned baseline.
-python scripts/bench_compare.py --latest \
-    || echo "bench_compare: regressions reported (non-fatal here)"
-
 echo "== tier-1 test suite =="
-rm -f /tmp/_t1.log
-timeout -k 10 870 env JAX_PLATFORMS=cpu python -m pytest tests/ -q \
-    -m 'not slow' --continue-on-collection-errors -p no:cacheprovider \
-    -p no:xdist -p no:randomly 2>&1 | tee /tmp/_t1.log
+rm -rf /tmp/_t1.log /tmp/_t1.xml
+timeout -k 10 1470 env JAX_PLATFORMS=cpu \
+    python -m pytest tests/ -q -m 'not slow' \
+    --continue-on-collection-errors -p no:cacheprovider -p xdist -n 6 \
+    --dist loadfile --junitxml=/tmp/_t1.xml -p no:randomly 2>&1 \
+    | tee /tmp/_t1.log
 rc=${PIPESTATUS[0]}
-echo DOTS_PASSED=$(grep -aE '^[.FEsx]+( *\[ *[0-9]+%\])?$' /tmp/_t1.log \
-    | tr -cd . | wc -c)
+said=$(sed -n 's/.*<testsuite [^>]*errors="\([0-9]*\)" failures="\([0-9]*\)" skipped="\([0-9]*\)" tests="\([0-9]*\)".*/\4 \1 \2 \3/p' \
+    /tmp/_t1.xml 2>/dev/null | head -n 1 \
+    | awk '{n=$1-$2-$3-$4; print (n<0 ? 0 : n)}')
+echo DOTS_PASSED=${said:-$(grep -aE '^[.FEsx]+( *\[ *[0-9]+%\])?$' /tmp/_t1.log \
+    | tr -cd . | wc -c)}
+echo WORKERS_DOWN=$(grep -acE '\[gw[0-9]+\] node down' /tmp/_t1.log 2>/dev/null)
 exit $rc

@@ -5,15 +5,13 @@ a hostile or merely huge ID stream cannot grow resident state without
 bound. This suite churns 10^5 distinct IDs (or enough distinct keys to
 overflow the smaller module-level caches several times over) through
 each structure and asserts the cap held, the overflow path engaged,
-and the structure still answers sanely afterwards — the unit-level
-twin of bench.py config 22's post-soak cap sweep.
+and the structure still answers sanely afterwards.
 """
 
 import pytest
 
 from pilosa_tpu.cache.result_cache import ResultCache
 from pilosa_tpu.errors import QuotaExceededError
-from pilosa_tpu.loadgen.tenants import SyntheticTenants
 from pilosa_tpu.obs.flight import FlightRecorder
 from pilosa_tpu.obs.metrics import MetricsRegistry
 from pilosa_tpu.obs.slo import SLOTracker
@@ -25,11 +23,15 @@ from pilosa_tpu.sched.scheduler import _Pending
 CHURN = 100_000
 
 
+def tenant_ids(n=CHURN):
+    """``n`` distinct tenant IDs, generated and never held as a list."""
+    return (f"t{i:07d}" for i in range(n))
+
+
 class TestTenantRegistryChurn:
     def test_stats_table_caps_at_max_tracked(self):
         reg = TenantRegistry(max_tracked=64, registry=MetricsRegistry())
-        pop = SyntheticTenants(CHURN)
-        for tid in pop.all_ids():
+        for tid in tenant_ids():
             reg.note(tid, queries=1)
         # tracked cells + the single overflow cell, never one more
         assert len(reg._stats) <= reg.max_tracked + 1
@@ -49,8 +51,7 @@ class TestTenantRegistryChurn:
         reg = TenantRegistry(max_tracked=16, default_qps=1e9,
                              default_ingest_rows_s=1e9,
                              clock=clock.now, registry=MetricsRegistry())
-        pop = SyntheticTenants(CHURN)
-        for tid in pop.all_ids():
+        for tid in tenant_ids():
             reg.charge_query(tid)
             reg.charge_ingest(tid, rows=1)
         # hostile-ID bound: the tables clear past 4x max_tracked, so
@@ -72,8 +73,7 @@ class TestSLOTenantChurn:
     def test_tenant_dimension_caps_with_overflow_cell(self):
         clock = ManualClock()
         tracker = SLOTracker(clock=clock, registry=MetricsRegistry())
-        pop = SyntheticTenants(CHURN)
-        for tid in pop.all_ids():
+        for tid in tenant_ids():
             tracker.record("query", 1.0, tenant=tid)
         # the set holds at most cap distinct IDs plus "__other__"
         assert len(tracker._tenant_ids) <= tracker.tenant_cap + 1
@@ -87,9 +87,8 @@ class TestSchedulerVtimeChurn:
         from pilosa_tpu.pql.parser import parse
 
         sched = QueryScheduler(executor=object(), fair_share=True)
-        pop = SyntheticTenants(CHURN)
         q = parse("Count(Row(f=1))")
-        for i, tid in enumerate(pop.all_ids()):
+        for i, tid in enumerate(tenant_ids()):
             p = _Pending("i", q, None, "interactive", None, 0.0, i)
             p.tenant = tid
             sched._assign_vtime_locked(p)

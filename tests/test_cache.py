@@ -5,10 +5,15 @@ every test here asserts on *dispatch counts* (via instance-level spies
 on Executor._execute_query) plus result correctness: a stale hit would
 show up as a wrong count, a missed invalidation as a skipped dispatch.
 
-This module is also run twice under PYTHONHASHSEED=0/1 by the tier-1
-script (scripts/tier1.sh) to catch hash-order-dependent key bugs.
+``TestHashSeed`` builds the same keys in two interpreters under
+PYTHONHASHSEED=0 and =1; scripts/tier1.sh also runs the whole module
+under both, to catch hash-order-dependent key bugs.
 """
 
+import json
+import os
+import subprocess
+import sys
 import threading
 from concurrent.futures import ThreadPoolExecutor
 
@@ -106,6 +111,60 @@ class TestQueryKey:
         assert k != query_cache_key(idx, q, [0, 1], namespace="remote")
         api.query("i", "Set(2, f=1)")
         assert k != query_cache_key(idx, q, [0, 1])
+
+
+# Run by TestHashSeed in a fresh interpreter: field names, views, shards
+# and keyed rows arrive in an order no sort would leave them in.
+_KEYS_SCRIPT = """
+import json
+from pilosa_tpu.api import API
+from pilosa_tpu.cache import query_cache_key, version_fingerprint
+from pilosa_tpu.pql.parser import parse
+from pilosa_tpu.shardwidth import SHARD_WIDTH
+
+api = API()
+api.create_index("i")
+for name in ("zeta", "alpha", "mu"):
+    api.create_field("i", name)
+api.create_field("i", "seg", {"type": "set", "keys": True})
+api.create_field("i", "when", {"type": "time", "timeQuantum": "YMD"})
+api.create_field("i", "amount", {"type": "int"})
+cols = [2 * SHARD_WIDTH + 5, 3, SHARD_WIDTH + 9]
+for name in ("mu", "zeta", "alpha"):
+    api.import_bits("i", name, rows=[2, 1, 7], cols=cols)
+api.import_bits("i", "seg", row_keys=["pear", "apple", "fig"], cols=cols)
+api.query("i", "Set(3, when=4, 2020-03-01T00:00)"
+               "Set(%d, when=4, 2019-11-30T00:00)" % (SHARD_WIDTH + 9))
+api.import_values("i", "amount", cols=cols, values=[-4, 11, 0])
+idx = api.holder.index("i")
+q = parse("Count(Intersect(Row(zeta=1), Row(alpha=2), Row(seg='fig')))")
+print(json.dumps({
+    "version_fingerprint": repr(version_fingerprint(idx, [2, 0, 1])),
+    "query_cache_key": repr(query_cache_key(idx, q, [2, 0, 1])),
+}))
+"""
+
+
+class TestHashSeed:
+    @pytest.fixture(scope="class")
+    def keys_by_seed(self):
+        """{"0": {...}, "1": {...}}: both keys' ``repr`` as a fresh
+        interpreter under each PYTHONHASHSEED builds them."""
+        out = {}
+        for seed in ("0", "1"):
+            done = subprocess.run(
+                [sys.executable, "-c", _KEYS_SCRIPT],
+                env=dict(os.environ, PYTHONHASHSEED=seed),
+                capture_output=True, text=True, timeout=120)
+            assert done.returncode == 0, done.stderr[-2000:]
+            out[seed] = json.loads(done.stdout.splitlines()[-1])
+        return out
+
+    @pytest.mark.parametrize("key", ["version_fingerprint",
+                                     "query_cache_key"])
+    def test_key_does_not_depend_on_hash_seed(self, keys_by_seed, key):
+        assert "zeta" in keys_by_seed["0"][key]
+        assert keys_by_seed["0"][key] == keys_by_seed["1"][key]
 
 
 # -- ResultCache unit ------------------------------------------------------

@@ -6,8 +6,7 @@ only at SATURATED), honest Retry-After propagation, and deadline
 tightening. Integration-level: scheduler admission sheds, brownout
 stale-serving through the result cache with the ``stale=true`` response
 tag, the bulk-import ingress shed, and the PILOSA_TPU_DEGRADE=0
-zero-cost-off contract. bench.py config 22 drives the same ladder
-against a live 3-node cluster under open-loop overload.
+zero-cost-off contract.
 """
 
 import pytest

@@ -1,18 +1,15 @@
 """Kernel performance attribution plane (ISSUE 11): analytic cost
 model over compiled op tapes, MFU/roofline profiles keyed on
 (family, shape_bucket, mesh_epoch), per-stage ingest throughput, the
-``/internal/stats/kernels`` surface, and the bench regression gate.
+and the ``/internal/stats/kernels`` surface.
 
 The invariants are the acceptance criteria: bit-identical query results
 with the plane on vs off, exactly zero cost-model work while disabled,
-a profile with MFU/GB/s for every compiled family on a warmed cluster,
-and a comparator that passes identical runs while flagging a synthetic
-20% regression.
+and a profile with MFU/GB/s for every compiled family on a warmed
+cluster.
 """
 
-import importlib.util
 import json
-import pathlib
 import urllib.request
 
 import numpy as np
@@ -460,81 +457,3 @@ class TestServing:
     def test_timeline_probe_disabled(self, unprofiled):
         assert devprof.timeline_probe() == {"enabled": False}
 
-
-# ---------------------------------------------------------------------------
-# bench_compare: the regression gate
-# ---------------------------------------------------------------------------
-
-
-def _bench_compare():
-    path = pathlib.Path(__file__).resolve().parents[1] / "scripts" \
-        / "bench_compare.py"
-    spec = importlib.util.spec_from_file_location("bench_compare", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-class TestBenchCompare:
-    @pytest.fixture(scope="class")
-    def bc(self):
-        return _bench_compare()
-
-    def _base(self):
-        return {
-            "q_p50 (cpu)": {"metric": "q_p50 (cpu)", "value": 10.0,
-                            "unit": "ms"},
-            "ingest (cpu)": {"metric": "ingest (cpu)", "value": 1e6,
-                             "unit": "rows/s"},
-        }
-
-    def test_identical_runs_pass(self, bc):
-        rows = bc.compare(self._base(), self._base())
-        assert rows and not any(r["regressed"] for r in rows)
-
-    def test_twenty_pct_regression_flagged_both_directions(self, bc):
-        worse = {k: dict(v) for k, v in self._base().items()}
-        worse["q_p50 (cpu)"]["value"] = 12.0    # latency up 20%
-        worse["ingest (cpu)"]["value"] = 8e5    # throughput down 20%
-        rows = bc.compare(self._base(), worse)
-        assert {r["metric"] for r in rows if r["regressed"]} \
-            == {"q_p50", "ingest"}
-
-    def test_improvements_and_small_drift_pass(self, bc):
-        better = {k: dict(v) for k, v in self._base().items()}
-        better["q_p50 (cpu)"]["value"] = 5.0    # latency halved: good
-        better["ingest (cpu)"]["value"] = 1.1e6  # +10%: good
-        rows = bc.compare(self._base(), better)
-        assert not any(r["regressed"] for r in rows)
-
-    def test_selftest_passes(self, bc):
-        assert bc._selftest(0.15) == 0
-
-    def test_load_profile_json_lines_and_wrapper(self, bc, tmp_path):
-        lines = tmp_path / "profile.json"
-        lines.write_text(
-            '{"metric": "m1", "value": 1.0, "unit": "ms"}\n'
-            'xla warning noise\n'
-            '{"metric": "__kernels__", "profile": {}}\n')
-        recs = bc.load_profile(str(lines))
-        assert recs["m1"]["value"] == 1.0 and "__kernels__" in recs
-        wrapper = tmp_path / "BENCH_r99.json"
-        wrapper.write_text(json.dumps({
-            "n": 99, "cmd": "python bench.py", "rc": 0,
-            "tail": 'Platform noise\n'
-                    '{"metric": "m1", "value": 2.0, "unit": "ms"}\n'
-                    'DOTS_PASSED=3\n'}))
-        recs = bc.load_profile(str(wrapper))
-        assert recs["m1"]["value"] == 2.0
-
-    def test_cli_exit_codes(self, bc, tmp_path):
-        old = tmp_path / "old.json"
-        new = tmp_path / "new.json"
-        old.write_text('{"metric": "m (cpu)", "value": 10.0, '
-                       '"unit": "ms"}\n')
-        new.write_text('{"metric": "m (cpu)", "value": 10.5, '
-                       '"unit": "ms"}\n')
-        assert bc.main([str(old), str(new)]) == 0
-        new.write_text('{"metric": "m (cpu)", "value": 20.0, '
-                       '"unit": "ms"}\n')
-        assert bc.main([str(old), str(new)]) == 1

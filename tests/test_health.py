@@ -502,14 +502,22 @@ class TestAPIHealth:
         from pilosa_tpu.api import API
 
         api = API()
+        if api.health is not None:  # PILOSA_TPU_OBS_TIMELINE=1 bootstrap
+            api.disable_health()
+        api.create_index("i")
+        api.create_field("i", "f")
+        api.import_bits("i", "f", rows=[0], cols=[0])
+        # no plane: a query takes no timeline sample
+        sampled = M.REGISTRY.value(M.METRIC_TIMELINE_SAMPLES)
+        off = api.query_json("i", "Count(Row(f=0))")
+        assert M.REGISTRY.value(M.METRIC_TIMELINE_SAMPLES) == sampled
         clock = ManualClock()
         hp = api.enable_health(clock=clock, interval_ms=100.0)
         try:
-            api.create_index("i")
-            api.create_field("i", "f")
             api.import_bits("i", "f", rows=[0], cols=[0])
             clock.advance(1.0)
-            api.query("i", "Count(Row(f=0))")
+            # the plane changes no answer
+            assert api.query_json("i", "Count(Row(f=0))") == off
             rows = {r["name"]: r for r in hp.slo.burn_rates()}
             assert rows["query-latency"]["events_fast"] == 1
             assert rows["ingest-latency"]["events_fast"] == 1

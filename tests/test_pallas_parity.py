@@ -351,7 +351,9 @@ def test_set_many_device_vs_classic(rng, forced, monkeypatch):
 # ---------------------------------------------------------------------------
 
 
-def test_tape_count_terminal_parity(rng, forced):
+def tape_count_on_one_device(rng):
+    """An AND tape's count program compiled for a one-device engine
+    mesh: (has a Pallas terminal, its count, numpy's count)."""
     import jax
     import jax.numpy as jnp
 
@@ -362,15 +364,18 @@ def test_tape_count_terminal_parity(rng, forced):
         total_words = 1024
         leaves = [jnp.asarray(rand_planes(rng, 1, total_words)[0])
                   for _ in range(2)]
-        tape = (("and", 0, 1),)
-        fn = mesh.compile_tape_count(tape, False, total_words)
-        assert getattr(fn, "pallas_terminal", False)
-        got = int(fn(*leaves))
-        want = int(np.sum([bin(int(w)).count("1") for w in
-                           np.asarray(leaves[0] & leaves[1])]))
-        assert got == want
+        fn = mesh.compile_tape_count((("and", 0, 1),), False, total_words)
+        want = int(np.bitwise_count(
+            np.asarray(leaves[0] & leaves[1])).sum())
+        return (getattr(fn, "pallas_terminal", False), int(fn(*leaves)),
+                want)
     finally:
         mesh.set_engine_mesh(None)
+
+
+def test_tape_count_terminal_parity(rng, forced):
+    pallas, got, want = tape_count_on_one_device(rng)
+    assert pallas and got == want
 
 
 def test_plane_count_pallas_2d(rng, forced):
@@ -440,6 +445,9 @@ def test_kill_switch_zero_dispatch_zero_overhead(rng, killed):
     for got, want in zip(G.pair_sums(*ops), pair_sums_numpy(*ops)):
         np.testing.assert_array_equal(np.asarray(got), want)
     assert dispatch_count("pair_sums") == sums_d
+    # a count program compiled under the switch has no Pallas terminal
+    pallas, got, want = tape_count_on_one_device(rng)
+    assert not pallas and got == want
     assert M.REGISTRY.value(M.METRIC_OPS_PALLAS_DISPATCH,
                             kernel="pair_counts") == snap_d
     # the switch must not even tick the fallback counter
