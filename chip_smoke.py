@@ -59,13 +59,17 @@ DAY_COLS = 65_536
 DAY_OFFSET = 5_000
 READ_RUNS = 3
 
-#: Pallas kernels that must show >= 1 dispatch on one chip. On a mesh the
-#: code routes kernels with sharded operands (and compression) to the XLA
-#: path with why="mesh"; what still dispatches there is listed below.
+#: Pallas kernels that must show >= 1 dispatch on one chip. On a mesh a
+#: compiled pallas_call cannot take sharded operands: the pair-count
+#: family (the GroupBy and its Sum) runs it per chip under shard_map +
+#: psum and still dispatches; the other families (and compression) take
+#: the XLA path with why="mesh". What dispatches there is listed below.
 EXPECTED_KERNELS = ("tape_count", "bsi_compare", "bsi_sum", "topn",
                     "pair_counts", "pair_sums", "ingest_scatter",
                     "ctile_count")
-EXPECTED_KERNELS_MESH = ("ingest_scatter",)
+EXPECTED_KERNELS_MESH = ("pair_counts", "pair_sums", "ingest_scatter")
+#: ... and which of those must have run as the per-chip mesh program
+EXPECTED_ON_MESH = ("pair_counts", "pair_sums")
 
 
 def fail(msg):
@@ -225,9 +229,15 @@ _METRIC = re.compile(r"^(\w+?)(?:\{(.*)\})? (\S+)$")
 
 
 def kernel_table(text):
-    """{kernel: {"dispatch": n, "fallback": {why: n}}} and the mesh
-    placement fallback count, from the Prometheus exposition."""
+    """{kernel: {"dispatch": n, "on_mesh": n, "fallback": {why: n}}}
+    (``on_mesh``: the dispatches that ran per chip under shard_map) and
+    the mesh placement fallback count, from the Prometheus exposition."""
     table, mesh_fallback = {}, 0.0
+
+    def row_of(kernel):
+        return table.setdefault(
+            kernel, {"dispatch": 0, "on_mesh": 0, "fallback": {}})
+
     for line in text.splitlines():
         m = None if line.startswith("#") else _METRIC.match(line)
         if m is None:
@@ -235,21 +245,20 @@ def kernel_table(text):
         name, labels, value = m.groups()
         lab = dict(re.findall(r'(\w+)="([^"]*)"', labels or ""))
         if name.endswith("ops_pallas_dispatch_total"):
-            row = table.setdefault(lab["kernel"],
-                                   {"dispatch": 0, "fallback": {}})
-            row["dispatch"] += int(float(value))
+            row_of(lab["kernel"])["dispatch"] += int(float(value))
+        elif name.endswith("ops_pallas_mesh_dispatch_total"):
+            row_of(lab["kernel"])["on_mesh"] += int(float(value))
         elif name.endswith("ops_pallas_fallback_total"):
-            row = table.setdefault(lab["kernel"],
-                                   {"dispatch": 0, "fallback": {}})
-            row["fallback"][lab["why"]] = int(float(value))
+            row_of(lab["kernel"])["fallback"][lab["why"]] = int(float(value))
         elif name.endswith("mesh_sharding_fallback_total"):
             mesh_fallback += float(value)
     return table, int(mesh_fallback)
 
 
-def check_kernels(table, mesh_fallback, expected, log_path):
+def check_kernels(table, mesh_fallback, expected, log_path, on_mesh=()):
     """No kernel may have struck out, no stack may have lost its mesh
-    placement, and every expected kernel must have dispatched."""
+    placement, every expected kernel must have dispatched, and those of
+    ``on_mesh`` as the per-chip mesh program."""
     for kernel, row in table.items():
         for why in ("error", "failures"):
             if row["fallback"].get(why):
@@ -260,6 +269,9 @@ def check_kernels(table, mesh_fallback, expected, log_path):
     missing = [k for k in expected if not table.get(k, {}).get("dispatch")]
     if missing:
         fail(f"kernel dispatch check: zero dispatches of {missing}")
+    missing = [k for k in on_mesh if not table.get(k, {}).get("on_mesh")]
+    if missing:
+        fail(f"kernel dispatch check: zero mesh dispatches of {missing}")
 
 
 # -- server child ----------------------------------------------------------------
@@ -393,15 +405,15 @@ def drive(args, client, proc, log_path, t_spawn):
     with open(log_path[:-len(".log")] + ".metrics", "w") as f:
         f.write(metrics)  # every counter of the run, beside the log
     table, mesh_fallback = kernel_table(metrics)
-    print(f"{'kernel':<16}{'dispatch':>9}  fallback{{why}}")
+    print(f"{'kernel':<16}{'dispatch':>9}{'on mesh':>9}  fallback{{why}}")
     for kernel in sorted(table):
         row = table[kernel]
-        print(f"{kernel:<16}{row['dispatch']:>9}  "
+        print(f"{kernel:<16}{row['dispatch']:>9}{row['on_mesh']:>9}  "
               f"{json.dumps(row['fallback'], sort_keys=True)}")
     print(f"mesh_sharding_fallback_total {mesh_fallback}")
     expected = EXPECTED_KERNELS_MESH if mesh else EXPECTED_KERNELS
     check_kernels(table, mesh_fallback, expected if on_chip else (),
-                  log_path)
+                  log_path, EXPECTED_ON_MESH if on_chip and mesh else ())
     if proc.poll() is not None:
         fail(f"server exited rc={proc.returncode} during the run")
 

@@ -20,9 +20,14 @@ materializing ``rows x 2^20`` lanes in HBM.
 
 Two forms of the one matmul: a fused Pallas kernel that expands in VMEM
 (:func:`_pair_counts_traced`) and an XLA scan that any backend and any
-sharding takes (:func:`_pair_counts_xla`). :func:`pair_counts` and
-:func:`pair_sums` pick between them from what ``pallas_util.why_not``
-sees in their concrete operands: backend, sharding, rows.
+sharding takes (:func:`_pair_counts_xla`). The kernel has two placements:
+one chip's program, and the mesh program that runs it on every chip over
+the words that chip holds and sums the counts with one small ``psum``
+(``parallel/mesh.psum_over_words``; the XLA scan, being a loop over the
+sharded axis, would have both operands gathered whole onto every chip).
+:func:`pair_counts` and :func:`pair_sums` pick from what
+``pallas_util.why_not`` and ``parallel/mesh.engine_placed`` see in their
+concrete operands: backend, sharding, rows. No option chooses.
 """
 
 from __future__ import annotations
@@ -69,26 +74,49 @@ def pair_counts(a, b, block_words: int = BLOCK_WORDS):
     Dispatch: concrete arrays on a TPU backend (or anywhere under
     ``PILOSA_TPU_PALLAS=1``, via the interpreter) take the fused Pallas
     expand+matmul kernel (the expansion stays in VMEM instead of
-    staging int8 lanes through HBM); traced values
-    (inside jit/shard_map, e.g. the mesh path's psum reduction),
-    mesh-sharded operands and other backends take the XLA scan: a
-    jitted program that wants the kernel chooses its route where its
-    operands are still concrete and calls :func:`_pair_counts_traced`
-    itself (:func:`pair_sums`, ops/bsi.py, ops/topk.py). Outcomes are
-    counted on the ``ops_pallas_*`` metrics (ops/pallas_util.py)."""
+    staging int8 lanes through HBM): on one chip as one program, and,
+    when both operands are sharded as the engine mesh places a stack
+    (:func:`_mesh_route`), as the mesh program that counts on every chip
+    over its own words and ``psum``s the ``int32[R1, R2]``. Traced
+    values (inside jit/shard_map, e.g. ``mesh._groupby_counts``),
+    operands sharded any other way, more than ``_PALLAS_MAX_R1`` rows
+    and other backends take the XLA scan: a jitted program that wants
+    the kernel chooses its route where its operands are still concrete
+    and calls :func:`_pair_counts_traced` itself (:func:`pair_sums`,
+    ops/bsi.py, ops/topk.py). Outcomes are counted on the
+    ``ops_pallas_*`` metrics (ops/pallas_util.py)."""
     why = PU.why_not("pair_counts", a, b, max_rows=_PALLAS_MAX_R1)
-    if why is None:
+    mesh = _mesh_route(why, a, b)
+    if why is None or mesh is not None:
         try:
             with PU.kernel_scope("mm", a.shape[0], b.shape[0], 2,
                                  a.shape[1]):
-                out = _pair_counts_pallas(a, b)
-            PU.dispatched("pair_counts")
+                out = (_pair_counts_pallas(a, b) if mesh is None
+                       else _pair_counts_mesh(a, b, mesh=mesh))
+            PU.dispatched("pair_counts", on_mesh=mesh is not None)
             return out
         except Exception as e:
             PU.failed("pair_counts", e)
     else:
         PU.fallback("pair_counts", why)
     return _pair_counts_xla(a, b, block_words)
+
+
+def _mesh_route(why, *operands):
+    """The engine mesh when a call that ``why_not`` refused takes the
+    mesh route of the Pallas kernel, else None: the refusal is
+    ``"mesh"`` (a compiled ``pallas_call`` cannot be partitioned, and
+    that is all that is wrong), the first operand fits the kernel's row
+    limit (``why_not`` answers ``"mesh"`` before it looks at shapes) and
+    every array operand is placed as the engine places a stack, so a
+    ``shard_map`` over that mesh finds each chip's words where they
+    already are."""
+    if why != "mesh" or operands[0].shape[0] > _PALLAS_MAX_R1:
+        return None
+    # lazy: parallel/mesh.py imports this module
+    from pilosa_tpu.parallel import mesh as PM
+
+    return PM.engine_mesh() if PM.engine_placed(*operands) else None
 
 
 def _expand_bitmajor(x):
@@ -168,6 +196,23 @@ def _pair_counts_pallas(a, b, interpret=None):
 
 
 @platform.guarded_call
+@functools.partial(jax.jit, static_argnames=("mesh", "interpret"))
+def _pair_counts_mesh(a, b, mesh, interpret=None):
+    """The kernel where the words live: each chip of ``mesh`` counts the
+    pairs over its own slice of the word axis (a local width that is no
+    multiple of ``_PALLAS_BW`` is padded there) and the partial
+    ``int32[R1, R2]`` are summed over the mesh — exact, like the counts
+    themselves, up to S * 2^20."""
+    from pilosa_tpu.parallel.mesh import psum_over_words
+
+    if interpret is None:  # static: resolved once per trace
+        interpret = PU.use_interpret()
+    return psum_over_words(
+        functools.partial(_pair_counts_traced, interpret=interpret),
+        mesh, a, b)
+
+
+@platform.guarded_call
 @functools.partial(jax.jit, static_argnames=("block_words",))
 def _pair_counts_xla(a, b, block_words: int = BLOCK_WORDS):
     """The XLA scan formulation (shard_map-compatible; all backends)."""
@@ -240,18 +285,23 @@ def pair_sums(a, b, mags, pos, neg):
 
     Dispatch as :func:`pair_counts`, decided here on the concrete
     operands: one scan over the planes either way, each step a fused
-    Pallas pair count (:func:`_pair_sums_pallas`) or two XLA ones
-    (:func:`_pair_sums_xla`).
+    Pallas pair count (:func:`_pair_sums_pallas`, or per chip with the
+    two outputs ``psum``med, :func:`_pair_sums_mesh`, when all five
+    operands are placed as the engine mesh places a stack) or two XLA
+    ones (:func:`_pair_sums_xla`).
 
     Returns (pos int32[D, R1, R2], neg int32[D, R1, R2]).
     """
     why = PU.why_not("pair_sums", a, b, mags, max_rows=_PALLAS_MAX_R1)
-    if why is None:
+    mesh = _mesh_route(why, a, b, mags, pos, neg)
+    if why is None or mesh is not None:
         try:
             with PU.kernel_scope("mm", 2 * mags.shape[0] * a.shape[0],
                                  b.shape[0], 5, a.shape[1]):
-                out = _pair_sums_pallas(a, b, mags, pos, neg)
-            PU.dispatched("pair_sums")
+                out = (_pair_sums_pallas(a, b, mags, pos, neg)
+                       if mesh is None else
+                       _pair_sums_mesh(a, b, mags, pos, neg, mesh=mesh))
+            PU.dispatched("pair_sums", on_mesh=mesh is not None)
             return out
         except Exception as e:
             PU.failed("pair_sums", e)
@@ -277,17 +327,13 @@ def _pair_sums_xla(a, b, mags, pos, neg):
     return p, n
 
 
-@platform.guarded_call
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _pair_sums_pallas(a, b, mags, pos, neg, interpret=None):
-    """The Pallas route of :func:`pair_sums`: the plane's mask goes on
-    the small side, P = A_i & sign & M_k, so ``b`` reaches the kernel as
-    it is and no ``b & M_k`` is written and read back a step; both signs
-    stack into one first operand, so the kernel expands ``b`` once a
-    plane, not twice (two calls a step where the stack would pass the
-    kernel's row limit)."""
-    if interpret is None:  # static: resolved once per trace
-        interpret = PU.use_interpret()
+def _pair_sums_traced(a, b, mags, pos, neg, interpret: bool):
+    """Traceable body of the Pallas route of :func:`pair_sums`: the
+    plane's mask goes on the small side, P = A_i & sign & M_k, so ``b``
+    reaches the kernel as it is and no ``b & M_k`` is written and read
+    back a step; both signs stack into one first operand, so the kernel
+    expands ``b`` once a plane, not twice (two calls a step where the
+    stack would pass the kernel's row limit)."""
     r1 = a.shape[0]
     firsts = [a & pos[None, :], a & neg[None, :]]
     if 2 * r1 <= _PALLAS_MAX_R1:
@@ -300,3 +346,27 @@ def _pair_sums_pallas(a, b, mags, pos, neg, interpret=None):
 
     _, (p, n) = lax.scan(step, None, mags)
     return p, n
+
+
+@platform.guarded_call
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _pair_sums_pallas(a, b, mags, pos, neg, interpret=None):
+    """The Pallas route of :func:`pair_sums` on one chip."""
+    if interpret is None:  # static: resolved once per trace
+        interpret = PU.use_interpret()
+    return _pair_sums_traced(a, b, mags, pos, neg, interpret)
+
+
+@platform.guarded_call
+@functools.partial(jax.jit, static_argnames=("mesh", "interpret"))
+def _pair_sums_mesh(a, b, mags, pos, neg, mesh, interpret=None):
+    """The Pallas route of :func:`pair_sums` where the words live: the
+    same scan on every chip of ``mesh`` over its own words of all five
+    operands, the two ``int32[D, R1, R2]`` summed over the mesh."""
+    from pilosa_tpu.parallel.mesh import psum_over_words
+
+    if interpret is None:  # static: resolved once per trace
+        interpret = PU.use_interpret()
+    return psum_over_words(
+        functools.partial(_pair_sums_traced, interpret=interpret),
+        mesh, a, b, mags, pos, neg)

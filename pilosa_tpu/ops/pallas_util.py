@@ -9,6 +9,8 @@ registry so silent degradation to the classic XLA path is visible on the
 timeline:
 
     ops_pallas_dispatch_total{kernel}        successful Pallas dispatches
+    ops_pallas_mesh_dispatch_total{kernel}   those of them that ran per
+                                             chip under shard_map + psum
     ops_pallas_fallback_total{kernel,why}    classic-path fallbacks
 
 Mode selection (``PILOSA_TPU_PALLAS``):
@@ -91,8 +93,12 @@ def why_not(kernel: str, *arrays, max_rows: Optional[int] = None
     :data:`INTERPRET_MAX_WORDS`. Mesh rule: a compiled ``pallas_call``
     whose operand is sharded over several devices is refused at lowering
     ("Mosaic kernels cannot be automatically partitioned. Please wrap
-    the call in a shard_map."), so those take the partitionable XLA path;
-    the interpreter lowers to ordinary XLA ops and is exempt."""
+    the call in a shard_map."), so the answer is ``"mesh"`` and the
+    caller takes the partitionable XLA path — unless its family has a
+    ``shard_map`` program of its own for operands placed as the engine
+    places them (the pair-count family: ``ops/groupby._mesh_route``);
+    the answer here is the same either way. The interpreter lowers to
+    ordinary XLA ops and is exempt."""
     if disabled():
         return "disabled"
     with _LOCK:
@@ -150,8 +156,13 @@ def mode_token() -> str:
     return "interpret" if use_interpret() else "tpu"
 
 
-def dispatched(kernel: str) -> None:
+def dispatched(kernel: str, on_mesh: bool = False) -> None:
+    """One successful Pallas dispatch; ``on_mesh`` when it was the
+    family's per-chip program under ``shard_map`` + ``psum``, so a scrape
+    can tell the two placements apart."""
     M.REGISTRY.count(M.METRIC_OPS_PALLAS_DISPATCH, kernel=kernel)
+    if on_mesh:
+        M.REGISTRY.count(M.METRIC_OPS_PALLAS_MESH_DISPATCH, kernel=kernel)
 
 
 def fallback(kernel: str, why: str) -> None:

@@ -44,10 +44,24 @@ COL_AXIS = "cols"
 # EVERY mesh device: contiguous word-blocks land on devices, which is
 # simultaneously shard-parallelism (different shards on different devices)
 # and column-parallelism (one shard's 32768 words split across devices) —
-# the DB analogs of dp and tp (SURVEY.md §5.7). The jitted kernels in
-# ops/ are unchanged: XLA's SPMD partitioner turns their reductions into
-# psum/all-reduce collectives over ICI from the input shardings (the
-# scaling-book recipe: annotate shardings, let XLA insert collectives).
+# the DB analogs of dp and tp (SURVEY.md §5.7). Who turns a kernel's
+# reduction over the word axis into a collective depends on the kernel:
+# - elementwise plane algebra and the popcount reduces of ops/ (jnp.sum
+#   over words) are left to XLA's SPMD partitioner, which computes on each
+#   chip's words and inserts the all-reduce from the input shardings (the
+#   scaling-book recipe: annotate shardings, let XLA insert collectives);
+# - a ``lax.scan`` over blocks of the word axis is NOT one of those: the
+#   partitioner cannot split a loop over the sharded axis and all-gathers
+#   the operands whole onto every chip (the XLA pair count,
+#   ops/groupby._pair_counts_xla: 134 MB a call on four chips). Nor is a
+#   ``pallas_call`` (Mosaic refuses to be partitioned). Those say where
+#   the words live themselves, with a ``shard_map`` whose body runs on a
+#   chip's own words and a ``psum`` of the small results: the pair-count
+#   family through :func:`psum_over_words`, :func:`compile_tape_count`
+#   and :class:`ShardPlacement`'s kernels with one of their own;
+# - every other Pallas family (bsi_compare, bsi_sum, topn, the tape
+#   terminal) still takes its partitionable XLA twin on a mesh
+#   (``why="mesh"``, ops/pallas_util.py).
 # ---------------------------------------------------------------------------
 
 _ENGINE_MESH: Optional[Mesh] = None
@@ -85,6 +99,12 @@ def set_engine_mesh(mesh: Optional[Mesh]) -> None:
 _FALLBACK_WARNED: set = set()
 
 
+def _words_spec(ndim: int) -> P:
+    """Leading axes whole, the last (the fused (shard, word) space) split
+    over every device of the mesh."""
+    return P(*([None] * (ndim - 1)), (SHARD_AXIS, COL_AXIS))
+
+
 def engine_sharding(ndim: int,
                     last_dim: int) -> Optional[NamedSharding]:
     """Sharding for a stacked engine tensor whose LAST axis is the fused
@@ -112,8 +132,46 @@ def engine_sharding(ndim: int,
 
         M.REGISTRY.count(M.METRIC_MESH_FALLBACK)
         return None
-    return NamedSharding(
-        mesh, P(*([None] * (ndim - 1)), (SHARD_AXIS, COL_AXIS)))
+    return NamedSharding(mesh, _words_spec(ndim))
+
+
+def engine_placed(*arrays) -> bool:
+    """Whether every one of ``arrays`` is a concrete device array laid
+    out as :func:`engine_sharding` lays a stack out: on the current
+    engine mesh of several devices, leading axes whole, the last axis
+    split evenly over every device. Pure: it reads the operands' own
+    ``sharding`` and ticks nothing. numpy operands, tracers, arrays on
+    one device or replicated, and arrays on another mesh (other devices,
+    or the same in another order) are not."""
+    mesh = engine_mesh()
+    n = mesh.devices.size
+    if n <= 1 or not arrays:
+        return False
+    for x in arrays:
+        if (isinstance(x, jax.core.Tracer) or not isinstance(x, jax.Array)
+                or x.ndim == 0 or x.shape[-1] % n):
+            return False
+        want = NamedSharding(mesh, _words_spec(x.ndim))
+        if not x.sharding.is_equivalent_to(want, x.ndim):
+            return False
+    return True
+
+
+def psum_over_words(body, mesh: Mesh, *operands):
+    """``body`` run on every chip of ``mesh`` over that chip's own words
+    of each operand (leading axes whole, the last split as the engine
+    places it), and the tree of arrays it returns summed over the mesh:
+    the replicated result of a reduction that contracts over the word
+    axis, for one small all-reduce and no operand moved. Traceable; the
+    caller jits it. ``check_vma`` is off because a ``pallas_call``'s
+    ``out_shape`` carries no varying-axes type and JAX 0.9.0 refuses
+    such a call under the check."""
+    @functools.partial(_shard_map, mesh=mesh,
+                       in_specs=tuple(_words_spec(x.ndim) for x in operands),
+                       out_specs=P(), check_vma=False)
+    def f(*local):
+        return lax.psum(body(*local), (SHARD_AXIS, COL_AXIS))
+    return f(*operands)
 
 
 def engine_put(host: np.ndarray) -> jax.Array:
@@ -291,7 +349,7 @@ def compile_tape_count(tape, masked: bool, total_words: int):
                 and total_words % mesh.devices.size == 0)
 
     if use_mesh:
-        spec = P((SHARD_AXIS, COL_AXIS))
+        spec = _words_spec(1)
 
         @jax.jit
         def fn(*args):

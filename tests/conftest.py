@@ -28,6 +28,28 @@ def rng():
     return np.random.default_rng(42)
 
 
+@pytest.fixture
+def pallas_as_compiled(monkeypatch):
+    """``pallas_util.why_not`` answers as it does where kernels are
+    compiled (an operand sharded over several devices: ``"mesh"``; no
+    interpreter width cap) while the programs themselves keep running
+    under the Pallas interpreter, which is all this backend has."""
+    from pilosa_tpu.ops import pallas_util as PU
+
+    monkeypatch.setenv("PILOSA_TPU_PALLAS", "1")
+    real = PU.why_not
+
+    def why_not(*args, **kwargs):
+        with monkeypatch.context() as m:
+            m.setattr(PU, "use_interpret", lambda: False)
+            return real(*args, **kwargs)
+
+    monkeypatch.setattr(PU, "why_not", why_not)
+    PU.reset_failures()
+    yield
+    PU.reset_failures()
+
+
 @pytest.fixture(autouse=True)
 def _budget_leak_audit():
     """Post-test accounting audit (the reference's testhook auditors,

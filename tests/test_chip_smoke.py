@@ -99,6 +99,8 @@ METRICS = """\
 # TYPE pilosa_tpu_ops_pallas_dispatch_total counter
 pilosa_tpu_ops_pallas_dispatch_total{kernel="tape_count"} 12
 pilosa_tpu_ops_pallas_dispatch_total{kernel="topn"} 3
+pilosa_tpu_ops_pallas_dispatch_total{kernel="pair_sums"} 2
+pilosa_tpu_ops_pallas_mesh_dispatch_total{kernel="pair_sums"} 2
 pilosa_tpu_ops_pallas_fallback_total{kernel="bsi_sum",why="mesh"} 3
 pilosa_tpu_ops_pallas_fallback_total{kernel="topn",why="error"} 1
 pilosa_tpu_mesh_sharding_fallback_total 0
@@ -109,8 +111,13 @@ pilosa_tpu_pql_queries_total 40
 def test_kernel_table_and_dispatch_checks():
     table, mesh_fallback = chip_smoke.kernel_table(METRICS)
     assert mesh_fallback == 0
-    assert table["tape_count"] == {"dispatch": 12, "fallback": {}}
-    assert table["bsi_sum"] == {"dispatch": 0, "fallback": {"mesh": 3}}
+    assert table["tape_count"] == {"dispatch": 12, "on_mesh": 0,
+                                   "fallback": {}}
+    assert table["bsi_sum"] == {"dispatch": 0, "on_mesh": 0,
+                                "fallback": {"mesh": 3}}
+    # a mesh dispatch counts in both series, once each
+    assert table["pair_sums"] == {"dispatch": 2, "on_mesh": 2,
+                                  "fallback": {}}
     with pytest.raises(SystemExit, match="topn fell back with why='error'"):
         chip_smoke.check_kernels(table, 0, (), "log")
     del table["topn"]["fallback"]["error"]
@@ -121,6 +128,21 @@ def test_kernel_table_and_dispatch_checks():
             table, 0, ("tape_count", "bsi_sum", "pair_counts"), "log")
     with pytest.raises(SystemExit, match="mesh_sharding_fallback_total"):
         chip_smoke.check_kernels(table, 2, (), "log")
+
+
+def test_mesh_kernels_must_dispatch_on_the_mesh():
+    """On several chips the pair-count family has to run as the per-chip
+    mesh program: a dispatch of the one-chip program (or none) fails."""
+    assert set(chip_smoke.EXPECTED_ON_MESH) <= set(
+        chip_smoke.EXPECTED_KERNELS_MESH) <= set(chip_smoke.EXPECTED_KERNELS)
+    table, _ = chip_smoke.kernel_table(METRICS)
+    del table["topn"]["fallback"]["error"]
+    chip_smoke.check_kernels(table, 0, ("pair_sums",), "log",
+                             on_mesh=("pair_sums",))
+    with pytest.raises(SystemExit,
+                       match=r"zero mesh dispatches of \['topn'\]"):
+        chip_smoke.check_kernels(table, 0, ("topn",), "log",
+                                 on_mesh=("topn", "pair_sums"))
 
 
 # -- compile cache placement -----------------------------------------------------

@@ -151,6 +151,57 @@ def test_groupby_equals_the_numpy_reference(n_fields, mode, monkeypatch):
                    for _, st in inner.values())
 
 
+@pytest.mark.parametrize("query,kernel", [
+    ("GroupBy(Rows(p), Rows(d))", "pair_counts"),
+    ("GroupBy(Rows(p), Rows(d), filter=Row(w=1), aggregate=Sum(field=v))",
+     "pair_sums"),
+    ("GroupBy(Rows(p), Rows(y), Rows(d))", "pair_counts"),
+])
+def test_groupby_on_a_mesh_of_four_equals_the_one_device_answer(
+        query, kernel, pallas_as_compiled):
+    """Under an engine mesh of four devices the stacks are sharded over
+    the word axis and the pair-count family takes its mesh route (the
+    kernel on every device's own words, one ``psum``): same answers as
+    on one device and as numpy, as many dispatches, all of them the mesh
+    program's, and nothing left to the XLA scan."""
+    import jax
+
+    from pilosa_tpu.parallel import mesh as PM
+
+    family = ("pair_counts", "pair_sums")
+
+    def ticks(name, **labels):
+        return np.array([M.REGISTRY.value(name, kernel=k, **labels)
+                         for k in family])
+
+    def counters():
+        return (ticks(M.METRIC_OPS_PALLAS_DISPATCH),
+                ticks(M.METRIC_OPS_PALLAS_MESH_DISPATCH),
+                ticks(M.METRIC_OPS_PALLAS_FALLBACK, why="mesh")
+                + ticks(M.METRIC_OPS_PALLAS_FALLBACK, why="error"))
+
+    h = Holder()
+    cols, slots, v = load(h)
+    try:
+        PM.set_engine_mesh(PM.analytics_mesh(jax.devices()[:1]))
+        d0, m0, _ = counters()
+        one = answer(Executor(h).execute("t", query)[0])
+        d1, m1, f1 = counters()
+        PM.set_engine_mesh(PM.analytics_mesh(jax.devices()[:4]))
+        four = answer(Executor(h).execute("t", query)[0])
+        d4, m4, f4 = counters()
+    finally:
+        PM.set_engine_mesh(None)
+    names = ("p", "y", "d") if "Rows(y)" in query else ("p", "d")
+    with_sum = kernel == "pair_sums"
+    assert four == one == reference(
+        slots, v, names, slots["w"] == 1 if with_sum else None, with_sum)
+    assert (d1 - d0)[family.index(kernel)] > 0
+    assert (m1 == m0).all()         # one device: never the mesh program
+    assert (d4 - d1 == d1 - d0).all() and (m4 - m1 == d1 - d0).all()
+    assert (f4 == f1).all()
+
+
 def _profiled(api, text):
     out = api.query_json("t", text, profile=True)
     spans = []
