@@ -44,6 +44,7 @@ from __future__ import annotations
 
 import contextlib
 import itertools
+import math
 import os
 import threading
 import time
@@ -120,17 +121,27 @@ def sync_part(arr):
     return arr
 
 
-def _engine_put(host: np.ndarray) -> jax.Array:
-    """Place a stacked tensor on the engine device mesh: the fused
+def _sent(host: np.ndarray, kind: str, span):
+    """The resident form of a block just assembled on the host, sent to
+    the devices inside its ``stack.build`` span: compressed tiles where
+    the policy says so, else dense on the engine device mesh — the fused
     (shard, word) last axis splits across all mesh devices, so the jitted
     query kernels execute SPMD with XLA-inserted collective reduces
     (parallel/mesh.py engine mesh; the reference's shard->node scatter +
     HTTP reduce, executor.go:6449, becomes shard->device + psum)."""
     from pilosa_tpu.parallel.mesh import engine_put
 
+    blk = ctiles.maybe_compress(host, kind=kind)
+    if blk is None:
+        blk = engine_put(host)
     UPLOAD_STATS["count"] += 1
-    UPLOAD_STATS["bytes"] += host.nbytes
-    return engine_put(host)
+    UPLOAD_STATS["bytes"] += blk.nbytes
+    M.REGISTRY.count(M.METRIC_STACK_BUILD_BYTES, blk.nbytes)
+    if span.recording:
+        held = device_bytes(blk)
+        span.set_tag("devices", len(held))
+        span.set_tag("bytes_per_device", max(held.values()))
+    return blk
 
 
 def _pow2(n: int) -> int:
@@ -169,8 +180,32 @@ def _budget_bytes() -> int:
     return _env_mb("PILOSA_TPU_HBM_BUDGET_MB", 6144) << 20
 
 
+def device_bytes(*blocks) -> Dict[int, int]:
+    """{device id: bytes} that ``blocks`` (dense device tensors or
+    compressed blocks) hold on each device, read from their own
+    sharding: a tensor split over the engine mesh costs each device its
+    share, one placed on a single device (one chip, a mesh fallback, a
+    compressed block) costs that device all of it, a replicated one
+    costs every device all of it."""
+    out: Dict[int, int] = {}
+    for blk in blocks:
+        if isinstance(blk, ctiles.CompressedBlock):
+            sharding, per = blk.payload.sharding, blk.nbytes
+        else:
+            sharding = blk.sharding
+            per = (math.prod(sharding.shard_shape(blk.shape))
+                   * blk.dtype.itemsize)
+        for d in sharding.device_set:
+            out[d.id] = out.get(d.id, 0) + per
+    return out
+
+
 class DeviceBudget:
-    """Byte-capped LRU of evictable device arrays (paged stack blocks).
+    """Per-device byte-capped LRU of evictable device arrays (paged
+    stack blocks): every device stays under ``cap``, and an entry costs
+    each device the bytes it holds there (:func:`device_bytes`; a plain
+    byte count is bytes on the default device). ``used`` is the fullest
+    device's bytes, which on one device is every byte charged.
 
     Eviction drops the owner's *reference*; in-flight kernels keep the
     buffer alive until they finish (XLA buffers are refcounted), so no
@@ -179,33 +214,56 @@ class DeviceBudget:
 
     def __init__(self, cap_bytes: int):
         self.cap = cap_bytes
-        self.used = 0
+        self._used: Dict[int, int] = {}
         self._lock = threading.Lock()
-        self._lru: "OrderedDict[Tuple, Tuple[int, object]]" = OrderedDict()
+        self._lru: "OrderedDict[Tuple, Tuple[Dict[int, int], object]]" = \
+            OrderedDict()
 
-    def charge(self, key: Tuple, nbytes: int, evict_cb) -> None:
+    @property
+    def used(self) -> int:
+        return max(self._used.values(), default=0)
+
+    def _add(self, cost: Dict[int, int], sign: int) -> None:
+        for d, b in cost.items():
+            left = self._used.get(d, 0) + sign * b
+            if left:
+                self._used[d] = left
+            else:
+                self._used.pop(d, None)
+
+    def _over(self) -> List[int]:
+        return [d for d, b in self._used.items() if b > self.cap]
+
+    def _gauges(self) -> None:
+        M.REGISTRY.gauge(M.METRIC_DEVICE_HBM_RESIDENT_BYTES, self.used)
+        M.REGISTRY.gauge(M.METRIC_DEVICE_BUDGET_RESIDENT_BYTES, self.used)
+
+    def charge(self, key: Tuple, cost, evict_cb) -> None:
+        if not isinstance(cost, dict):
+            cost = {jax.devices()[0].id: int(cost)}
         with self._lock:
             old = self._lru.pop(key, None)
             if old is not None:
-                self.used -= old[0]
-            self._lru[key] = (nbytes, evict_cb)
-            self.used += nbytes
-            while self.used > self.cap and len(self._lru) > 1:
-                k, (b, cb) = self._lru.popitem(last=False)
-                if k == key:  # never evict the entry being inserted
-                    self._lru[k] = (b, cb)
-                    self._lru.move_to_end(k, last=False)
-                    if len(self._lru) == 1:
-                        break
+                self._add(old[0], -1)
+            self._lru[key] = (cost, evict_cb)
+            self._add(cost, +1)
+            over = self._over()
+            # oldest first, never the entry being inserted, and only
+            # entries that free bytes on a device that is over the cap
+            for k in [k for k in self._lru if k != key] if over else ():
+                held, cb = self._lru[k]
+                if not any(held.get(d) for d in over):
                     continue
-                self.used -= b
+                del self._lru[k]
+                self._add(held, -1)
                 PAGING_STATS["evictions"] += 1
                 M.REGISTRY.count(M.METRIC_DEVICE_STACK_EVICTIONS)
                 M.REGISTRY.count(M.METRIC_DEVICE_BUDGET_EVICTIONS)
                 cb()
-            M.REGISTRY.gauge(M.METRIC_DEVICE_HBM_RESIDENT_BYTES, self.used)
-            M.REGISTRY.gauge(M.METRIC_DEVICE_BUDGET_RESIDENT_BYTES,
-                             self.used)
+                over = self._over()
+                if not over:
+                    break
+            self._gauges()
 
     def touch(self, key: Tuple) -> None:
         with self._lock:
@@ -213,7 +271,8 @@ class DeviceBudget:
                 self._lru.move_to_end(key)
 
     def room(self) -> int:
-        """Bytes that can still be charged without evicting anything."""
+        """Bytes that can still be charged to the fullest device without
+        evicting anything."""
         with self._lock:
             return self.cap - self.used
 
@@ -221,37 +280,47 @@ class DeviceBudget:
         with self._lock:
             old = self._lru.pop(key, None)
             if old is not None:
-                self.used -= old[0]
-                M.REGISTRY.gauge(M.METRIC_DEVICE_HBM_RESIDENT_BYTES,
-                                 self.used)
-                M.REGISTRY.gauge(M.METRIC_DEVICE_BUDGET_RESIDENT_BYTES,
-                                 self.used)
+                self._add(old[0], -1)
+                self._gauges()
 
     def audit(self) -> None:
         """Accounting invariants (the testhook auditor analog,
-        reference: testhook/auditor.go): the byte counter must equal the
-        sum of resident entries — a drift means a leak or double-release
-        somewhere in the charge/evict/release protocol."""
+        reference: testhook/auditor.go): each device's byte counter must
+        equal the sum of resident entries there — a drift means a leak or
+        double-release somewhere in the charge/evict/release protocol."""
         with self._lock:
-            total = sum(b for b, _ in self._lru.values())
-            assert total == self.used, (
-                f"DeviceBudget drift: used={self.used} entries={total}")
+            total: Dict[int, int] = {}
+            for held, _ in self._lru.values():
+                for d, b in held.items():
+                    total[d] = total.get(d, 0) + b
+            assert total == self._used, (
+                f"DeviceBudget drift: used={self._used} entries={total}")
 
 
-#: Default HBM budget for resident stacked planes (v5e has 16 GiB; leave
-#: headroom for kernel workspace and XLA constants).
+#: Default HBM budget for resident stacked planes, on every device of the
+#: engine mesh (a v5e chip has 16 GiB; leave headroom for kernel
+#: workspace and XLA constants).
 BUDGET = DeviceBudget(_budget_bytes())
 
-#: Target bytes per row block. A stack pages when its full tensor would
-#: exceed one block. Tests override via env to exercise paging cheaply.
+#: Target bytes per row block on the device that holds most of it. A
+#: stack pages when its full tensor would exceed one block. Tests
+#: override via env to exercise paging cheaply.
 _BLOCK_BYTES = _env_mb("PILOSA_TPU_BLOCK_BYTES_MB", 256) << 20
+
+
+def _row_bytes(total_words: int) -> int:
+    """Bytes of one ``total_words``-word plane on its widest device, as
+    the engine mesh places a stack of that width."""
+    from pilosa_tpu.parallel.mesh import words_per_device
+
+    return words_per_device(total_words) * 4
 
 
 def planes_per_block(total_words: int) -> int:
     """How many planes of ``total_words`` words one row block's bytes
     hold (at least one): what a layer above sizes a device working set
     by, so that it blocks where a stack of that width would page."""
-    return max(1, _BLOCK_BYTES // max(total_words * 4, 1))
+    return max(1, _BLOCK_BYTES // max(_row_bytes(total_words), 1))
 
 
 def _decode_whole(blk: ctiles.CompressedBlock, kind: str):
@@ -298,7 +367,7 @@ class StackedSet:
                 rows.update(frag.row_index)
         self.row_ids: List[int] = sorted(rows)
         self.row_index: Dict[int, int] = {r: i for i, r in enumerate(self.row_ids)}
-        row_bytes = self.total_words * 4
+        row_bytes = _row_bytes(self.total_words)
         per_block = max(_MIN_SLOTS, planes_per_block(self.total_words))
         self.block_rows = min(_pow2(len(self.row_ids)),
                               _pow2(per_block) // 2 or _MIN_SLOTS)
@@ -330,7 +399,7 @@ class StackedSet:
             # lazily rebuilds with the usual version check.
             blk = self._build_block_host(0)
             self._blocks[0] = blk
-            BUDGET.charge((self.serial, 0), blk.nbytes,
+            BUDGET.charge((self.serial, 0), device_bytes(blk),
                           lambda s=self: s._drop_block(0))
 
     # -- block machinery ----------------------------------------------------
@@ -354,7 +423,7 @@ class StackedSet:
         # warm resident query
         with get_tracer().start_span(
                 "stack.build", block=bi,
-                rows=hi_slot - lo_slot, words=self.total_words):
+                rows=hi_slot - lo_slot, words=self.total_words) as span:
             host = np.zeros((self.block_rows, self.total_words),
                             dtype=np.uint32)
             for si, frag in enumerate(self._fragments):
@@ -367,12 +436,7 @@ class StackedSet:
                         host[slot - lo_slot, lo:lo + self.words] = \
                             frag.planes[fslot]
             PAGING_STATS["block_builds"] += 1
-            cb = ctiles.maybe_compress(host, kind="set")
-            if cb is not None:
-                UPLOAD_STATS["count"] += 1
-                UPLOAD_STATS["bytes"] += cb.nbytes
-                return cb
-            return _engine_put(host)
+            return _sent(host, "set", span)
 
     def _ensure_block(self, bi: int):
         blk = self._blocks[bi]
@@ -395,7 +459,7 @@ class StackedSet:
             blk = self._build_block_host(bi)
             self._blocks[bi] = blk
         if not self.ephemeral:
-            BUDGET.charge((self.serial, bi), blk.nbytes,
+            BUDGET.charge((self.serial, bi), device_bytes(blk),
                           lambda s=self, i=bi: s._drop_block(i))
         return blk
 
@@ -430,7 +494,7 @@ class StackedSet:
                 if stays:
                     self._walked[bi] = (blk, dense)
             if stays:
-                BUDGET.charge((self.serial, bi), blk.nbytes + dense.nbytes,
+                BUDGET.charge((self.serial, bi), device_bytes(blk, dense),
                               lambda s=self, i=bi: s._drop_block(i))
         return dense
 
@@ -566,7 +630,7 @@ class StackedBSI:
 
         with get_tracer().start_span(
                 "stack.build", kind="bsi", planes=bsiops.OFFSET + self.depth,
-                words=self.total_words):
+                words=self.total_words) as span:
             host = np.zeros((bsiops.OFFSET + self.depth, self.total_words),
                             dtype=np.uint32)
             for si, frag in enumerate(self._fragments):
@@ -574,19 +638,15 @@ class StackedBSI:
                     continue
                 lo = si * self.words
                 host[: frag.planes.shape[0], lo:lo + self.words] = frag.planes
-            cb = ctiles.maybe_compress(host, kind="bsi")
-            if cb is not None:
-                UPLOAD_STATS["count"] += 1
-                UPLOAD_STATS["bytes"] += cb.nbytes
-                return cb
-            return _engine_put(host)
+            return _sent(host, "bsi", span)
 
     def _charge(self) -> None:
         blk = self._planes
         if blk is not None and not self.ephemeral:
             kept = self._walked
             BUDGET.charge((self.serial, 0),
-                          blk.nbytes + (0 if kept is None else kept[1].nbytes),
+                          device_bytes(blk, *(() if kept is None
+                                              else (kept[1],))),
                           lambda s=self: s._drop())
 
     def _drop(self) -> None:
@@ -917,7 +977,7 @@ def _advance_set(stack: "StackedSet", fragments, built_vers) -> Optional["Stacke
         # grow the single block in place (device-side zero pad, pow2
         # capacities so XLA sees few shapes); outgrowing one block means
         # the stack must be rebuilt in paged form
-        row_bytes = stack.total_words * 4
+        row_bytes = _row_bytes(stack.total_words)
         need = _pow2(len(new.row_ids))
         if need * row_bytes > _BLOCK_BYTES:
             return None
@@ -937,7 +997,7 @@ def _advance_set(stack: "StackedSet", fragments, built_vers) -> Optional["Stacke
         # call the new entry's neighbors' callbacks, and new's own
         # callback reads _blocks
         new._blocks = [blk]
-        BUDGET.charge((new.serial, 0), blk.nbytes,
+        BUDGET.charge((new.serial, 0), device_bytes(blk),
                       lambda s=new: s._drop_block(0))
         return new
     # paged: block_rows is fixed; appends extend the lazy block list.
@@ -968,7 +1028,7 @@ def _advance_set(stack: "StackedSet", fragments, built_vers) -> Optional["Stacke
     new._blocks = blocks
     for bi, blk in enumerate(blocks):
         if blk is not None:
-            BUDGET.charge((new.serial, bi), blk.nbytes,
+            BUDGET.charge((new.serial, bi), device_bytes(blk),
                           lambda s=new, i=bi: s._drop_block(i))
     return new
 

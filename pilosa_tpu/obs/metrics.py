@@ -211,10 +211,15 @@ METRIC_STARTUP_PHASE_SECONDS = "startup_phase_seconds"
 # bytes of ingest_stage_bytes_total{stage="wal_commit"})
 METRIC_HTTP_REQUEST_BODY_BYTES = "http_request_body_bytes_total"
 # DeviceBudget's own accounting exported directly (same numbers the LRU
-# enforces): bytes currently charged against the HBM cap, and entries it
-# has evicted to stay under it
+# enforces): bytes currently charged against the HBM cap on the fullest
+# device of the engine mesh (on one device: every byte charged), and
+# entries it has evicted to keep every device under it
 METRIC_DEVICE_BUDGET_RESIDENT_BYTES = "device_budget_resident_bytes"
 METRIC_DEVICE_BUDGET_EVICTIONS = "device_budget_evictions_total"
+# bytes of stack blocks built (or rebuilt after an eviction) on the host
+# and sent to the devices, in the form they went: what a budget too
+# small for the working set costs per read
+METRIC_STACK_BUILD_BYTES = "stack_build_bytes_total"
 # compressed-residency plane (ops/ctiles.py): blocks stored in
 # compressed-tile form (labelled kind=set|bsi), blocks kept dense and
 # why (disabled is never ticked — the kill switch costs nothing),

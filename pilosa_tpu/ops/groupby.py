@@ -85,8 +85,7 @@ def pair_counts(a, b, block_words: int = BLOCK_WORDS):
     and calls :func:`_pair_counts_traced` itself (:func:`pair_sums`,
     ops/bsi.py, ops/topk.py). Outcomes are counted on the
     ``ops_pallas_*`` metrics (ops/pallas_util.py)."""
-    why = PU.why_not("pair_counts", a, b, max_rows=_PALLAS_MAX_R1)
-    mesh = _mesh_route(why, a, b)
+    why, mesh = _pair_counts_plan(a, b)
     if why is None or mesh is not None:
         try:
             with PU.kernel_scope("mm", a.shape[0], b.shape[0], 2,
@@ -100,6 +99,24 @@ def pair_counts(a, b, block_words: int = BLOCK_WORDS):
     else:
         PU.fallback("pair_counts", why)
     return _pair_counts_xla(a, b, block_words)
+
+
+def _pair_counts_plan(a, b):
+    """``(why, mesh)``: ``why_not``'s answer for these operands and the
+    engine mesh when its ``"mesh"`` becomes the mesh route."""
+    why = PU.why_not("pair_counts", a, b, max_rows=_PALLAS_MAX_R1)
+    return why, _mesh_route(why, a, b)
+
+
+def pair_counts_route(a, b) -> str:
+    """Which program :func:`pair_counts` runs for these operands:
+    ``"pallas"`` (the kernel on one chip), ``"mesh"`` (the kernel on
+    every chip over its own words) or ``"xla"`` (the scan). Runs and
+    counts nothing: for a span's tag."""
+    why, mesh = _pair_counts_plan(a, b)
+    if why is None:
+        return "pallas"
+    return "xla" if mesh is None else "mesh"
 
 
 def _mesh_route(why, *operands):

@@ -135,6 +135,16 @@ def engine_sharding(ndim: int,
     return NamedSharding(mesh, _words_spec(ndim))
 
 
+def words_per_device(last_dim: int) -> int:
+    """How many of the ``last_dim`` words of a stack's fused (shard,
+    word) axis its widest device holds as :func:`engine_sharding` places
+    it: an even share when the axis splits over the mesh, all of them on
+    one device or where it does not divide. Pure: ticks and logs
+    nothing."""
+    n = engine_mesh().devices.size
+    return last_dim // n if n > 1 and last_dim % n == 0 else last_dim
+
+
 def engine_placed(*arrays) -> bool:
     """Whether every one of ``arrays`` is a concrete device array laid
     out as :func:`engine_sharding` lays a stack out: on the current

@@ -46,7 +46,8 @@ from pilosa_tpu.ops import bitmap as B
 from pilosa_tpu.ops import bsi as S
 from pilosa_tpu.ops import topk as T
 from pilosa_tpu.ops.groupby import (PAIR_COUNTS_MAX_ROWS, group_planes,
-                                    pair_counts, pair_sums)
+                                    pair_counts, pair_counts_route,
+                                    pair_sums)
 from pilosa_tpu.pql.ast import Call, Condition, Query, ROW_OPTIONS, unwrap_options
 from pilosa_tpu.pql.parser import parse
 from pilosa_tpu.pql import programs
@@ -1339,6 +1340,8 @@ class Executor:
                 r = min(st.block_rows, n - lo)
                 if r <= 0:
                     break
+                if not blocks and span.recording:
+                    span.set_tag("route", pair_counts_route(planes, blk))
                 rs = min(r, room)
                 gs = max(1, room // rs)
                 for g0 in range(0, g, gs):
@@ -1386,9 +1389,13 @@ class Executor:
             with get_tracer().start_span(
                     "groupby.level", level=level, groups_in=len(keys),
                     plane_bytes=group_planes.nbytes) as span:
-                counts_matrix = np.concatenate(
-                    [np.asarray(pair_counts(group_planes, blk))
-                     for _, blk in st.iter_blocks()], axis=1)[:, :nb]
+                parts = []
+                for _, blk in st.iter_blocks():
+                    if not parts and span.recording:
+                        span.set_tag("route",
+                                     pair_counts_route(group_planes, blk))
+                    parts.append(np.asarray(pair_counts(group_planes, blk)))
+                counts_matrix = np.concatenate(parts, axis=1)[:, :nb]
                 M.REGISTRY.count(M.METRIC_GROUPBY_HOST_FETCHES)
                 gi, gj = np.nonzero(counts_matrix)
                 span.set_tag("groups_live", int(gi.size))

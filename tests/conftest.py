@@ -50,6 +50,18 @@ def pallas_as_compiled(monkeypatch):
     PU.reset_failures()
 
 
+@pytest.fixture
+def per_device():
+    """``nbytes`` of a stack block as one device of the engine mesh holds
+    them: the residency plane (core/stacked.py) caps bytes per device,
+    so a test that sizes ``_BLOCK_BYTES`` or a ``DeviceBudget`` by whole
+    blocks states the whole and passes it through this."""
+    from pilosa_tpu.parallel.mesh import engine_mesh
+
+    n = engine_mesh().devices.size
+    return lambda nbytes: nbytes // n
+
+
 @pytest.fixture(autouse=True)
 def _budget_leak_audit():
     """Post-test accounting audit (the reference's testhook auditors,

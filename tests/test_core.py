@@ -293,6 +293,6 @@ class TestParanoia:
         b = DeviceBudget(1 << 20)
         b.charge(("x", 0), 100, lambda: None)
         b.audit()
-        b.used += 7  # simulated leak
+        b._used[next(iter(b._used))] += 7  # simulated leak
         with pytest.raises(AssertionError):
             b.audit()

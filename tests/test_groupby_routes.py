@@ -99,14 +99,16 @@ MODES = {
 @pytest.mark.parametrize("n_fields,mode", [
     (n, mode) for mode in sorted(MODES) for n in (1, 2, 3, 4)
     if n == 2 or not mode.startswith("pallas")])
-def test_groupby_equals_the_numpy_reference(n_fields, mode, monkeypatch):
+def test_groupby_equals_the_numpy_reference(n_fields, mode, monkeypatch,
+                                            per_device):
     filtered, with_sum, limit, compressed, paged, above = MODES[mode]
     if compressed:
         monkeypatch.setenv("PILOSA_TPU_COMPRESS", "1")
     if paged:
         # 8-row blocks at three shards, and a budget of about four of them
-        monkeypatch.setattr(stx, "_BLOCK_BYTES", 4 << 20)
-        monkeypatch.setattr(stx, "BUDGET", stx.DeviceBudget(14 << 20))
+        monkeypatch.setattr(stx, "_BLOCK_BYTES", per_device(4 << 20))
+        monkeypatch.setattr(stx, "BUDGET",
+                            stx.DeviceBudget(per_device(14 << 20)))
     if above:
         monkeypatch.setattr(ex, "_DENSE_MAX_CELLS", 2)
     if mode.startswith("pallas"):
@@ -234,11 +236,13 @@ def _wide_api(rows, per_row=8):
 
 @pytest.mark.parametrize("rows", [4, 8, 16])
 def test_group_planes_held_stay_under_a_block_as_groups_grow(rows,
-                                                             monkeypatch):
+                                                             monkeypatch,
+                                                             per_device):
     """A 3-field GroupBy below the cap makes rows x rows group planes, a
     block at a time: no level ever holds more than a row block's bytes,
     it fetches once, and the planes it made are those of the groups."""
-    monkeypatch.setattr(stx, "_BLOCK_BYTES", 2 << 20)   # 8 planes of 2 shards
+    # 8 planes of 2 shards
+    monkeypatch.setattr(stx, "_BLOCK_BYTES", per_device(2 << 20))
     api, n = _wide_api(rows)
     plane = 2 * SHARD_WIDTH // 8
     made0 = M.REGISTRY.value(M.METRIC_GROUPBY_GROUP_PLANE_BYTES)
@@ -247,7 +251,7 @@ def test_group_planes_held_stay_under_a_block_as_groups_grow(rows,
     assert sum(g["count"] for g in groups) == n
     assert len(groups) == rows * rows * 4
     assert spans and all(s["tags"]["level"] == 1 for s in spans)
-    assert max(s["tags"]["plane_bytes"] for s in spans) <= stx._BLOCK_BYTES
+    assert max(s["tags"]["plane_bytes"] for s in spans) <= 2 << 20
     assert sum(s["tags"]["blocks"] for s in spans) >= rows * rows // 8
     assert sum(s["tags"]["groups_in"] for s in spans) == rows
     assert sum(s["tags"]["groups_live"] for s in spans) == rows * rows

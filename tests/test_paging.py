@@ -25,9 +25,10 @@ BUDGET_BYTES = 20 << 20  # ~5 blocks resident
 
 
 @pytest.fixture
-def paged_env(monkeypatch):
-    monkeypatch.setattr(stx, "_BLOCK_BYTES", BLOCK_BYTES)
-    monkeypatch.setattr(stx, "BUDGET", stx.DeviceBudget(BUDGET_BYTES))
+def paged_env(monkeypatch, per_device):
+    monkeypatch.setattr(stx, "_BLOCK_BYTES", per_device(BLOCK_BYTES))
+    monkeypatch.setattr(stx, "BUDGET",
+                        stx.DeviceBudget(per_device(BUDGET_BYTES)))
     h = Holder()
     e = Executor(h)
     h.create_index("i").create_field("f")
@@ -58,7 +59,7 @@ def test_high_cardinality_topn_under_budget(paged_env):
     stacks = [st for inner in f._stacked_cache.values()
               for (_, st) in inner.values()]
     assert any(st.paged and st.n_blocks > 1 for st in stacks)
-    assert stx.BUDGET.used <= BUDGET_BYTES
+    assert stx.BUDGET.used <= stx.BUDGET.cap
     assert stx.PAGING_STATS["evictions"] > 0, "budget never forced eviction"
 
 
@@ -93,7 +94,7 @@ def test_groupby_on_paged_stack_matches_oracle(paged_env):
         for r, cs in oracle.items():
             want = len(g_oracle[gr] & cs)
             assert gmap.get((gr, r), 0) == want, (gr, r)
-    assert stx.BUDGET.used <= BUDGET_BYTES
+    assert stx.BUDGET.used <= stx.BUDGET.cap
 
 
 def test_eviction_rebuilds_transparently(paged_env):
@@ -234,12 +235,13 @@ def test_eviction_racing_iter_blocks_reader(paged_env):
         "the evictor never forced a rebuild"
 
 
-def test_advance_under_tiny_budget_no_crash(monkeypatch):
+def test_advance_under_tiny_budget_no_crash(monkeypatch, per_device):
     """_advance_set must assign _blocks before charging: an eviction
     cascade can pop the new stack's own earlier entries."""
-    monkeypatch.setattr(stx, "_BLOCK_BYTES", 4 << 20)
+    monkeypatch.setattr(stx, "_BLOCK_BYTES", per_device(4 << 20))
     # budget fits ~1 block: every charge evicts the previous entries
-    monkeypatch.setattr(stx, "BUDGET", stx.DeviceBudget(3 << 20))
+    monkeypatch.setattr(stx, "BUDGET",
+                        stx.DeviceBudget(per_device(3 << 20)))
     h = Holder()
     e = Executor(h)
     h.create_index("i").create_field("f")
@@ -257,4 +259,4 @@ def test_advance_under_tiny_budget_no_crash(monkeypatch):
     assert sum(p.count for p in top2.pairs) == base_total + int(changed)
     # eviction cascades under the tiny cap never left the budget over by
     # more than the entry being inserted
-    assert stx.BUDGET.used <= stx.BUDGET.cap + (4 << 20)
+    assert stx.BUDGET.used <= stx.BUDGET.cap + per_device(4 << 20)

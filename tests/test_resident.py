@@ -176,8 +176,10 @@ class TestResidencyMetrics:
         e.execute("i", "Count(Row(f=2))")
         assert M.REGISTRY.value(M.METRIC_DEVICE_RESIDENT_HITS) > hits0
 
-    def test_evictions_counted_under_tiny_budget(self, monkeypatch):
-        monkeypatch.setattr(stx, "BUDGET", stx.DeviceBudget(1 << 20))
+    def test_evictions_counted_under_tiny_budget(self, monkeypatch,
+                                                 per_device):
+        monkeypatch.setattr(stx, "BUDGET",
+                            stx.DeviceBudget(per_device(1 << 20)))
         ev0 = M.REGISTRY.value(M.METRIC_DEVICE_STACK_EVICTIONS)
         h = Holder()
         e = Executor(h)
@@ -243,12 +245,14 @@ class TestStaleAndEviction:
 
 
 class TestConcurrentWritersTinyBudget:
-    def test_in_place_advance_under_writers_and_eviction(self, monkeypatch):
+    def test_in_place_advance_under_writers_and_eviction(self, monkeypatch,
+                                                         per_device):
         """Readers against resident stacks while writers advance them in
         place, under a budget small enough that resident blocks evict
         mid-query: every read must be internally consistent (count ==
         len(columns) of the same row) and the final state exact."""
-        monkeypatch.setattr(stx, "BUDGET", stx.DeviceBudget(2 << 20))
+        monkeypatch.setattr(stx, "BUDGET",
+                            stx.DeviceBudget(per_device(2 << 20)))
         h = Holder()
         e = Executor(h)
         idx = h.create_index("i")
