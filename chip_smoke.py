@@ -229,14 +229,17 @@ _METRIC = re.compile(r"^(\w+?)(?:\{(.*)\})? (\S+)$")
 
 
 def kernel_table(text):
-    """{kernel: {"dispatch": n, "on_mesh": n, "fallback": {why: n}}}
-    (``on_mesh``: the dispatches that ran per chip under shard_map) and
-    the mesh placement fallback count, from the Prometheus exposition."""
+    """{kernel: {"dispatch": n, "on_mesh": n, "body": {body: n},
+    "fallback": {why: n}}} (``on_mesh``: the dispatches that ran per chip
+    under shard_map; ``body``: those of the pair-count kernel by the body
+    its operands' heights chose) and the mesh placement fallback count,
+    from the Prometheus exposition."""
     table, mesh_fallback = {}, 0.0
 
     def row_of(kernel):
         return table.setdefault(
-            kernel, {"dispatch": 0, "on_mesh": 0, "fallback": {}})
+            kernel, {"dispatch": 0, "on_mesh": 0, "body": {},
+                     "fallback": {}})
 
     for line in text.splitlines():
         m = None if line.startswith("#") else _METRIC.match(line)
@@ -248,6 +251,8 @@ def kernel_table(text):
             row_of(lab["kernel"])["dispatch"] += int(float(value))
         elif name.endswith("ops_pallas_mesh_dispatch_total"):
             row_of(lab["kernel"])["on_mesh"] += int(float(value))
+        elif name.endswith("ops_pallas_body_total"):
+            row_of(lab["kernel"])["body"][lab["body"]] = int(float(value))
         elif name.endswith("ops_pallas_fallback_total"):
             row_of(lab["kernel"])["fallback"][lab["why"]] = int(float(value))
         elif name.endswith("mesh_sharding_fallback_total"):
@@ -257,8 +262,9 @@ def kernel_table(text):
 
 def check_kernels(table, mesh_fallback, expected, log_path, on_mesh=()):
     """No kernel may have struck out, no stack may have lost its mesh
-    placement, every expected kernel must have dispatched, and those of
-    ``on_mesh`` as the per-chip mesh program."""
+    placement, every expected kernel must have dispatched, those of
+    ``on_mesh`` as the per-chip mesh program, and every ``pair_counts``
+    dispatch must name the kernel body it took."""
     for kernel, row in table.items():
         for why in ("error", "failures"):
             if row["fallback"].get(why):
@@ -272,6 +278,10 @@ def check_kernels(table, mesh_fallback, expected, log_path, on_mesh=()):
     missing = [k for k in on_mesh if not table.get(k, {}).get("on_mesh")]
     if missing:
         fail(f"kernel dispatch check: zero mesh dispatches of {missing}")
+    row = table.get("pair_counts")
+    if row and sum(row["body"].values()) != row["dispatch"]:
+        fail(f"kernel body check: {row['dispatch']} dispatches of "
+             f"pair_counts took the bodies {row['body']}")
 
 
 # -- server child ----------------------------------------------------------------
@@ -405,10 +415,12 @@ def drive(args, client, proc, log_path, t_spawn):
     with open(log_path[:-len(".log")] + ".metrics", "w") as f:
         f.write(metrics)  # every counter of the run, beside the log
     table, mesh_fallback = kernel_table(metrics)
-    print(f"{'kernel':<16}{'dispatch':>9}{'on mesh':>9}  fallback{{why}}")
+    print(f"{'kernel':<16}{'dispatch':>9}{'on mesh':>9}  "
+          f"{'body{body}':<24}fallback{{why}}")
     for kernel in sorted(table):
         row = table[kernel]
         print(f"{kernel:<16}{row['dispatch']:>9}{row['on_mesh']:>9}  "
+              f"{json.dumps(row['body'], sort_keys=True):<24}"
               f"{json.dumps(row['fallback'], sort_keys=True)}")
     print(f"mesh_sharding_fallback_total {mesh_fallback}")
     expected = EXPECTED_KERNELS_MESH if mesh else EXPECTED_KERNELS

@@ -341,11 +341,11 @@ def _plane_popcounts_xla(planes, filt):
 @platform.guarded_call
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def _plane_popcounts_pallas(planes, filt, interpret):
-    """MXU formulation: popcount(P & Q) = Σc P[c]·Q[c], so every per-
-    plane popcount is one entry of the pair-count matmul — A = the two
-    sign classes, B = the magnitude planes plus an all-ones plane whose
-    column recovers the filtered count (pos and neg are disjoint, so
-    their popcounts add)."""
+    """Pair-count formulation: every per-plane popcount is one entry of
+    popcount(A_i & B_j) (ops/groupby._pair_counts_traced; two rows take
+    its VPU body) — A = the two sign classes, B = the magnitude planes
+    plus an all-ones plane whose column recovers the filtered count (pos
+    and neg are disjoint, so their popcounts add)."""
     exists = planes[EXISTS]
     sign = planes[SIGN]
     mags = planes[OFFSET:]
@@ -364,8 +364,8 @@ def bsi_plane_popcounts(planes, filt):
     ``sum = Σ pos[k]<<k − Σ neg[k]<<k`` with Python ints (reference:
     fragment.go:724 sum — same plane-popcount algorithm, scalar Go loop).
     Returns (count, pos_counts[depth], neg_counts[depth]). Dispatch:
-    eligible concrete stacks take the Pallas bit-expand + int8 MXU
-    matmul; the per-plane XLA reduction is the oracle fallback.
+    eligible concrete stacks take the Pallas pair-count kernel; the
+    per-plane XLA reduction is the oracle fallback.
     """
     why = PU.why_not("bsi_sum", planes)
     if why is None and isinstance(filt, jax.core.Tracer):
@@ -377,7 +377,7 @@ def bsi_plane_popcounts(planes, filt):
                                  planes.shape[-1]):
                 out = _plane_popcounts_pallas(planes, filt,
                                               PU.use_interpret())
-            PU.dispatched("bsi_sum")
+            PU.dispatched("bsi_sum", body=_gb.pallas_body(2, depth + 1))
             return out
         except Exception as e:
             PU.failed("bsi_sum", e)

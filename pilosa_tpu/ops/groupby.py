@@ -1,33 +1,49 @@
-"""GroupBy pair-count kernels — MXU matmul over bit planes.
+"""GroupBy pair-count kernels — popcounts of pairwise ANDs over bit planes.
 
 The reference's GroupBy walks nested row iterators per shard and popcounts
 each intersection one pair at a time (reference: executor.go:3918
-executeGroupByShard, :3176 groupByIterator). The TPU-native formulation:
-the matrix of intersection counts between two row sets
+executeGroupByShard, :3176 groupByIterator). The TPU-native formulation
+counts the whole matrix of intersections between two row sets at once,
 
     C[i, j] = popcount(A_i AND B_j)
 
-is exactly a matmul over {0,1} bit lanes: expand each uint32 word into 32
-int8 lanes and contract over the 2^20-column axis on the MXU with int32
-accumulation — exact for any count, and the v5e MXU runs int8 at 2x bf16
-rate (measured ~18% faster end-to-end; the expansion, not the matmul,
-bounds this kernel). This turns the reference's scalar hot loop into the
-systolic array's native op — the core of BASELINE.json config 3
-(TopK+GroupBy on SSB) and the north-star GroupBy speedup.
+in int32, exact for any count up to S * 2^20. This is the core of
+BASELINE.json config 3 (TopK+GroupBy on SSB) and the north-star GroupBy
+speedup.
 
-Column blocking keeps the int8 expansion in VMEM-sized chunks instead of
-materializing ``rows x 2^20`` lanes in HBM.
+Two forms: a Pallas kernel that works a word block at a time in VMEM, so
+that HBM sees only the packed uint32 planes (:func:`_pair_counts_traced`),
+and an XLA scan that any backend and any sharding takes
+(:func:`_pair_counts_xla`: each uint32 word expanded into 32 int8 lanes
+and contracted as a matmul, a column block at a time).
 
-Two forms of the one matmul: a fused Pallas kernel that expands in VMEM
-(:func:`_pair_counts_traced`) and an XLA scan that any backend and any
-sharding takes (:func:`_pair_counts_xla`). The kernel has two placements:
-one chip's program, and the mesh program that runs it on every chip over
-the words that chip holds and sums the counts with one small ``psum``
+The kernel has two bodies, one algorithm with two instruction mixes, and
+picks between them from its operands' heights alone (:func:`pallas_body`):
+
+* ``vpu`` — AND every row of the shorter operand with the rows of the
+  taller, ``population_count``, add into lane-wise accumulators: 3
+  vector operations per pair and 128 words, nothing expanded. The
+  cheaper mix while r1 * r2 / (r1 + r2) is small: every shape a served
+  GroupBy, its Sum and TopN send. Written as loops around one short
+  listing, so that its program costs the server no more to build than
+  the MXU body's (a listing is traced, lowered and serialized once a
+  shape and a process, before the compile cache can be asked: PERF.md
+  section 5, "Set-up of the SSB cells").
+* ``mxu`` — expand both operands to int8 0/1 lanes (32 shift/mask/narrow
+  passes over every row) and contract on the systolic array: its cost
+  goes with r1 + r2, so it keeps the operands with two tall sides.
+
+On a v5e the MXU body is bound by its instruction schedule, not by HBM,
+whatever the heights; the VPU body runs the narrow shapes within a sixth
+of HBM's floor (PERF.md §5 has the sweep). The kernel has two
+placements: one chip's program, and the mesh program that runs it on
+every chip over the words that chip holds and sums the counts with one
+small ``psum``
 (``parallel/mesh.psum_over_words``; the XLA scan, being a loop over the
 sharded axis, would have both operands gathered whole onto every chip).
-:func:`pair_counts` and :func:`pair_sums` pick from what
-``pallas_util.why_not`` and ``parallel/mesh.engine_placed`` see in their
-concrete operands: backend, sharding, rows. No option chooses.
+:func:`pair_counts` and :func:`pair_sums` pick the form and the placement
+from what ``pallas_util.why_not`` and ``parallel/mesh.engine_placed`` see
+in their concrete operands: backend, sharding, rows. No option chooses.
 """
 
 from __future__ import annotations
@@ -46,15 +62,53 @@ from pilosa_tpu.ops.bitmap import zeros_varying_like
 # -> int8 chunk of [R, 65536] = 64KiB per row, MXU-friendly.
 BLOCK_WORDS = 2048
 
-# Pallas kernel tile sizes (VMEM-bounded; swept on v5e: BW=512/TR2=256
-# beat 1024/256, 512/512, 256/512): per step the expanded int8 lanes are
-# [R1p, 16384] + [256, 16384] = a few MB of VMEM.
+# Tile sizes of the kernel's MXU body (VMEM-bounded; swept on v5e:
+# BW=512/TR2=256 beat 1024/256, 512/512, 256/512: its schedule grows
+# faster than its word block): per step the expanded int8 lanes are
+# [R1p, 16384] + [256, 16384] = a few MB of VMEM. Both bodies pad the
+# word axis to _PALLAS_BW and tile the second operand by _PALLAS_TR2.
 _PALLAS_BW = 512
 _PALLAS_TR2 = 256
 _PALLAS_MAX_R1 = 128  # larger outer sides would blow VMEM; swap or scan
 #: the most rows the Pallas route of :func:`pair_counts` takes as its
 #: first operand: a caller that blocks its group planes blocks them to this
 PAIR_COUNTS_MAX_ROWS = _PALLAS_MAX_R1
+# The VPU body's word block is the widest power-of-two multiple of
+# _PALLAS_BW that divides the padded width (a shard is 32,768 words),
+# up to _VPU_MAX_BW words and _VPU_INPUT_BYTES of double-buffered input
+# (swept on v5e, 24 x 16 rows: 0.69 / 0.58 / 0.52 / 0.49 ms at 2048 /
+# 4096 / 8192 / 16384: a grid step costs ~0.35 us whatever it holds).
+# Inside it the body is three loops around one listing: over _VPU_SUB
+# words (four column chunks, unrolled: what the 4 VALUs' schedule
+# needs), over _VPU_GROUP rows of ``b`` (8 vregs a chunk) and over
+# _VPU_ROWS rows of ``a``, of which as many are live together as
+# _VPU_ACC_VREGS accumulator vregs hold, so that a loaded ``b`` vreg
+# serves several rows (a load a pair would bound the loop, not the
+# VALUs). A loop step of 16 x 64 rows is 384 bundles of VALU work,
+# which Mosaic schedules at 410: 1,640 a 16 x 256 step against 1,553
+# for the same rows listed one by one (PERF.md section 5).
+_VPU_MAX_BW = 16384
+_VPU_INPUT_BYTES = 6 << 20
+_VPU_SUB = 512
+_VPU_GROUP = 64
+_VPU_ROWS = 8
+_VPU_ACC_VREGS = 32
+#: the VPU body where r1 * r2 < _VPU_MAX_PAIRS_PER_ROW * (r1 + r2)
+_VPU_MAX_PAIRS_PER_ROW = 28
+
+
+def pallas_body(r1: int, r2: int) -> str:
+    """``"vpu"`` or ``"mxu"``: which body of the Pallas kernel counts an
+    ``r1``-row operand against an ``r2``-row one. A pure function of the
+    two heights. The VPU body spends 3 * r1 * r2 / 8 vector operations
+    per 128 words, the MXU body about 96 * (r1 + r2) / 8 on expanding
+    both operands, so the first wins while r1 * r2 / (r1 + r2) is under
+    ~32 by the arithmetic; the sweep on the chip (PERF.md §5) puts the
+    line where it wins by more than a tenth: under 28 it takes 0.41 to
+    0.80 of the MXU body's time, from 28.4 to 32 it takes 0.90 to 0.93,
+    above that more."""
+    return ("vpu" if r1 * r2 < _VPU_MAX_PAIRS_PER_ROW * (r1 + r2)
+            else "mxu")
 
 
 def _expand_bits_i8(words):
@@ -72,9 +126,9 @@ def pair_counts(a, b, block_words: int = BLOCK_WORDS):
     aggregates (group bitmaps x BSI magnitude planes).
 
     Dispatch: concrete arrays on a TPU backend (or anywhere under
-    ``PILOSA_TPU_PALLAS=1``, via the interpreter) take the fused Pallas
-    expand+matmul kernel (the expansion stays in VMEM instead of
-    staging int8 lanes through HBM): on one chip as one program, and,
+    ``PILOSA_TPU_PALLAS=1``, via the interpreter) take the Pallas
+    kernel (HBM sees only the packed planes; its body, ``vpu`` or
+    ``mxu``, goes by the two heights): on one chip as one program, and,
     when both operands are sharded as the engine mesh places a stack
     (:func:`_mesh_route`), as the mesh program that counts on every chip
     over its own words and ``psum``s the ``int32[R1, R2]``. Traced
@@ -84,7 +138,8 @@ def pair_counts(a, b, block_words: int = BLOCK_WORDS):
     the kernel chooses its route where its operands are still concrete
     and calls :func:`_pair_counts_traced` itself (:func:`pair_sums`,
     ops/bsi.py, ops/topk.py). Outcomes are counted on the
-    ``ops_pallas_*`` metrics (ops/pallas_util.py)."""
+    ``ops_pallas_*`` metrics (ops/pallas_util.py), the body a dispatch
+    took among them."""
     why, mesh = _pair_counts_plan(a, b)
     if why is None or mesh is not None:
         try:
@@ -92,7 +147,8 @@ def pair_counts(a, b, block_words: int = BLOCK_WORDS):
                                  a.shape[1]):
                 out = (_pair_counts_pallas(a, b) if mesh is None
                        else _pair_counts_mesh(a, b, mesh=mesh))
-            PU.dispatched("pair_counts", on_mesh=mesh is not None)
+            PU.dispatched("pair_counts", on_mesh=mesh is not None,
+                          body=pallas_body(a.shape[0], b.shape[0]))
             return out
         except Exception as e:
             PU.failed("pair_counts", e)
@@ -108,15 +164,18 @@ def _pair_counts_plan(a, b):
     return why, _mesh_route(why, a, b)
 
 
-def pair_counts_route(a, b) -> str:
-    """Which program :func:`pair_counts` runs for these operands:
-    ``"pallas"`` (the kernel on one chip), ``"mesh"`` (the kernel on
-    every chip over its own words) or ``"xla"`` (the scan). Runs and
-    counts nothing: for a span's tag."""
+def pair_counts_route(a, b):
+    """``(route, body)``: which program :func:`pair_counts` runs for
+    these operands, ``"pallas"`` (the kernel on one chip), ``"mesh"``
+    (the kernel on every chip over its own words) or ``"xla"`` (the
+    scan), and which body the kernel takes there (``"vpu"`` | ``"mxu"``;
+    ``"none"`` under the scan). Runs and counts nothing: for a span's
+    tags."""
     why, mesh = _pair_counts_plan(a, b)
-    if why is None:
-        return "pallas"
-    return "xla" if mesh is None else "mesh"
+    if why is not None and mesh is None:
+        return "xla", "none"
+    return ("pallas" if why is None else "mesh",
+            pallas_body(a.shape[0], b.shape[0]))
 
 
 def _mesh_route(why, *operands):
@@ -146,7 +205,32 @@ def _expand_bitmajor(x):
         [((x >> k) & 1).astype(jnp.int8) for k in range(32)], axis=1)
 
 
-def _pallas_kernel(a_ref, b_ref, out_ref):
+def _pad_rows(x, rows: int):
+    return x if rows == x.shape[0] else jnp.pad(
+        x, ((0, rows - x.shape[0]), (0, 0)))
+
+
+def _padded_words(a, b):
+    """Both operands zero-padded to whole ``_PALLAS_BW``-word blocks
+    (zero words count nothing)."""
+    pad_w = (-a.shape[1]) % _PALLAS_BW
+    if pad_w:
+        a = jnp.pad(a, ((0, 0), (0, pad_w)))
+        b = jnp.pad(b, ((0, 0), (0, pad_w)))
+    return a, b
+
+
+def _row_tile(r2: int):
+    """``(tr2, r2p)``: the row tile of the second operand and its height
+    in whole tiles. A second operand narrower than one row tile is one
+    tile of its own (sublane-rounded) height: padded to _PALLAS_TR2, an
+    8-row block of 66-shard rows (8.65 MB each) would be copied out as
+    2.2 GB and read back as such."""
+    tr2 = min(_PALLAS_TR2, -(-r2 // 8) * 8)
+    return tr2, -(-r2 // tr2) * tr2
+
+
+def _mxu_kernel(a_ref, b_ref, out_ref):
     from jax.experimental import pallas as pl
 
     w = pl.program_id(1)  # innermost: contiguous revisits of the out
@@ -164,34 +248,17 @@ def _pallas_kernel(a_ref, b_ref, out_ref):
         out_ref[:, :] += blk
 
 
-def _pair_counts_traced(a, b, interpret: bool):
-    """Traceable core of the fused bit-expansion + int8 MXU matmul: the
-    expansion lives in VMEM per (512-word x 256-row) tile, so HBM sees
-    only the packed uint32 planes (measured 5.6 ms vs 10.7 ms XLA for
-    the SSB config-3 contraction on v5e). Shared by bsi_plane_popcounts
-    (magnitude-plane popcounts) and TopN row counts — any "popcount of
-    pairwise ANDs" is this one matmul."""
+def _pair_counts_mxu(a, b, interpret: bool):
+    """The MXU body: both operands expanded to int8 lanes in VMEM per
+    (512-word x 256-row) tile and contracted on the systolic array."""
     from jax.experimental import pallas as pl
 
-    r1, w_total = a.shape
-    r2, _ = b.shape
-    pad_w = (-w_total) % _PALLAS_BW
-    if pad_w:
-        a = jnp.pad(a, ((0, 0), (0, pad_w)))
-        b = jnp.pad(b, ((0, 0), (0, pad_w)))
+    r1, r2 = a.shape[0], b.shape[0]
+    a, b = _padded_words(a, b)
     r1p = max(8, -(-r1 // 8) * 8)  # sublane multiple, not just >= 8
-    if r1p != r1:
-        a = jnp.pad(a, ((0, r1p - r1), (0, 0)))
-    # a second operand narrower than one row tile is one tile of its own
-    # (sublane-rounded) height: padded to _PALLAS_TR2, an 8-row block of
-    # 66-shard rows (8.65 MB each) would be copied out as 2.2 GB and read
-    # back as such
-    tr2 = min(_PALLAS_TR2, -(-r2 // 8) * 8)
-    r2p = -(-r2 // tr2) * tr2
-    if r2p != r2:
-        b = jnp.pad(b, ((0, r2p - r2), (0, 0)))
+    tr2, r2p = _row_tile(r2)
     out = pl.pallas_call(
-        _pallas_kernel,
+        _mxu_kernel,
         grid=(r2p // tr2, a.shape[1] // _PALLAS_BW),
         in_specs=[
             pl.BlockSpec((r1p, _PALLAS_BW), lambda t, w: (0, w)),
@@ -200,8 +267,165 @@ def _pair_counts_traced(a, b, interpret: bool):
         out_specs=pl.BlockSpec((r1p, tr2), lambda t, w: (0, t)),
         out_shape=jax.ShapeDtypeStruct((r1p, r2p), jnp.int32),
         interpret=interpret,
-    )(a, b)
+    )(_pad_rows(a, r1p), _pad_rows(b, r2p))
     return out[:r1, :r2]
+
+
+def _vpu_kernel(a_ref, b_ref, out_ref, acc_ref):
+    """``acc_ref``: VMEM ``uint32[r1, tr2, 128]`` of lane-wise partial
+    counts (a lane sums at most 32 * W / 128, twice that where two row
+    groups overlap: exact far beyond S * 2^20), so a word step does no
+    cross-lane work.
+
+    The body is three nested loops, over ``_VPU_SUB`` words, over
+    ``_VPU_GROUP`` rows of ``b`` and over ``_VPU_ROWS`` rows of ``a``,
+    around one listing of at most 24 rows by ``_VPU_SUB / 128`` column
+    chunks: the program does not grow with the operands' heights (a
+    listing is traced, lowered and serialized once a shape and a
+    process, before the compile cache can be asked)."""
+    from jax.experimental import pallas as pl
+
+    w = pl.program_id(1)  # innermost, as in the MXU body
+    r1, bw = a_ref.shape
+    tr2 = b_ref.shape[0]
+    nj = min(tr2, _VPU_GROUP)
+    groups = -(-tr2 // nj)
+    # rows of ``a`` a loop step takes (against a group of under four
+    # vregs a chunk all of them: ``a`` is the shorter operand, 24 rows at
+    # most there, and a step of eight would be too little work), and
+    # those of them whose accumulators are live together: a loaded vreg
+    # of ``b`` serves them all
+    rows_a = _VPU_ROWS if nj >= 4 * 8 else r1
+    ib = min(rows_a, _VPU_ACC_VREGS // (nj // 8))
+
+    @pl.when(w == 0)
+    def _():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def count(off, j0, i0, ni):
+        """``ni`` rows of ``a`` from ``i0`` against ``nj`` rows of ``b``
+        from ``j0`` over ``_VPU_SUB`` words from ``off``."""
+        rows = pl.ds(j0, nj)
+        # a traced start is a whole sublane tile's: the row inside the
+        # view is static, and its load broadcasts it for free (Mosaic
+        # refuses a dynamic load from an unaligned row)
+        a_rows, first = ((a_ref, i0) if isinstance(i0, int)
+                         else (a_ref.at[pl.ds(i0, ni)], 0))
+        for k0 in range(0, ni, ib):
+            block = range(k0, min(k0 + ib, ni))
+            sums = [acc_ref[i0 + k, rows] for k in block]
+            for c in range(0, _VPU_SUB, 128):
+                cols = pl.ds(off + c, 128)
+                b_rows = b_ref[rows, cols]
+                for n, k in enumerate(block):
+                    sums[n] += lax.population_count(
+                        a_rows[pl.ds(first + k, 1), cols] & b_rows)
+            for n, k in enumerate(block):
+                acc_ref[i0 + k, rows] = sums[n]
+
+    def words(step, carry):
+        off = pl.multiple_of(step * _VPU_SUB, _VPU_SUB)
+
+        def group(j, carry):
+            # the last group of a ragged tile starts early and counts
+            # the rows it shares with the one before a second time
+            j0 = pl.multiple_of(jnp.minimum(j * nj, tr2 - nj), 8)
+            _blocks(r1, rows_a, functools.partial(count, off, j0))
+            return carry
+
+        if groups == 1:
+            _blocks(r1, rows_a, functools.partial(count, off, 0))
+        else:
+            lax.fori_loop(0, groups, group, None)
+        return carry
+
+    lax.fori_loop(0, bw // _VPU_SUB, words, None)
+
+    @pl.when(w == pl.num_programs(1) - 1)
+    def _():
+        counts = jnp.sum(acc_ref[...].astype(jnp.int32), axis=-1)
+        twice = groups * nj - tr2  # rows the last two groups share
+        if twice:
+            col = lax.broadcasted_iota(jnp.int32, counts.shape, 1)
+            shared = (col >= tr2 - nj) & (col < tr2 - nj + twice)
+            counts = jnp.where(shared, counts >> 1, counts)
+        out_ref[...] = counts
+
+
+def _blocks(n: int, size: int, body) -> None:
+    """``body(start, count)`` over ``range(n)`` in blocks of ``size``: the
+    whole blocks under one ``fori_loop`` (``start`` traced) where there
+    are several, the rest as a block of its own: however many blocks,
+    ``body`` is traced twice at most."""
+    from jax.experimental import pallas as pl
+
+    whole = n // size
+    if whole == 1:
+        body(0, size)
+    elif whole:
+        def step(i, carry):
+            body(pl.multiple_of(i * size, size), size)
+            return carry
+
+        lax.fori_loop(0, whole, step, None)
+    if n % size:
+        body(whole * size, n % size)
+
+
+def _vpu_block_words(r1: int, tr2: int, padded_words: int) -> int:
+    """Words a grid step of the VPU body takes for these heights."""
+    bw = _PALLAS_BW
+    while (2 * bw <= _VPU_MAX_BW and padded_words % (2 * bw) == 0
+           and 2 * 4 * (r1 + tr2) * 2 * bw <= _VPU_INPUT_BYTES):
+        bw *= 2
+    return bw
+
+
+def _pair_counts_vpu(a, b, interpret: bool):
+    """The VPU body: every row of the shorter operand ANDed with the
+    rows of the taller, popcounted and added into lane-wise accumulators
+    in VMEM scratch; one cross-lane reduce on the last word step. Both
+    operands go in as they are (a one-row TopN filter is one row, not
+    eight; no row is copied to be padded): the last row tile of the
+    taller hangs over its end, and the counts of the rows that are not
+    there are cut off."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    r1, r2 = a.shape[0], b.shape[0]
+    if r1 > r2:
+        # popcount(A_i & B_j) is symmetric, and the kernel lists the
+        # rows of its first operand: the shorter one
+        return _pair_counts_vpu(b, a, interpret).T
+    a, b = _padded_words(a, b)
+    tr2, r2p = _row_tile(r2)
+    bw = _vpu_block_words(r1, tr2, a.shape[1])
+    out = pl.pallas_call(
+        _vpu_kernel,
+        grid=(r2p // tr2, a.shape[1] // bw),
+        in_specs=[
+            pl.BlockSpec((r1, bw), lambda t, w: (0, w)),
+            pl.BlockSpec((tr2, bw), lambda t, w: (t, w)),
+        ],
+        out_specs=pl.BlockSpec((r1, tr2), lambda t, w: (0, t)),
+        out_shape=jax.ShapeDtypeStruct((r1, r2p), jnp.int32),
+        scratch_shapes=[pltpu.VMEM((r1, tr2, 128), jnp.uint32)],
+        interpret=interpret,
+    )(a, b)
+    return out[:, :r2]
+
+
+def _pair_counts_traced(a, b, interpret: bool):
+    """Traceable core of the Pallas pair count: one ``pallas_call`` on
+    the two packed operands, its body chosen by :func:`pallas_body` from
+    their heights (static under a trace). Both bodies work a word block
+    at a time in VMEM, so HBM sees only the packed uint32 planes. Shared
+    by ``pair_sums`` (a call a magnitude plane), ``bsi_plane_popcounts``
+    (magnitude-plane popcounts) and TopN row counts: any "popcount of
+    pairwise ANDs" is this one kernel."""
+    if pallas_body(a.shape[0], b.shape[0]) == "vpu":
+        return _pair_counts_vpu(a, b, interpret)
+    return _pair_counts_mxu(a, b, interpret)
 
 
 @platform.guarded_call
@@ -318,7 +542,9 @@ def pair_sums(a, b, mags, pos, neg):
                 out = (_pair_sums_pallas(a, b, mags, pos, neg)
                        if mesh is None else
                        _pair_sums_mesh(a, b, mags, pos, neg, mesh=mesh))
-            PU.dispatched("pair_sums", on_mesh=mesh is not None)
+            PU.dispatched("pair_sums", on_mesh=mesh is not None,
+                          body=pallas_body(_pair_sums_step_rows(a.shape[0]),
+                                           b.shape[0]))
             return out
         except Exception as e:
             PU.failed("pair_sums", e)
@@ -344,16 +570,22 @@ def _pair_sums_xla(a, b, mags, pos, neg):
     return p, n
 
 
+def _pair_sums_step_rows(r1: int) -> int:
+    """Rows of the first operand of a kernel call of ``pair_sums``: both
+    signs stacked where the stack fits the kernel's row limit."""
+    return 2 * r1 if 2 * r1 <= _PALLAS_MAX_R1 else r1
+
+
 def _pair_sums_traced(a, b, mags, pos, neg, interpret: bool):
     """Traceable body of the Pallas route of :func:`pair_sums`: the
     plane's mask goes on the small side, P = A_i & sign & M_k, so ``b``
     reaches the kernel as it is and no ``b & M_k`` is written and read
     back a step; both signs stack into one first operand, so the kernel
-    expands ``b`` once a plane, not twice (two calls a step where the
+    reads ``b`` once a plane, not twice (two calls a step where the
     stack would pass the kernel's row limit)."""
     r1 = a.shape[0]
     firsts = [a & pos[None, :], a & neg[None, :]]
-    if 2 * r1 <= _PALLAS_MAX_R1:
+    if _pair_sums_step_rows(r1) == 2 * r1:
         firsts = [jnp.concatenate(firsts)]
 
     def step(_, mk):

@@ -11,6 +11,9 @@ timeline:
     ops_pallas_dispatch_total{kernel}        successful Pallas dispatches
     ops_pallas_mesh_dispatch_total{kernel}   those of them that ran per
                                              chip under shard_map + psum
+    ops_pallas_body_total{kernel,body}       those of the pair-count family
+                                             by the kernel body their
+                                             operands' heights chose
     ops_pallas_fallback_total{kernel,why}    classic-path fallbacks
 
 Mode selection (``PILOSA_TPU_PALLAS``):
@@ -156,13 +159,18 @@ def mode_token() -> str:
     return "interpret" if use_interpret() else "tpu"
 
 
-def dispatched(kernel: str, on_mesh: bool = False) -> None:
+def dispatched(kernel: str, on_mesh: bool = False,
+               body: Optional[str] = None) -> None:
     """One successful Pallas dispatch; ``on_mesh`` when it was the
     family's per-chip program under ``shard_map`` + ``psum``, so a scrape
-    can tell the two placements apart."""
+    can tell the two placements apart; ``body`` (``vpu`` | ``mxu``) when
+    it ran the pair-count kernel, whose body goes by its operands'
+    heights (``ops/groupby.pallas_body``)."""
     M.REGISTRY.count(M.METRIC_OPS_PALLAS_DISPATCH, kernel=kernel)
     if on_mesh:
         M.REGISTRY.count(M.METRIC_OPS_PALLAS_MESH_DISPATCH, kernel=kernel)
+    if body is not None:
+        M.REGISTRY.count(M.METRIC_OPS_PALLAS_BODY, kernel=kernel, body=body)
 
 
 def fallback(kernel: str, why: str) -> None:

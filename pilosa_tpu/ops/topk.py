@@ -8,10 +8,10 @@ popcount-reduce over the fragment tensor and ``jax.lax.top_k`` ranks on
 device — recounting is cheaper than cache maintenance.
 
 Pallas path: the per-row masked popcount is one row of the groupby
-pair-count matmul — A = the filter plane (or all-ones), B = the row
-planes — so TopN rides the same MXU bit-expand kernel, then ranks the
-resulting count vector on device. The fused XLA reduction stays as the
-bit-identity oracle.
+pair count — A = the filter plane (or all-ones), B = the row planes —
+so TopN rides the same kernel (a one-row first operand takes its VPU
+body, ops/groupby.pallas_body), then ranks the resulting count vector on
+device. The fused XLA reduction stays as the bit-identity oracle.
 """
 
 from __future__ import annotations
@@ -59,7 +59,8 @@ def _pallas_counts(planes, filt):
             with PU.kernel_scope("mm", 1, planes.shape[0], 2,
                                  planes.shape[-1]):
                 counts = _row_counts_pallas(planes, f, PU.use_interpret())
-            PU.dispatched("topn")
+            PU.dispatched("topn",
+                          body=_gb.pallas_body(1, planes.shape[0]))
             return counts
         except Exception as e:
             PU.failed("topn", e)
@@ -70,8 +71,8 @@ def _pallas_counts(planes, filt):
 
 def row_counts(planes, filt=None):
     """Dispatching per-row popcount of a fragment tensor ``uint32[R, W]``
-    (optionally masked by ``filt``): Pallas MXU matmul when eligible,
-    the fused XLA reduction otherwise."""
+    (optionally masked by ``filt``): the Pallas pair count when
+    eligible, the fused XLA reduction otherwise."""
     counts = _pallas_counts(planes, filt)
     if counts is not None:
         return counts

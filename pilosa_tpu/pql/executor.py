@@ -213,6 +213,14 @@ def _group_block(total_words: int) -> int:
     return max(8, min(PAIR_COUNTS_MAX_ROWS, planes_per_block(total_words)))
 
 
+def _tag_route(span, planes, blk) -> None:
+    """Which pair-count program a level's planes take, and which body of
+    the kernel there, on its ``groupby.level`` span."""
+    route, body = pair_counts_route(planes, blk)
+    span.set_tag("route", route)
+    span.set_tag("body", body)
+
+
 def _made(planes):
     """Count group planes just materialised on the device."""
     M.REGISTRY.count(M.METRIC_GROUPBY_GROUP_PLANE_BYTES, planes.nbytes)
@@ -1341,7 +1349,7 @@ class Executor:
                 if r <= 0:
                     break
                 if not blocks and span.recording:
-                    span.set_tag("route", pair_counts_route(planes, blk))
+                    _tag_route(span, planes, blk)
                 rs = min(r, room)
                 gs = max(1, room // rs)
                 for g0 in range(0, g, gs):
@@ -1392,8 +1400,7 @@ class Executor:
                 parts = []
                 for _, blk in st.iter_blocks():
                     if not parts and span.recording:
-                        span.set_tag("route",
-                                     pair_counts_route(group_planes, blk))
+                        _tag_route(span, group_planes, blk)
                     parts.append(np.asarray(pair_counts(group_planes, blk)))
                 counts_matrix = np.concatenate(parts, axis=1)[:, :nb]
                 M.REGISTRY.count(M.METRIC_GROUPBY_HOST_FETCHES)

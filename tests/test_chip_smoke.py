@@ -101,6 +101,9 @@ pilosa_tpu_ops_pallas_dispatch_total{kernel="tape_count"} 12
 pilosa_tpu_ops_pallas_dispatch_total{kernel="topn"} 3
 pilosa_tpu_ops_pallas_dispatch_total{kernel="pair_sums"} 2
 pilosa_tpu_ops_pallas_mesh_dispatch_total{kernel="pair_sums"} 2
+pilosa_tpu_ops_pallas_body_total{kernel="pair_sums",body="vpu"} 2
+pilosa_tpu_ops_pallas_body_total{kernel="topn",body="vpu"} 2
+pilosa_tpu_ops_pallas_body_total{kernel="topn",body="mxu"} 1
 pilosa_tpu_ops_pallas_fallback_total{kernel="bsi_sum",why="mesh"} 3
 pilosa_tpu_ops_pallas_fallback_total{kernel="topn",why="error"} 1
 pilosa_tpu_mesh_sharding_fallback_total 0
@@ -112,12 +115,13 @@ def test_kernel_table_and_dispatch_checks():
     table, mesh_fallback = chip_smoke.kernel_table(METRICS)
     assert mesh_fallback == 0
     assert table["tape_count"] == {"dispatch": 12, "on_mesh": 0,
-                                   "fallback": {}}
-    assert table["bsi_sum"] == {"dispatch": 0, "on_mesh": 0,
+                                   "body": {}, "fallback": {}}
+    assert table["bsi_sum"] == {"dispatch": 0, "on_mesh": 0, "body": {},
                                 "fallback": {"mesh": 3}}
     # a mesh dispatch counts in both series, once each
     assert table["pair_sums"] == {"dispatch": 2, "on_mesh": 2,
-                                  "fallback": {}}
+                                  "body": {"vpu": 2}, "fallback": {}}
+    assert table["topn"]["body"] == {"vpu": 2, "mxu": 1}
     with pytest.raises(SystemExit, match="topn fell back with why='error'"):
         chip_smoke.check_kernels(table, 0, (), "log")
     del table["topn"]["fallback"]["error"]
@@ -128,6 +132,24 @@ def test_kernel_table_and_dispatch_checks():
             table, 0, ("tape_count", "bsi_sum", "pair_counts"), "log")
     with pytest.raises(SystemExit, match="mesh_sharding_fallback_total"):
         chip_smoke.check_kernels(table, 2, (), "log")
+
+
+def test_every_pair_counts_dispatch_must_name_its_body():
+    series = "pilosa_tpu_ops_pallas_{}_total{{kernel=\"pair_counts\"{}}} {}\n"
+    text = (series.format("dispatch", "", 5)
+            + series.format("body", ',body="vpu"', 3))
+    table, _ = chip_smoke.kernel_table(text)
+    assert table["pair_counts"]["body"] == {"vpu": 3}
+    with pytest.raises(SystemExit, match=r"kernel body check: 5 dispatches "
+                                         r"of pair_counts took .*'vpu': 3"):
+        chip_smoke.check_kernels(table, 0, ("pair_counts",), "log")
+    table, _ = chip_smoke.kernel_table(
+        text + series.format("body", ',body="mxu"', 2))
+    chip_smoke.check_kernels(table, 0, ("pair_counts",), "log")
+    # a program that lacks the counter names no body at all
+    table, _ = chip_smoke.kernel_table(series.format("dispatch", "", 5))
+    with pytest.raises(SystemExit, match="kernel body check"):
+        chip_smoke.check_kernels(table, 0, ("pair_counts",), "log")
 
 
 def test_mesh_kernels_must_dispatch_on_the_mesh():

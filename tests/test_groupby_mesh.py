@@ -79,6 +79,10 @@ SHAPES = [
     (1, 1, 1, None),       # one word a device
     (128, 8, 16, None),    # the kernel's row limit
     (7, 100, 520, 2),      # one device holds no bit at all
+    # the shapes above all take the kernel's VPU body; so does a taxi Q4
+    # call, here with a local width of two of its word blocks ...
+    (24, 16, 2 * 2048 + 512, None),
+    (64, 128, 512, None),  # ... and two tall sides take the MXU body
 ]
 
 
@@ -242,6 +246,38 @@ def test_engine_placed_operands_take_the_mesh_route(rng, mesh4, pallas_as_compil
     one = [jax.device_put(x, jax.devices()[0]) for x in ops]
     _call(kernel, one)
     assert _ticks(kernel) == (d0 + 2, m0 + 1, f0, e0)
+
+
+@pytest.mark.parametrize("r1,r2,lw,body", [
+    (24, 16, 2048, "vpu"),   # a taxi Q4 call: 16-row blocks, 24 planes
+    (1, 80, 600, "vpu"),
+    (64, 128, 512, "mxu"),
+])
+def test_the_mesh_route_equals_one_device_under_either_body(
+        rng, mesh4, pallas_as_compiled, r1, r2, lw, body):
+    """The body goes by the heights, and a chip sees the heights the
+    whole operands have: the mesh program takes the body the one-chip
+    program takes, ticks it once, and both give the same integers."""
+    a, b = operands(rng, r1, r2, 1, lw)[:2]
+    assert G.pallas_body(r1, r2) == body
+    route = G.pair_counts_route(PM.engine_put(a), PM.engine_put(b))
+    assert route == ("mesh", body)
+    assert G.pair_counts_route(a, b) == ("pallas", body)
+
+    def bodies():
+        return {k: value(M.METRIC_OPS_PALLAS_BODY, kernel="pair_counts",
+                         body=k) for k in ("vpu", "mxu")}
+
+    before, m0 = bodies(), _ticks("pair_counts")[1]
+    on_mesh = np.asarray(G.pair_counts(PM.engine_put(a), PM.engine_put(b)))
+    assert _ticks("pair_counts")[1] == m0 + 1
+    one = np.asarray(G.pair_counts(jax.device_put(a, jax.devices()[0]),
+                                   jax.device_put(b, jax.devices()[0])))
+    assert _ticks("pair_counts")[1] == m0 + 1
+    before[body] += 2
+    assert bodies() == before
+    np.testing.assert_array_equal(on_mesh, one)
+    np.testing.assert_array_equal(one, pair_counts_numpy(a, b))
 
 
 @pytest.mark.parametrize("kernel,case", [

@@ -270,6 +270,9 @@ def test_the_fold_fetches_per_level_and_says_what_lived(monkeypatch):
     assert [s["tags"]["level"] for s in spans] == [1, 2]
     assert [s["tags"]["groups_in"] for s in spans] == [4, 16]
     assert [s["tags"]["groups_live"] for s in spans] == [16, 64]
+    # no kernel on this backend: the scan, which has no body to name
+    assert [(s["tags"]["route"], s["tags"]["body"]) for s in spans] == [
+        ("xla", "none")] * 2
     assert M.REGISTRY.value(M.METRIC_GROUPBY_HOST_FETCHES) == fetch0 + 2
     # the sixteen live pairs, gathered and then ANDed
     assert (M.REGISTRY.value(M.METRIC_GROUPBY_GROUP_PLANE_BYTES) - made0

@@ -299,6 +299,8 @@ def test_a_groupby_level_says_which_route_its_counts_took(served):
     levels = spans_of(served[0], "GroupBy(Rows(p), Rows(y), Rows(d))",
                       "groupby.level")
     assert levels and all(s["tags"]["route"] == "mesh" for s in levels)
+    # ... and which body of the kernel its heights chose there
+    assert all(s["tags"]["body"] == "vpu" for s in levels)
 
 
 def test_a_stack_build_says_where_its_bytes_went(mesh, monkeypatch):

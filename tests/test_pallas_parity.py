@@ -83,6 +83,222 @@ def test_pair_counts_all_set_and_empty(rng, forced):
         np.asarray(G.pair_counts(ones, zeros)), np.zeros((4, 4), np.int32))
 
 
+# -- the kernel's two bodies: one answer, whichever the heights choose ------
+
+
+def body_count(kernel, body):
+    return M.REGISTRY.value(M.METRIC_OPS_PALLAS_BODY, kernel=kernel,
+                            body=body)
+
+
+@pytest.mark.parametrize("r1,r2,body", [
+    (24, 16, "vpu"),     # a taxi Q4 call
+    (8, 16, "vpu"),
+    (1, 80, "vpu"),      # TopN: one filter row
+    (1, 2, "vpu"),
+    (2, 14, "vpu"),      # a BSI Sum: two sign classes x (depth + 1)
+    (16, 256, "vpu"),    # a pair_sums step of groupby-closed
+    (8, 1000, "vpu"),    # the paged 1000-row stack
+    (8, 512, "vpu"),
+    (31, 256, "vpu"),    # just under the line ...
+    (48, 64, "vpu"),
+    (56, 56, "mxu"),     # ... on it ...
+    (32, 256, "mxu"),    # ... within a tenth either way: stays
+    (64, 64, "mxu"),
+    (64, 256, "mxu"),
+    (128, 128, "mxu"),
+    (128, 256, "mxu"),   # two tall sides
+])
+def test_pallas_body_is_a_pure_function_of_the_two_heights(r1, r2, body):
+    assert G.pallas_body(r1, r2) == body
+    assert G.pallas_body(r2, r1) == body  # AND commutes; so does the rule
+    import inspect
+    assert list(inspect.signature(G.pallas_body).parameters) == ["r1", "r2"]
+
+
+#: both sides of the rule, and every way the VPU body walks its rows: one
+#: group of ``b`` and one block of ``a``, several groups (256 rows), a
+#: ragged last group that overlaps the one before (232, 90, 1000 -> 232),
+#: more rows of ``a`` than a loop step takes, with and without a rest
+PARITY_SHAPES = [
+    (1, 80), (2, 14), (8, 16), (24, 16), (16, 256), (25, 232), (31, 256),
+    (8, 1000), (100, 17), (128, 24), (40, 90), (32, 150), (128, 256)]
+
+
+def assert_bodies_equal_the_scan(a, b):
+    """The body the rule picks and the MXU body both equal the XLA scan."""
+    import jax
+
+    want = np.asarray(G._pair_counts_xla(a, b))
+    for body in (G._pair_counts_traced, G._pair_counts_mxu):
+        got = jax.jit(lambda x, y: body(x, y, True))(a, b)
+        assert got.shape == want.shape and got.dtype == np.int32
+        np.testing.assert_array_equal(np.asarray(got), want)
+    return want
+
+
+@pytest.mark.parametrize("w", [1, 7, 512, 2055, 8192])
+@pytest.mark.parametrize("r1,r2", PARITY_SHAPES)
+def test_pair_counts_bodies_parity(rng, r1, r2, w):
+    """Both sides of the rule at every width class (a word, nothing
+    aligned, one block, a padded odd width, the widest the interpreter
+    takes), an all-ones and an empty plane on each side."""
+    a, b = rand_planes(rng, r1, w), rand_planes(rng, r2, w)
+    a[0] = 0xFFFFFFFF
+    b[0], b[-1] = 0xFFFFFFFF, 0
+    if r1 > 1:
+        a[-1] = 0
+    want = assert_bodies_equal_the_scan(a, b)
+    assert want[0, 0] == 32 * w and not want[:, -1].any()
+
+
+@pytest.mark.parametrize("r1,r2", [(24, 16), (25, 232), (100, 17)])
+def test_pair_counts_bodies_parity_at_a_shard(rng, r1, r2):
+    """One whole shard, 32,768 words: several grid steps of the VPU
+    body's widest word block, all-ones planes to the accumulators' top."""
+    a, b = rand_planes(rng, r1, 32768), rand_planes(rng, r2, 32768)
+    a[0] = b[0] = b[r2 // 2] = 0xFFFFFFFF
+    want = assert_bodies_equal_the_scan(a, b)
+    assert want[0, 0] == want[0, r2 // 2] == 1 << 20
+
+
+def kernel_listing(r1, r2, words=1024):
+    """Equations of the kernel's jaxpr, its loops' bodies included: what
+    is traced, lowered and serialized once a shape and a process."""
+    import jax
+    from jax.extend import core as jcore
+
+    def count(jaxpr):
+        n = len(jaxpr.eqns)
+        for eqn in jaxpr.eqns:
+            for v in eqn.params.values():
+                for j in v if isinstance(v, (tuple, list)) else (v,):
+                    if isinstance(j, jcore.ClosedJaxpr):
+                        j = j.jaxpr
+                    if isinstance(j, jcore.Jaxpr):
+                        n += count(j)
+        return n
+
+    spec = [jax.ShapeDtypeStruct((r, words), np.uint32) for r in (r1, r2)]
+    closed = jax.make_jaxpr(
+        lambda a, b: G._pair_counts_traced(a, b, True))(*spec)
+    call, = [e for e in closed.jaxpr.eqns
+             if e.primitive.name == "pallas_call"]
+    return count(call.params["jaxpr"])
+
+
+#: the most equations a VPU-body kernel may list, whatever its heights
+#: (the MXU body lists ~120). A listing is paid once a shape and a
+#: process before the compile cache can be asked, on the benchmark's
+#: host 2-6 ms an AND + popcount + add inside the server: PR 34's
+#: unrolled body (3,000 equations at 31 x 256, 2,400 at 100 x 17) added
+#: 13 s to groupby-closed's warm-up of 8 (PERF.md section 5)
+MAX_LISTING = 500
+
+
+@pytest.mark.parametrize("r1,r2", [
+    (8, 16), (24, 16), (24, 24), (16, 256), (25, 232), (31, 256), (8, 1000),
+    (100, 17), (128, 24), (127, 30), (47, 40), (55, 56), (27, 1000)])
+def test_the_vpu_body_lists_a_bounded_program(r1, r2):
+    assert G.pallas_body(r1, r2) == "vpu"
+    assert kernel_listing(r1, r2) <= MAX_LISTING
+
+
+def test_the_vpu_body_does_not_unroll_over_rows():
+    """The next edit that unrolls over the rows of either operand fails
+    here, not in a chip check of set-up seconds. Four times the rows of
+    the first operand and sixteen times those of the second list about
+    twice the program (the rows of one loop step and of a rest); more
+    tiles, more words or a taller first operand list nothing more; and
+    the kernel lists the rows of the shorter operand, whichever it is."""
+    small = kernel_listing(8, 16)
+    assert kernel_listing(31, 256) <= 2.2 * small
+    assert kernel_listing(16, 256) <= 1.3 * small
+    assert kernel_listing(8, 256) <= 1.2 * small
+    assert kernel_listing(8, 1000) == kernel_listing(8, 256)
+    assert kernel_listing(16, 256, 8192) == kernel_listing(16, 256)
+    assert kernel_listing(47, 60) == kernel_listing(31, 60)
+    assert kernel_listing(100, 17) == kernel_listing(17, 100)
+    assert kernel_listing(128, 8) == kernel_listing(8, 128) <= 1.2 * small
+
+
+def test_vpu_body_takes_the_widest_block_that_divides_and_fits():
+    # a shard is 32,768 words: whole-shard operands never pad past 512
+    assert G._vpu_block_words(24, 16, 66 * 32768) == G._VPU_MAX_BW
+    assert G._vpu_block_words(24, 16, 5 * 512) == 512
+    assert G._vpu_block_words(24, 16, 12 * 512) == 2048
+    # double-buffered inputs stay under the byte budget
+    for r1, tr2 in [(1, 80), (16, 256), (31, 256), (128, 32)]:
+        bw = G._vpu_block_words(r1, tr2, 1 << 20)
+        assert 512 <= bw <= G._VPU_MAX_BW and (1 << 20) % bw == 0
+        assert 2 * 4 * (r1 + tr2) * bw <= G._VPU_INPUT_BYTES
+
+
+@pytest.mark.parametrize("r1,r2,body", [(24, 16, "vpu"), (128, 256, "mxu")])
+def test_pair_counts_dispatch_names_its_body(rng, forced, r1, r2, body):
+    a, b = rand_planes(rng, r1), rand_planes(rng, r2)
+    other = "mxu" if body == "vpu" else "vpu"
+    before = (dispatch_count("pair_counts"),
+              body_count("pair_counts", body),
+              body_count("pair_counts", other))
+    got = np.asarray(G.pair_counts(a, b))
+    assert (dispatch_count("pair_counts"), body_count("pair_counts", body),
+            body_count("pair_counts", other)) == (
+                before[0] + 1, before[1] + 1, before[2])
+    np.testing.assert_array_equal(got, np.asarray(G._pair_counts_xla(a, b)))
+
+
+def test_a_fallback_names_no_body(rng, killed):
+    before = sum(body_count("pair_counts", b) for b in ("vpu", "mxu"))
+    G.pair_counts(rand_planes(rng, 8), rand_planes(rng, 16))
+    assert sum(body_count("pair_counts", b)
+               for b in ("vpu", "mxu")) == before
+
+
+def test_the_family_dispatches_name_their_bodies(rng, forced):
+    """``pair_sums`` (both signs stacked: 2 * r1 rows a step), TopN (one
+    filter row) and the BSI Sum (two sign classes) reach the kernel
+    through ``_pair_counts_traced`` and tick the body its rule picks
+    for the shapes they send, once a dispatch."""
+    before = {k: (dispatch_count(k), body_count(k, "vpu"),
+                  body_count(k, "mxu"))
+              for k in ("pair_sums", "topn", "bsi_sum")}
+    ops = sum_operands(rng, 8, 256, 3, WORDS)
+    got = G.pair_sums(*ops)
+    for g, n in zip(got, pair_sums_numpy(*ops)):
+        np.testing.assert_array_equal(np.asarray(g), n)
+    planes, filt = rand_planes(rng, 80), rand_planes(rng, 1)[0]
+    np.testing.assert_array_equal(
+        np.asarray(T.row_counts(planes, filt)),
+        np.asarray(B.row_counts(planes, filt)))
+    np.testing.assert_array_equal(
+        np.asarray(T._row_counts_pallas(planes, filt, True)),
+        np.asarray(B.row_counts(planes, filt)))
+    cols, vals, bsi = encode(rng)
+    assert S.bsi_sum(bsi, bsi[S.EXISTS]) == (int(vals.sum()), cols.size)
+    for g, x in zip(S._plane_popcounts_pallas(bsi, bsi[S.EXISTS], True),
+                    S._plane_popcounts_xla(bsi, bsi[S.EXISTS])):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(x))
+    assert G.pallas_body(16, 256) == G.pallas_body(1, 80) == "vpu"
+    for k, (d, vpu, mxu) in before.items():
+        assert (dispatch_count(k), body_count(k, "vpu"),
+                body_count(k, "mxu")) == (d + 1, vpu + 1, mxu)
+
+
+def test_pair_sums_past_the_stacking_limit_names_the_body_of_its_calls(
+        rng, forced):
+    # 2 * 70 > 128: two calls of 70 x 140 rows a step, the MXU body's
+    assert G._pair_sums_step_rows(70) == 70
+    assert G._pair_sums_step_rows(64) == 128
+    assert G.pallas_body(70, 140) == "mxu"
+    ops = sum_operands(rng, 70, 140, 2, 40)
+    before = body_count("pair_sums", "mxu")
+    got = G.pair_sums(*ops)
+    assert body_count("pair_sums", "mxu") == before + 1
+    for g, n in zip(got, pair_sums_numpy(*ops)):
+        np.testing.assert_array_equal(np.asarray(g), n)
+
+
 # ---------------------------------------------------------------------------
 # pair_sums (two-field GroupBy with a Sum)
 # ---------------------------------------------------------------------------
