@@ -10,6 +10,13 @@ back, and ``/metrics`` must show every expected Pallas kernel dispatched
 with no error fallback. Any failed check, non-2xx answer, dead server or
 raised phase exits non-zero and prints no result line.
 
+Then, with the server gone and the chip free, one child process checks
+the key-plane form of a mutex stack too tall for its device budget
+(``core/stacked.KeyedSet``): a small stack under a tiny budget, its rows
+derived by the compiled ``_key_rows_pallas`` equal to the interpreter's,
+its served answers equal to numpy, and the kernel's dispatch counter
+ticked (on a mesh: the XLA twin, counted ``why="mesh"``).
+
 The last two lines of standard output are JSON objects: first the smoke
 report (per-query cold/warm ms, load rows/s, the kernel table, device
 bytes, ``native``, versions, compile cache), then the verdict, exactly
@@ -314,6 +321,86 @@ def cache_entries(cache_dir):
     return len(os.listdir(cache_dir))
 
 
+#: the key-rows step: one shard of a 300-row mutex field, on one chip in
+#: 32-row blocks (SF-10's height), 40 MB dense against a budget of 4 MB
+#: (both limits are a device's: on a mesh the blocks are taller)
+KEY_ROWS_ENV = {"PILOSA_TPU_DEVICE_BUDGET": str(4 << 20),
+                "PILOSA_TPU_BLOCK_BYTES_MB": "8"}
+KEY_ROWS = 300
+
+
+def key_rows_child(seed):
+    """The key-rows step's own process (it imports JAX and takes the
+    chip): prints one JSON line of what it found."""
+    import jax
+
+    from pilosa_tpu.core import FieldOptions, FieldType, Holder
+    from pilosa_tpu.core import stacked as stx
+    from pilosa_tpu.obs import metrics as M
+    from pilosa_tpu.ops import keyrows as K
+    from pilosa_tpu.pql import Executor
+
+    rng = np.random.default_rng(seed)
+    cols = rng.choice(SHARD_WIDTH, 200_000, replace=False)
+    rows = rng.integers(0, KEY_ROWS, cols.size)
+    holder = Holder()
+    field = holder.create_index("k").create_field(
+        "m", FieldOptions(type=FieldType.MUTEX))
+    field.import_bits(rows.tolist(), cols.tolist())
+    ex = Executor(holder)
+    st = stx.stacked_set(field, [0], "standard")
+    want = np.bincount(rows, minlength=KEY_ROWS)
+    top = ex.execute("k", f"TopN(m, n={KEY_ROWS})")[0]
+    served = ({p.id: p.count for p in top.pairs}
+              == {r: int(n) for r, n in enumerate(want) if n}
+              and ex.execute("k", "Count(Row(m=7))")[0] == int(want[7]))
+    out = {"form": type(st).__name__, "block_rows": st.block_rows,
+           "bits": st.bits if isinstance(st, stx.KeyedSet) else None,
+           "served_equal": bool(served)}
+    if jax.devices()[0].platform == "tpu" and isinstance(st, stx.KeyedSet):
+        keys = jax.device_put(st._ensure_keys(), jax.devices()[0])
+        first = jax.device_put(np.array([st.block_rows], dtype=np.int32),
+                               jax.devices()[0])
+        compiled, interpreted = (np.asarray(K._key_rows_pallas(
+            keys, first, st.block_rows, st.bits, interpret))
+            for interpret in (False, True))
+        out["compiled_equals_interpret"] = bool(
+            np.array_equal(compiled, interpreted))
+    snap = M.REGISTRY.snapshot()["counters"]
+    out["key_rows"] = {k: v for k, v in snap.items()
+                       if 'kernel="key_rows"' in k}
+    print(json.dumps(out))
+
+
+def key_rows_step(args, on_chip, mesh):
+    """Run the key-rows child and check what it found: the key form, the
+    served answers, and on a chip the compiled kernel against the
+    interpreter and its dispatch (or, on a mesh, its ``why="mesh"``
+    fallback)."""
+    proc = subprocess.run(
+        [sys.executable, os.path.abspath(__file__), "--key-rows-child",
+         "--seed", str(args.seed)],
+        cwd=HERE, env=dict(os.environ, **KEY_ROWS_ENV),
+        capture_output=True, text=True, timeout=600)
+    if proc.returncode != 0:
+        fail(f"key-rows step exited rc={proc.returncode}: "
+             f"{proc.stderr[-2000:]}")
+    found = json.loads(proc.stdout.splitlines()[-1])
+    print(f"key rows: {json.dumps(found, sort_keys=True)}")
+    if found["form"] != "KeyedSet" or not found["served_equal"]:
+        fail(f"key-rows step: {found}")
+    if on_chip:
+        table, _ = kernel_table("\n".join(
+            f"{k} {v}" for k, v in found["key_rows"].items()))
+        row = table.get("key_rows", {"dispatch": 0, "fallback": {}})
+        ticked = (row["fallback"].get("mesh") if mesh
+                  else row["dispatch"])
+        if not found.get("compiled_equals_interpret") or not ticked \
+                or row["fallback"].get("error"):
+            fail(f"key-rows step: compiled kernel or its counter: {found}")
+    return found
+
+
 def main():
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--seed", type=int, default=0)
@@ -322,7 +409,12 @@ def main():
     ap.add_argument("--allow-cpu", action="store_true",
                     help="CPU dry run of the control flow: skips the "
                          "device and kernel-dispatch assertions")
+    ap.add_argument("--key-rows-child", action="store_true",
+                    help=argparse.SUPPRESS)
     args = ap.parse_args()
+    if args.key_rows_child:
+        key_rows_child(args.seed)
+        return 0
 
     os.makedirs(OUT_DIR, exist_ok=True)
     data_dir = tempfile.mkdtemp(prefix="data-", dir=OUT_DIR)
@@ -352,6 +444,9 @@ def main():
             proc.kill()
             proc.wait()
         shutil.rmtree(data_dir, ignore_errors=True)
+    on_chip = report["device_checks"] == "done"
+    report["key_rows"] = key_rows_step(
+        args, on_chip, report["device"]["count"] > 1)
     print(json.dumps(report))
     # the verdict: these keys and no others, on the last line
     print(json.dumps({"ok": True, "device": report["device"]}))

@@ -48,7 +48,7 @@ import math
 import os
 import threading
 import time
-from collections import OrderedDict
+from collections import OrderedDict, deque
 from typing import Dict, Iterator, List, Optional, Sequence, Tuple
 
 import jax
@@ -60,6 +60,7 @@ from pilosa_tpu.obs import metrics as M
 from pilosa_tpu.ops import bitmap as bitops
 from pilosa_tpu.ops import bsi as bsiops
 from pilosa_tpu.ops import ctiles
+from pilosa_tpu.ops import keyrows
 from pilosa_tpu.shardwidth import WORDS_PER_SHARD
 
 _MIN_SLOTS = 8
@@ -121,23 +122,27 @@ def sync_part(arr):
     return arr
 
 
-def _sent(host: np.ndarray, kind: str, span):
+def _sent(host: np.ndarray, kind: str, span, compress: bool = True):
     """The resident form of a block just assembled on the host, sent to
     the devices inside its ``stack.build`` span: compressed tiles where
-    the policy says so, else dense on the engine device mesh — the fused
+    the policy says so (never for key planes, ``compress`` False), else
+    dense on the engine device mesh — the fused
     (shard, word) last axis splits across all mesh devices, so the jitted
     query kernels execute SPMD with XLA-inserted collective reduces
     (parallel/mesh.py engine mesh; the reference's shard->node scatter +
     HTTP reduce, executor.go:6449, becomes shard->device + psum)."""
     from pilosa_tpu.parallel.mesh import engine_put
 
-    blk = ctiles.maybe_compress(host, kind=kind)
+    blk = ctiles.maybe_compress(host, kind=kind) if compress else None
     if blk is None:
         blk = engine_put(host)
     UPLOAD_STATS["count"] += 1
     UPLOAD_STATS["bytes"] += blk.nbytes
     M.REGISTRY.count(M.METRIC_STACK_BUILD_BYTES, blk.nbytes)
     if span.recording:
+        span.set_tag("form", "keys" if not compress else
+                     "compressed" if isinstance(blk, ctiles.CompressedBlock)
+                     else "dense")
         held = device_bytes(blk)
         span.set_tag("devices", len(held))
         span.set_tag("bytes_per_device", max(held.values()))
@@ -342,6 +347,32 @@ def _decode_whole(blk: ctiles.CompressedBlock, kind: str):
 _stack_serial = itertools.count()
 
 
+def _slot_layout(fragments, total_words: int):
+    """(row ids, block rows, slot capacity) of a set stack over
+    ``fragments``: slots are the union of their row ids in order, in
+    power-of-two row blocks of at most ``_BLOCK_BYTES`` a device."""
+    rows: set = set()
+    for frag in fragments:
+        if frag is not None:
+            rows.update(frag.row_index)
+    row_ids = sorted(rows)
+    per_block = max(_MIN_SLOTS, planes_per_block(total_words))
+    block_rows = min(_pow2(len(row_ids)), _pow2(per_block) // 2 or _MIN_SLOTS)
+    if block_rows * _row_bytes(total_words) > _BLOCK_BYTES:
+        block_rows = max(_MIN_SLOTS, block_rows // 2)
+    cap = max(block_rows, -(-len(row_ids) // block_rows) * block_rows)
+    return row_ids, block_rows, cap
+
+
+def keyed_form(mutex: bool, cap: int, total_words: int) -> bool:
+    """The rule, with no knob: a mutex (or bool) stack takes the
+    key-plane form (:class:`KeyedSet`) when its dense bytes on its
+    fullest device exceed the budget's cap. Such a stack can never be
+    resident whole, so an LRU walk of it would miss every block and
+    rebuild each from the host on every walk."""
+    return mutex and cap * _row_bytes(total_words) > BUDGET.cap
+
+
 class StackedSet:
     """Union-row view of set fragments: ``uint32[cap, S*W]`` in row blocks.
 
@@ -351,7 +382,8 @@ class StackedSet:
     """
 
     def __init__(self, shards: Sequence[int], fragments,
-                 words: int = WORDS_PER_SHARD, write_lock=None):
+                 words: int = WORDS_PER_SHARD, write_lock=None,
+                 layout=None):
         self.shards = tuple(shards)
         self.words = words
         self.total_words = len(self.shards) * words
@@ -361,21 +393,10 @@ class StackedSet:
         # the eager build path)
         self._write_lock = (write_lock if write_lock is not None
                             else contextlib.nullcontext())
-        rows: set = set()
-        for frag in fragments:
-            if frag is not None:
-                rows.update(frag.row_index)
-        self.row_ids: List[int] = sorted(rows)
-        self.row_index: Dict[int, int] = {r: i for i, r in enumerate(self.row_ids)}
-        row_bytes = _row_bytes(self.total_words)
-        per_block = max(_MIN_SLOTS, planes_per_block(self.total_words))
-        self.block_rows = min(_pow2(len(self.row_ids)),
-                              _pow2(per_block) // 2 or _MIN_SLOTS)
-        if self.block_rows * row_bytes > _BLOCK_BYTES:
-            self.block_rows = max(_MIN_SLOTS, self.block_rows // 2)
-        self.cap = max(self.block_rows,
-                       -(-len(self.row_ids) // self.block_rows)
-                       * self.block_rows)
+        self.row_ids, self.block_rows, self.cap = (
+            layout or _slot_layout(fragments, self.total_words))
+        self.row_index: Dict[int, int] = {
+            r: i for i, r in enumerate(self.row_ids)}
         self.paged = self.cap > self.block_rows
         # snapshot context for lazy builds + advance
         self._fragments = list(fragments)
@@ -392,6 +413,9 @@ class StackedSet:
         # block index -> (compressed block, its dense words): what a
         # whole walk decoded and the budget had room to keep
         self._walked: Dict[int, Tuple[ctiles.CompressedBlock, jax.Array]] = {}
+        self._materialize()
+
+    def _materialize(self) -> None:
         if not self.paged:
             # unpaged stacks are resident (pinned until LRU-evicted)
             # and charged like any block, so BUDGET is the complete
@@ -451,11 +475,7 @@ class StackedSet:
             blk = self._blocks[bi]
             if blk is not None:
                 return blk
-            for frag, built_v in zip(self._fragments, self._built_vers):
-                if (frag.version if frag is not None else -1) != built_v:
-                    PAGING_STATS["stale_retries"] += 1
-                    raise StackStale(
-                        "fragment advanced past the stack snapshot")
+            _check_snapshot(self)
             blk = self._build_block_host(bi)
             self._blocks[bi] = blk
         if not self.ephemeral:
@@ -593,6 +613,198 @@ class StackedSet:
         return parts[0] if len(parts) == 1 else jnp.concatenate(parts)
 
 
+def _check_snapshot(stack) -> None:
+    """Raise :class:`StackStale` when a member fragment moved past the
+    version ``stack`` was built at (a lazy rebuild must never serve a
+    torn or newer state than the read's snapshot). Caller holds the
+    writer lock."""
+    for frag, built_v in zip(stack._fragments, stack._built_vers):
+        if (frag.version if frag is not None else -1) != built_v:
+            PAGING_STATS["stale_retries"] += 1
+            raise StackStale("fragment advanced past the stack snapshot")
+
+
+class KeyedSet(StackedSet):
+    """A mutex stack too tall for the budget, held as key planes
+    (``ops/keyrows.py``): ``uint32[k_pad, S*W]``, where K_i holds bit i of
+    each record's slot + 1, one budget entry of ``k_pad`` planes in the
+    place of ``cap`` (SSB SF-10's ``p_brand1``: 16 planes, 122 MB, where
+    the dense stack is 7.8 GB). Rows are derived on the device, never
+    built on the host or sent: a block for a walk by the Pallas kernel,
+    kept and charged like a built block where the budget has room (else
+    derived on every walk), the few rows of a point read on XLA. What a
+    consumer sees (``iter_blocks``, ``row_plane``, ``take_rows``,
+    ``rows_plane``, ``row_counts``, ``block_rows``, ``cap``) has exactly
+    the dense form's shapes and bits."""
+
+    #: budget key of the key planes beside the blocks' (serial, bi)
+    _KEYS = -1
+    #: derived blocks a walk lets run ahead of the device
+    _AHEAD = 2
+
+    def _materialize(self) -> None:
+        self.paged = True
+        self.bits = keyrows.key_bits(self.cap)
+        self._keys: Optional[jax.Array] = self._build_keys_host()
+        self._charge_keys()
+
+    def _build_keys_host(self) -> jax.Array:
+        """OR each fragment row's plane into K_i for every set bit i of
+        its slot + 1, under the writer lock like a block build. Two rows
+        of one record (the mutex invariant broken) raise: a key can hold
+        one row, and folding two would answer wrong silently."""
+        from pilosa_tpu.obs.tracing import get_tracer
+
+        k_pad = keyrows.padded_planes(self.bits)
+        with get_tracer().start_span(
+                "stack.build", planes=k_pad, rows=len(self.row_ids),
+                words=self.total_words) as span:
+            host = np.zeros((k_pad, self.total_words), dtype=np.uint32)
+            seen = np.empty(self.words, dtype=np.uint32)
+            both = np.empty(self.words, dtype=np.uint32)
+            for si, frag in enumerate(self._fragments):
+                if frag is None:
+                    continue
+                lo = si * self.words
+                keys = host[:, lo:lo + self.words]
+                seen[:] = 0
+                for fslot, row in enumerate(frag.row_ids):
+                    plane = frag.planes[fslot]
+                    if np.bitwise_and(seen, plane, out=both).any():
+                        raise ValueError(
+                            f"mutex stack: a record of shard "
+                            f"{self.shards[si]} is in two rows (row {row} "
+                            f"and another); key planes hold one")
+                    np.bitwise_or(seen, plane, out=seen)
+                    v = self.row_index[row] + 1
+                    for i in range(self.bits):
+                        if v >> i & 1:
+                            np.bitwise_or(keys[i], plane, out=keys[i])
+            PAGING_STATS["block_builds"] += 1
+            return _sent(host, "set", span, compress=False)
+
+    def _charge_keys(self) -> None:
+        keys = self._keys
+        if keys is not None and not self.ephemeral:
+            BUDGET.charge((self.serial, self._KEYS), device_bytes(keys),
+                          lambda s=self: s._drop_keys())
+
+    def _drop_keys(self) -> None:
+        # eviction callback: derived blocks stay valid (they are of this
+        # snapshot); the next derivation rebuilds the planes
+        self._keys = None
+
+    def _ensure_keys(self) -> jax.Array:
+        keys = self._keys
+        if keys is not None:
+            BUDGET.touch((self.serial, self._KEYS))
+            return keys
+        with writer_wait(self._write_lock), self._lock:
+            keys = self._keys
+            if keys is not None:
+                return keys
+            _check_snapshot(self)
+            keys = self._keys = self._build_keys_host()
+        self._charge_keys()
+        return keys
+
+    def release_device(self) -> None:
+        super().release_device()
+        BUDGET.release((self.serial, self._KEYS))
+
+    @contextlib.contextmanager
+    def _deriving(self, kind: str, rows: int, **tags):
+        """Yield the key planes to derive ``rows`` rows from, inside the
+        ``stack.derive`` span and profiler leaf; ticks the counters."""
+        from pilosa_tpu.obs.tracing import annotate, get_tracer
+
+        keys = self._ensure_keys()
+        nbytes = rows * _row_bytes(self.total_words)
+        M.REGISTRY.count(M.METRIC_STACK_KEY_ROWS, kind=kind)
+        M.REGISTRY.count(M.METRIC_STACK_KEY_ROWS_BYTES, nbytes)
+        with get_tracer().start_span(
+                "stack.derive", rows=rows, key_planes=keys.shape[0],
+                bytes=nbytes, **tags), annotate("stack.derive"):
+            yield keys
+
+    def _block_dense(self, bi: int) -> jax.Array:
+        """Block ``bi``: kept, or derived from the key planes (and kept
+        and charged like a built block where the budget has room for it
+        without evicting anything)."""
+        blk = self._blocks[bi]
+        if blk is not None:
+            BUDGET.touch((self.serial, bi))
+            return blk
+        with self._deriving("block", self.block_rows, block=bi) as keys:
+            blk = keyrows.key_rows(keys, bi * self.block_rows,
+                                   self.block_rows, self.bits)
+        held = device_bytes(blk)
+        if not self.ephemeral and BUDGET.room() >= max(held.values()):
+            with self._lock:
+                keep = self._blocks[bi] is None
+                if keep:
+                    self._blocks[bi] = blk
+            if keep:
+                BUDGET.charge((self.serial, bi), held,
+                              lambda s=self, i=bi: s._drop_block(i))
+        return blk
+
+    _ensure_block = _block_dense
+
+    def iter_blocks(self) -> Iterator[Tuple[int, jax.Array]]:
+        """The dense form's walk, held to :data:`_AHEAD` blocks ahead of
+        the device. A derived block the budget has no room for is charged
+        to nothing, and dispatch is asynchronous: unheld, only timing
+        bounds how many of a walk's blocks are on the device at once (SSB
+        SF-10: up to 32 of 243 MB a walk of ``p_brand1``). So before it hands
+        out a block, the walk waits until the one ``_AHEAD`` places back
+        is derived; the device runs programs in order, so the consumers
+        of the blocks before that one have run and their blocks are
+        free."""
+        ahead: deque = deque()
+        for lo, blk in super().iter_blocks():
+            ahead.append(blk)
+            if len(ahead) > self._AHEAD:
+                jax.block_until_ready(ahead.popleft())
+            yield lo, blk
+
+    def row_counts(self, filt: Optional[jax.Array] = None) -> jax.Array:
+        from pilosa_tpu.ops import topk as topkops
+
+        parts = [sync_part(topkops.row_counts(blk, filt))
+                 for _, blk in self.iter_blocks()]
+        return parts[0] if len(parts) == 1 else jnp.concatenate(parts)
+
+    def _rows_at(self, slots: Sequence[int]) -> jax.Array:
+        with self._deriving("rows", len(slots)) as keys:
+            return keyrows.key_rows_at(keys, slots)
+
+    def row_plane(self, row: int) -> jax.Array:
+        slot = self.row_index.get(row)
+        if slot is None:
+            return self.zero_plane()
+        return self._rows_at([slot])[0]
+
+    def take_rows(self, rows: Sequence[int]) -> jax.Array:
+        slots = [self.row_index.get(r) for r in rows]
+        present = [s for s in slots if s is not None]
+        if not present:
+            return jnp.zeros((len(rows), self.total_words), dtype=jnp.uint32)
+        out = self._rows_at([0 if s is None else s for s in slots])
+        if len(present) < len(slots):
+            absent = [i for i, s in enumerate(slots) if s is None]
+            out = out.at[jnp.asarray(absent, dtype=jnp.int32)].set(0)
+        return out
+
+    def rows_plane(self, rows: Sequence[int]) -> jax.Array:
+        slots = sorted({self.row_index[r] for r in rows
+                        if r in self.row_index})
+        if not slots:
+            return self.zero_plane()
+        return jax.lax.reduce(self._rows_at(slots), jnp.uint32(0),
+                              jax.lax.bitwise_or, dimensions=(0,))
+
+
 class StackedBSI:
     """BSI plane stacks across shards: device uint32[2+depth, S*W].
 
@@ -669,11 +881,7 @@ class StackedBSI:
             blk = self._planes
             if blk is not None:
                 return blk
-            for frag, built_v in zip(self._fragments, self._built_vers):
-                if (frag.version if frag is not None else -1) != built_v:
-                    PAGING_STATS["stale_retries"] += 1
-                    raise StackStale(
-                        "fragment advanced past the stack snapshot")
+            _check_snapshot(self)
             blk = self._build_host()
             self._planes = blk
         self._charge()
@@ -906,6 +1114,8 @@ def _advance_set(stack: "StackedSet", fragments, built_vers) -> Optional["Stacke
     Caller holds the writer lock (fragment versions are quiescent)."""
     from pilosa_tpu.shardwidth import BITS_PER_WORD
 
+    if isinstance(stack, KeyedSet):
+        return _advance_keyed(stack, fragments, built_vers)
     acc = _MaskAccum()
     new_rows: List[int] = []
     new_index: Optional[Dict[int, int]] = None
@@ -1030,6 +1240,95 @@ def _advance_set(stack: "StackedSet", fragments, built_vers) -> Optional["Stacke
         if blk is not None:
             BUDGET.charge((new.serial, bi), device_bytes(blk),
                           lambda s=new, i=bi: s._drop_block(i))
+    return new
+
+
+def _record_key(frag, col: int, slot_of) -> int:
+    """The key (slot + 1, 0: none) the host fragment holds for column
+    ``col`` now; a record in two rows raises, as at a build."""
+    from pilosa_tpu.shardwidth import BITS_PER_WORD
+
+    w, b = divmod(col, BITS_PER_WORD)
+    n = len(frag.row_ids)
+    hit = np.flatnonzero((frag.planes[:n, w] >> np.uint32(b)) & 1)
+    if hit.size > 1:
+        raise ValueError(f"mutex stack: column {col} is in rows "
+                         f"{[frag.row_ids[i] for i in hit]}")
+    return slot_of(frag.row_ids[hit[0]]) + 1 if hit.size else 0
+
+
+def _advance_keyed(stack: "KeyedSet", fragments,
+                   built_vers) -> Optional["KeyedSet"]:
+    """Replay pending writes onto a key-plane stack: each column a write
+    touched gets the key its fragment holds now, as OR/ANDNOT masks on
+    the key planes (``_MaskAccum`` deltas apply to planes of any
+    meaning); kept derived blocks are dropped. A new row is a new slot;
+    one past what the planes' bits can name, an evicted key tensor or a
+    fragment that came or went rebuilds. Caller holds the writer lock."""
+    from pilosa_tpu.shardwidth import BITS_PER_WORD
+
+    base = stack._keys
+    if base is None:
+        return None
+    row_ids = list(stack.row_ids)
+    row_index = dict(stack.row_index)
+
+    def slot_of(row: int) -> int:
+        s = row_index.get(row)
+        if s is None:
+            s = row_index[row] = len(row_ids)
+            row_ids.append(row)
+        return s
+
+    touched: List[Tuple[int, int]] = []
+    for si, (frag, built_v) in enumerate(zip(fragments, built_vers)):
+        if frag is None:
+            if built_v != -1:
+                return None
+            continue
+        if built_v == frag.version:
+            continue
+        if built_v < 0:
+            return None
+        ops = frag.deltas.since(built_v, frag.version)
+        if ops is None:
+            return None
+        cols = set()
+        for row, set_cols, clear_cols in ops:
+            slot_of(row)
+            cols.update(set_cols)
+            cols.update(clear_cols)
+        touched.extend((si, c) for c in sorted(cols))
+    cap = max(stack.cap, -(-len(row_ids) // stack.block_rows)
+              * stack.block_rows)
+    if keyrows.key_bits(cap) > stack.bits:
+        return None  # a new row needs one more key bit: rebuild
+    acc = _MaskAccum()
+    for si, col in touched:
+        key = _record_key(fragments[si], col, slot_of)
+        w, b = divmod(col, BITS_PER_WORD)
+        for i in range(stack.bits):
+            if key >> i & 1:
+                acc.set(i, si * stack.words + w, b)
+            else:
+                acc.clear(i, si * stack.words + w, b)
+    new = KeyedSet.__new__(KeyedSet)
+    new.shards, new.words = stack.shards, stack.words
+    new.total_words = stack.total_words
+    new.serial = next(_stack_serial)
+    new.block_rows, new.cap, new.paged = stack.block_rows, cap, True
+    new.bits = stack.bits
+    new.row_ids, new.row_index = row_ids, row_index
+    new._lock = threading.Lock()
+    new._write_lock = stack._write_lock
+    new.ephemeral = False
+    new._walked = {}
+    new._fragments = list(fragments)
+    new._built_vers = tuple(
+        -1 if f is None else f.version for f in fragments)
+    new._blocks = [None] * (cap // stack.block_rows)
+    new._keys = acc.apply(base)
+    new._charge_keys()
     return new
 
 
@@ -1159,9 +1458,22 @@ def stacked_set(field, shards: Sequence[int], view: str) -> StackedSet:
             hit = _advance_or_rebuild(
                 field, group, subset, vers, fragments,
                 advance=_advance_set,
-                rebuild=lambda: StackedSet(
-                    shards, fragments, write_lock=_writer_lock(field)))
+                rebuild=lambda: _new_set_stack(field, shards, fragments))
     return hit
+
+
+def _new_set_stack(field, shards, fragments) -> StackedSet:
+    """A fresh stack of ``field``'s fragments, in the form
+    :func:`keyed_form` gives it."""
+    from pilosa_tpu.core.schema import FieldType
+
+    layout = _slot_layout(fragments, len(shards) * WORDS_PER_SHARD)
+    mutex = field.options.type in (FieldType.MUTEX, FieldType.BOOL)
+    cls = (KeyedSet if keyed_form(mutex, layout[2],
+                                  len(shards) * WORDS_PER_SHARD)
+           else StackedSet)
+    return cls(shards, fragments, write_lock=_writer_lock(field),
+               layout=layout)
 
 
 def stacked_bsi(field, shards: Sequence[int]) -> StackedBSI:

@@ -220,6 +220,12 @@ METRIC_DEVICE_BUDGET_EVICTIONS = "device_budget_evictions_total"
 # and sent to the devices, in the form they went: what a budget too
 # small for the working set costs per read
 METRIC_STACK_BUILD_BYTES = "stack_build_bytes_total"
+# key-plane stacks (core/stacked.py KeyedSet, ops/keyrows.py): rows
+# derived on the device from a mutex field's key planes, a whole block
+# for a walk or the few rows of a point read (kind=block|rows), and the
+# bytes of dense rows so derived
+METRIC_STACK_KEY_ROWS = "stack_key_rows_total"
+METRIC_STACK_KEY_ROWS_BYTES = "stack_key_rows_bytes_total"
 # compressed-residency plane (ops/ctiles.py): blocks stored in
 # compressed-tile form (labelled kind=set|bsi), blocks kept dense and
 # why (disabled is never ticked — the kill switch costs nothing),

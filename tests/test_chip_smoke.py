@@ -60,6 +60,13 @@ def test_cpu_dry_run_passes_every_oracle_check(tmp_path):
     # the kernel table is read even where its assertions are skipped
     assert "tape_count" in out["kernels"]
     assert out["mesh_sharding_fallback_total"] == 0
+    # the key-rows step: the key form, answers equal to numpy; the
+    # compiled kernel and its counter only on a chip
+    kr = out["key_rows"]
+    assert kr["form"] == "KeyedSet" and kr["bits"] >= 9
+    assert kr["served_equal"] is True
+    assert "compiled_equals_interpret" not in kr
+    assert f"key rows: {json.dumps(kr, sort_keys=True)}" in r.stdout
 
 
 def test_without_allow_cpu_fails_naming_the_device_check():

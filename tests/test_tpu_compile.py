@@ -97,3 +97,25 @@ def test_pair_counts_compiles_for_v5e_with_the_face_the_readers_match(
         assert rows == sorted([r1, r2])
     else:
         assert rows[0] == -(-r1 // 8) * 8 and rows[1] >= r2
+
+
+def test_key_rows_compiles_for_v5e_with_the_face_the_reader_matches(one_chip):
+    """The derivation of one 32-row block of SF-10's brand stack from its
+    11 key bits, stored as 16 planes over 58 shards: one
+    ``tpu_custom_call`` named ``%_key_rows_pallas``, whose shapes ``benchmark/kernel_costs/key_rows.py`` reads to the bytes a
+    call moves."""
+    from benchmark.harness import kernel_cost, manifest
+    from pilosa_tpu.ops import keyrows as K
+
+    words = 58 * 32768
+    keys = jax.ShapeDtypeStruct((16, words), jnp.uint32, sharding=one_chip)
+    first = jax.ShapeDtypeStruct((1,), jnp.int32, sharding=one_chip)
+    text = K._key_rows_pallas.__wrapped__.lower(
+        keys, first, rows=32, planes=11, interpret=False).compile().as_text()
+    calls = [ln.strip() for ln in text.splitlines()
+             if "tpu_custom_call" in ln]
+    assert len(calls) == 1, calls
+    assert re.match(r"(ROOT )?%_key_rows_pallas(\.\d+)? = u32\[32,",
+                    calls[0]), calls[0]
+    cost = kernel_cost.family("key_rows", manifest.BENCH)
+    assert cost(calls[0]) == (words * 32.0 * 16, 4.0 * words * (16 + 32))
