@@ -295,10 +295,13 @@ METRIC_KERNEL_H2D_SECONDS = "device_kernel_h2d_seconds_total"
 # as the engine mesh places them: the pair-count family) ticks the mesh
 # series as well. A dispatch of the pair-count family also says which
 # body of the kernel its operands' heights chose (body=vpu|mxu,
-# ops/groupby.pallas_body).
+# ops/groupby.pallas_body). A dispatch of pair_sums whose kernel ANDed
+# each magnitude plane into its operand in VMEM, instead of being handed
+# the masked operand, ticks the fused-mask series.
 METRIC_OPS_PALLAS_DISPATCH = "ops_pallas_dispatch_total"
 METRIC_OPS_PALLAS_MESH_DISPATCH = "ops_pallas_mesh_dispatch_total"
 METRIC_OPS_PALLAS_BODY = "ops_pallas_body_total"
+METRIC_OPS_PALLAS_FUSED_MASK = "ops_pallas_fused_mask_total"
 METRIC_OPS_PALLAS_FALLBACK = "ops_pallas_fallback_total"
 # a warm compiled-tape dispatch is tens of µs of launch overhead on CPU
 # up through multi-ms sharded collectives; cold paths land in the tail

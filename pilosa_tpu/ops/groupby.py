@@ -93,6 +93,8 @@ _VPU_SUB = 512
 _VPU_GROUP = 64
 _VPU_ROWS = 8
 _VPU_ACC_VREGS = 32
+# rows of the mask planes' block in the masked VPU body: one sublane tile
+_MASK_ROWS = 8
 #: the VPU body where r1 * r2 < _VPU_MAX_PAIRS_PER_ROW * (r1 + r2)
 _VPU_MAX_PAIRS_PER_ROW = 28
 
@@ -210,14 +212,13 @@ def _pad_rows(x, rows: int):
         x, ((0, rows - x.shape[0]), (0, 0)))
 
 
-def _padded_words(a, b):
-    """Both operands zero-padded to whole ``_PALLAS_BW``-word blocks
+def _padded_words(*planes):
+    """Every operand zero-padded to whole ``_PALLAS_BW``-word blocks
     (zero words count nothing)."""
-    pad_w = (-a.shape[1]) % _PALLAS_BW
+    pad_w = (-planes[0].shape[1]) % _PALLAS_BW
     if pad_w:
-        a = jnp.pad(a, ((0, 0), (0, pad_w)))
-        b = jnp.pad(b, ((0, 0), (0, pad_w)))
-    return a, b
+        planes = [jnp.pad(x, ((0, 0), (0, pad_w))) for x in planes]
+    return planes
 
 
 def _row_tile(r2: int):
@@ -283,7 +284,27 @@ def _vpu_kernel(a_ref, b_ref, out_ref, acc_ref):
     chunks: the program does not grow with the operands' heights (a
     listing is traced, lowered and serialized once a shape and a
     process, before the compile cache can be asked)."""
+    _vpu_body(a_ref, b_ref, out_ref, acc_ref)
+
+
+def _vpu_masked_kernel(plane_ref, a_ref, b_ref, m_ref, out_ref, acc_ref,
+                       am_ref):
+    """:func:`_vpu_kernel` counting popcount(A_i & M & B_j). ``m_ref`` is
+    the ``(_MASK_ROWS, bw)`` block of the mask planes that holds plane
+    ``plane_ref[0]`` (scalar prefetch, read by the index map: Mosaic
+    takes neither a one-row block of a taller array nor a load from a
+    traced unaligned row). Before the loops take ``_VPU_SUB`` words of
+    ``a``, the plane's chunks are rotated to the block's first row and
+    ANDed into them a sublane tile at a time, into ``am_ref``, from which
+    the loops read ``a``'s rows: one AND for eight rows and 128 words,
+    whatever the height of ``b``."""
+    shift = (_MASK_ROWS - plane_ref[0] % _MASK_ROWS) % _MASK_ROWS
+    _vpu_body(a_ref, b_ref, out_ref, acc_ref, (m_ref, shift, am_ref))
+
+
+def _vpu_body(a_ref, b_ref, out_ref, acc_ref, mask=None):
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
     w = pl.program_id(1)  # innermost, as in the MXU body
     r1, bw = a_ref.shape
@@ -302,39 +323,49 @@ def _vpu_kernel(a_ref, b_ref, out_ref, acc_ref):
     def _():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    def count(off, j0, i0, ni):
+    def count(off, src, j0, i0, ni):
         """``ni`` rows of ``a`` from ``i0`` against ``nj`` rows of ``b``
-        from ``j0`` over ``_VPU_SUB`` words from ``off``."""
+        from ``j0`` over ``_VPU_SUB`` words from ``off``; ``a``'s rows
+        are read from ``src``, ``a_ref`` itself or the masked copy of
+        these words."""
         rows = pl.ds(j0, nj)
         # a traced start is a whole sublane tile's: the row inside the
         # view is static, and its load broadcasts it for free (Mosaic
         # refuses a dynamic load from an unaligned row)
-        a_rows, first = ((a_ref, i0) if isinstance(i0, int)
-                         else (a_ref.at[pl.ds(i0, ni)], 0))
+        a_rows, first = ((src, i0) if isinstance(i0, int)
+                         else (src.at[pl.ds(i0, ni)], 0))
         for k0 in range(0, ni, ib):
             block = range(k0, min(k0 + ib, ni))
             sums = [acc_ref[i0 + k, rows] for k in block]
             for c in range(0, _VPU_SUB, 128):
                 cols = pl.ds(off + c, 128)
+                a_cols = cols if src is a_ref else pl.ds(c, 128)
                 b_rows = b_ref[rows, cols]
                 for n, k in enumerate(block):
                     sums[n] += lax.population_count(
-                        a_rows[pl.ds(first + k, 1), cols] & b_rows)
+                        a_rows[pl.ds(first + k, 1), a_cols] & b_rows)
             for n, k in enumerate(block):
                 acc_ref[i0 + k, rows] = sums[n]
 
     def words(step, carry):
         off = pl.multiple_of(step * _VPU_SUB, _VPU_SUB)
+        src = a_ref
+        if mask is not None:
+            m_ref, shift, src = mask
+            for c in range(0, _VPU_SUB, 128):
+                cols = pl.ds(off + c, 128)
+                plane = pltpu.roll(m_ref[:, cols], shift, 0)[0:1]
+                src[:, pl.ds(c, 128)] = a_ref[:, cols] & plane
 
         def group(j, carry):
             # the last group of a ragged tile starts early and counts
             # the rows it shares with the one before a second time
             j0 = pl.multiple_of(jnp.minimum(j * nj, tr2 - nj), 8)
-            _blocks(r1, rows_a, functools.partial(count, off, j0))
+            _blocks(r1, rows_a, functools.partial(count, off, src, j0))
             return carry
 
         if groups == 1:
-            _blocks(r1, rows_a, functools.partial(count, off, 0))
+            _blocks(r1, rows_a, functools.partial(count, off, src, 0))
         else:
             lax.fori_loop(0, groups, group, None)
         return carry
@@ -372,23 +403,32 @@ def _blocks(n: int, size: int, body) -> None:
         body(whole * size, n % size)
 
 
-def _vpu_block_words(r1: int, tr2: int, padded_words: int) -> int:
-    """Words a grid step of the VPU body takes for these heights."""
+def _vpu_block_words(r1: int, tr2: int, padded_words: int,
+                     masked: bool = False) -> int:
+    """Words a grid step of the VPU body takes for these heights (and
+    the mask's block, where it has one)."""
+    rows = r1 + tr2 + _MASK_ROWS * masked
     bw = _PALLAS_BW
     while (2 * bw <= _VPU_MAX_BW and padded_words % (2 * bw) == 0
-           and 2 * 4 * (r1 + tr2) * 2 * bw <= _VPU_INPUT_BYTES):
+           and 2 * 4 * rows * 2 * bw <= _VPU_INPUT_BYTES):
         bw *= 2
     return bw
 
 
-def _pair_counts_vpu(a, b, interpret: bool):
+def _pair_counts_vpu(a, b, interpret: bool, masks=None, plane=None):
     """The VPU body: every row of the shorter operand ANDed with the
     rows of the taller, popcounted and added into lane-wise accumulators
     in VMEM scratch; one cross-lane reduce on the last word step. Both
     operands go in as they are (a one-row TopN filter is one row, not
     eight; no row is copied to be padded): the last row tile of the
     taller hangs over its end, and the counts of the rows that are not
-    there are cut off."""
+    there are cut off.
+
+    With ``masks`` (``uint32[D, W]``, D >= ``_MASK_ROWS``, as wide as the
+    operands once padded) and ``plane`` (``int32[1]``) it counts
+    popcount(A_i & M & B_j) for M = ``masks[plane]``: the kernel reads the
+    sublane tile that holds that row a block at a time beside ``a``'s and
+    masks ``a`` in VMEM, so no masked operand is written to HBM."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -396,22 +436,32 @@ def _pair_counts_vpu(a, b, interpret: bool):
     if r1 > r2:
         # popcount(A_i & B_j) is symmetric, and the kernel lists the
         # rows of its first operand: the shorter one
-        return _pair_counts_vpu(b, a, interpret).T
+        return _pair_counts_vpu(b, a, interpret, masks, plane).T
     a, b = _padded_words(a, b)
     tr2, r2p = _row_tile(r2)
-    bw = _vpu_block_words(r1, tr2, a.shape[1])
+    bw = _vpu_block_words(r1, tr2, a.shape[1], masked=masks is not None)
+    grid = (r2p // tr2, a.shape[1] // bw)
+    # an index map takes the grid's indices, then any scalar prefetched
+    in_specs = [pl.BlockSpec((r1, bw), lambda t, w, *_: (0, w)),
+                pl.BlockSpec((tr2, bw), lambda t, w, *_: (t, w))]
+    out_specs = pl.BlockSpec((r1, tr2), lambda t, w, *_: (0, t))
+    scratch = [pltpu.VMEM((r1, tr2, 128), jnp.uint32)]
+    out_shape = jax.ShapeDtypeStruct((r1, r2p), jnp.int32)
+    if masks is None:
+        out = pl.pallas_call(
+            _vpu_kernel, grid=grid, in_specs=in_specs, out_specs=out_specs,
+            out_shape=out_shape, scratch_shapes=scratch,
+            interpret=interpret)(a, b)
+        return out[:, :r2]
+    in_specs.append(pl.BlockSpec((_MASK_ROWS, bw),
+                                 lambda t, w, k: (k[0] // _MASK_ROWS, w)))
     out = pl.pallas_call(
-        _vpu_kernel,
-        grid=(r2p // tr2, a.shape[1] // bw),
-        in_specs=[
-            pl.BlockSpec((r1, bw), lambda t, w: (0, w)),
-            pl.BlockSpec((tr2, bw), lambda t, w: (t, w)),
-        ],
-        out_specs=pl.BlockSpec((r1, tr2), lambda t, w: (0, t)),
-        out_shape=jax.ShapeDtypeStruct((r1, r2p), jnp.int32),
-        scratch_shapes=[pltpu.VMEM((r1, tr2, 128), jnp.uint32)],
-        interpret=interpret,
-    )(a, b)
+        _vpu_masked_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=grid, in_specs=in_specs,
+            out_specs=out_specs,
+            scratch_shapes=scratch + [pltpu.VMEM((r1, _VPU_SUB), jnp.uint32)]),
+        out_shape=out_shape, interpret=interpret)(plane, a, b, masks)
     return out[:, :r2]
 
 
@@ -420,9 +470,9 @@ def _pair_counts_traced(a, b, interpret: bool):
     the two packed operands, its body chosen by :func:`pallas_body` from
     their heights (static under a trace). Both bodies work a word block
     at a time in VMEM, so HBM sees only the packed uint32 planes. Shared
-    by ``pair_sums`` (a call a magnitude plane), ``bsi_plane_popcounts``
-    (magnitude-plane popcounts) and TopN row counts: any "popcount of
-    pairwise ANDs" is this one kernel."""
+    by ``pair_sums`` (where its steps take the MXU body),
+    ``bsi_plane_popcounts`` (magnitude-plane popcounts) and TopN row
+    counts: any "popcount of pairwise ANDs" is this one kernel."""
     if pallas_body(a.shape[0], b.shape[0]) == "vpu":
         return _pair_counts_vpu(a, b, interpret)
     return _pair_counts_mxu(a, b, interpret)
@@ -544,7 +594,9 @@ def pair_sums(a, b, mags, pos, neg):
                        _pair_sums_mesh(a, b, mags, pos, neg, mesh=mesh))
             PU.dispatched("pair_sums", on_mesh=mesh is not None,
                           body=pallas_body(_pair_sums_step_rows(a.shape[0]),
-                                           b.shape[0]))
+                                           b.shape[0]),
+                          fused_mask=_pair_sums_fuses_mask(a.shape[0],
+                                                           b.shape[0]))
             return out
         except Exception as e:
             PU.failed("pair_sums", e)
@@ -576,24 +628,46 @@ def _pair_sums_step_rows(r1: int) -> int:
     return 2 * r1 if 2 * r1 <= _PALLAS_MAX_R1 else r1
 
 
+def _pair_sums_fuses_mask(r1: int, r2: int) -> bool:
+    """Whether the kernel calls of a ``pair_sums`` over ``r1`` x ``r2``
+    rows take the plane's mask themselves: the VPU body does (one AND a
+    row and 128 words beside 3 * r2 / 8 for its pairs); the MXU body's
+    calls are given the masked first operand."""
+    return pallas_body(_pair_sums_step_rows(r1), r2) == "vpu"
+
+
 def _pair_sums_traced(a, b, mags, pos, neg, interpret: bool):
-    """Traceable body of the Pallas route of :func:`pair_sums`: the
-    plane's mask goes on the small side, P = A_i & sign & M_k, so ``b``
-    reaches the kernel as it is and no ``b & M_k`` is written and read
-    back a step; both signs stack into one first operand, so the kernel
-    reads ``b`` once a plane, not twice (two calls a step where the
-    stack would pass the kernel's row limit)."""
+    """Traceable body of the Pallas route of :func:`pair_sums`: one scan
+    over the planes, a pair count of P = A_i & sign & M_k against ``b`` a
+    step. Both signs stack into one first operand, formed once a call,
+    so the kernel reads ``b`` once a plane, not twice (two calls a step
+    where the stack would pass the kernel's row limit). The VPU body
+    takes the whole ``mags`` and the step's plane index and ANDs the
+    plane into P in VMEM (:func:`_pair_sums_fuses_mask`), so no
+    ``P & M_k`` is written to HBM and read back a step; the MXU body is
+    given ``x & M_k``."""
     r1 = a.shape[0]
     firsts = [a & pos[None, :], a & neg[None, :]]
     if _pair_sums_step_rows(r1) == 2 * r1:
         firsts = [jnp.concatenate(firsts)]
 
-    def step(_, mk):
-        c = jnp.concatenate([_pair_counts_traced(x & mk[None, :], b, interpret)
-                             for x in firsts])
+    if _pair_sums_fuses_mask(r1, b.shape[0]):
+        planes = jnp.arange(mags.shape[0], dtype=jnp.int32)
+        *firsts, b, mags = _padded_words(*firsts, b, mags)
+        mags = _pad_rows(mags, max(_MASK_ROWS, mags.shape[0]))
+
+        def counts(x, k):
+            return _pair_counts_vpu(x, b, interpret, mags, k[None])
+    else:
+        def counts(x, mk):
+            return _pair_counts_traced(x & mk[None, :], b, interpret)
+        planes = mags
+
+    def step(_, plane):
+        c = jnp.concatenate([counts(x, plane) for x in firsts])
         return None, (c[:r1], c[r1:])
 
-    _, (p, n) = lax.scan(step, None, mags)
+    _, (p, n) = lax.scan(step, None, planes)
     return p, n
 
 

@@ -48,6 +48,10 @@ SUBLANES = 8
 #: narrower; a word block is worked in chunks of CHUNK_WORDS
 BLOCK_WORDS = 16384
 CHUNK_WORDS = 512
+#: VMEM a grid step's double-buffered blocks may take (the key planes'
+#: and the rows'): SF-10's 32-row block from 16 planes fills it at
+#: BLOCK_WORDS; a taller block takes fewer words
+BLOCK_BYTES = 6 << 20
 
 _ONES = np.uint32(0xFFFFFFFF)
 
@@ -134,11 +138,17 @@ def _key_rows_body(planes: int, chunk: int, first_ref, keys_ref, out_ref):
     lax.fori_loop(0, bw // chunk, step, 0)
 
 
-def _word_block(words: int) -> int:
+def _word_block(words: int, rows: int, k_pad: int) -> int:
     """The word block a grid step takes: the whole width where it is at
     most :data:`BLOCK_WORDS`, else BLOCK_WORDS (the last step may hang
-    over the end; its words past the width are not written)."""
-    return words if words <= BLOCK_WORDS else BLOCK_WORDS
+    over the end; its words past the width are not written), halved
+    while the step's blocks of ``k_pad`` key planes and ``rows`` rows,
+    double-buffered, pass :data:`BLOCK_BYTES`."""
+    bw = words if words <= BLOCK_WORDS else BLOCK_WORDS
+    while (2 * 4 * (rows + k_pad) * bw > BLOCK_BYTES
+           and bw % (2 * CHUNK_WORDS) == 0):
+        bw //= 2
+    return bw
 
 
 def _chunk(bw: int) -> int:
@@ -157,7 +167,7 @@ def _key_rows_pallas(keys, first, rows: int, planes: int, interpret: bool):
     from jax.experimental.pallas import tpu as pltpu
 
     k_pad, words = keys.shape
-    bw = _word_block(words)
+    bw = _word_block(words, rows, k_pad)
     return pl.pallas_call(
         functools.partial(_key_rows_body, planes, _chunk(bw)),
         grid=(-(-words // bw),),

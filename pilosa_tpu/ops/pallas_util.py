@@ -14,6 +14,8 @@ timeline:
     ops_pallas_body_total{kernel,body}       those of the pair-count family
                                              by the kernel body their
                                              operands' heights chose
+    ops_pallas_fused_mask_total{kernel}      those whose kernel ANDed a
+                                             mask plane in VMEM itself
     ops_pallas_fallback_total{kernel,why}    classic-path fallbacks
 
 Mode selection (``PILOSA_TPU_PALLAS``):
@@ -160,17 +162,21 @@ def mode_token() -> str:
 
 
 def dispatched(kernel: str, on_mesh: bool = False,
-               body: Optional[str] = None) -> None:
+               body: Optional[str] = None, fused_mask: bool = False) -> None:
     """One successful Pallas dispatch; ``on_mesh`` when it was the
     family's per-chip program under ``shard_map`` + ``psum``, so a scrape
     can tell the two placements apart; ``body`` (``vpu`` | ``mxu``) when
     it ran the pair-count kernel, whose body goes by its operands'
-    heights (``ops/groupby.pallas_body``)."""
+    heights (``ops/groupby.pallas_body``); ``fused_mask`` when that
+    kernel ANDed a mask plane into its operand in VMEM itself
+    (``ops/groupby._pair_sums_fuses_mask``)."""
     M.REGISTRY.count(M.METRIC_OPS_PALLAS_DISPATCH, kernel=kernel)
     if on_mesh:
         M.REGISTRY.count(M.METRIC_OPS_PALLAS_MESH_DISPATCH, kernel=kernel)
     if body is not None:
         M.REGISTRY.count(M.METRIC_OPS_PALLAS_BODY, kernel=kernel, body=body)
+    if fused_mask:
+        M.REGISTRY.count(M.METRIC_OPS_PALLAS_FUSED_MASK, kernel=kernel)
 
 
 def fallback(kernel: str, why: str) -> None:

@@ -3,6 +3,13 @@ the benchmark's cells send: the table behind ``ops/groupby.pallas_body``
 (PERF.md §5). Run it through the chip tool, from the repo root:
 
     python scripts/pair_counts_sweep.py [out.json]
+    python scripts/pair_counts_sweep.py --pair-sums [out.json]
+
+The second form times a whole ``pair_sums`` call (a scan of one kernel
+call a magnitude plane) at the shapes of a Q2.x Sum in the two
+``groupby-closed`` cells, in the form that hands the kernel each plane's
+masked first operand (``unfused``: the mask an XLA fusion a step) and in
+the one whose kernel masks in VMEM (``fused``).
 
 Every variant is a jitted program of its own name; its time is the mean
 device time of its ``XLA Modules`` events in one profiler trace, and its
@@ -47,6 +54,10 @@ SHAPES += [
 ]
 VPU_BLOCKS = (2048, 4096, 8192, 16384)
 CALLS = 20
+SF10 = 58 * 32768
+#: (rows a, rows b, planes, words) of a Q2.x Sum: SF-10 (a derived 32-row
+#: block of the brand stack), SF-1 (a 256-row block)
+SUM_SHAPES = [(8, 32, 17, SF10), (8, 256, 17, SSB)]
 
 
 def variants(r1, tr2, words):
@@ -62,6 +73,78 @@ def variants(r1, tr2, words):
                 yield f"vpu{bw}", G._pair_counts_vpu
     finally:
         G._VPU_MAX_BW = cap
+
+
+def _pair_sums_unfused(a, b, mags, pos, neg, interpret):
+    """``pair_sums``' scan as it was before its kernel took the mask:
+    ``x & M_k`` written by XLA a step, then the unmasked kernel."""
+    r1 = a.shape[0]
+    x = jnp.concatenate([a & pos[None, :], a & neg[None, :]])
+
+    def step(_, mk):
+        c = G._pair_counts_traced(x & mk[None, :], b, interpret)
+        return None, (c[:r1], c[r1:])
+
+    return jax.lax.scan(step, None, mags)[1]
+
+
+SUM_VARIANTS = (("unfused", _pair_sums_unfused),
+                ("fused", G._pair_sums_traced))
+
+
+def sweep_pair_sums(out_path):
+    dev = jax.devices()[0]
+    print("device", dev.platform, dev.device_kind, flush=True)
+    rows = []
+    for n, (r1, r2, d, words) in enumerate(SUM_SHAPES):
+        keys = jax.random.split(jax.random.PRNGKey(100 + n), 4)
+        a = jax.random.bits(keys[0], (r1, words), jnp.uint32)
+        b = jax.random.bits(keys[1], (r2, words), jnp.uint32)
+        mags = jax.random.bits(keys[2], (d, words), jnp.uint32)
+        pos = jax.random.bits(keys[3], (words,), jnp.uint32)
+        ops = (a, b, mags, pos, ~pos)
+        want = [np.asarray(x) for x in G._pair_sums_xla(*ops)]
+        runs = []
+        for tag, body in SUM_VARIANTS:
+            name = f"ps_{r1}x{r2}x{d}_{words}_{tag}"
+
+            def fn(*xs, body=body):
+                return body(*xs, interpret=G.PU.use_interpret())
+
+            fn.__name__ = name
+            prog = jax.jit(fn)
+            t0 = time.perf_counter()
+            prog.lower(*ops)
+            lower_s = time.perf_counter() - t0
+            got = [np.asarray(x) for x in prog(*ops)]
+            row = {"r1": r1, "r2": r2, "planes": d, "words": words,
+                   "form": tag, "name": name,
+                   "equal": all((g == x).all() for g, x in zip(got, want)),
+                   "lower_s": round(lower_s, 3)}
+            t0 = time.perf_counter()
+            for _ in range(CALLS):
+                out = prog(*ops)
+            jax.block_until_ready(out)
+            row["wall_ms"] = round((time.perf_counter() - t0) / CALLS * 1e3, 4)
+            rows.append(row)
+            runs.append(prog)
+        with tempfile.TemporaryDirectory() as tmp:
+            jax.profiler.start_trace(tmp)
+            for prog in runs:
+                for _ in range(CALLS):
+                    out = prog(*ops)
+                jax.block_until_ready(out)
+            jax.profiler.stop_trace()
+            times = module_ms(tmp)
+        for row in rows[-len(runs):]:
+            row["ms"], row["calls"] = times[row["name"]]
+            row["step_ms"] = round(row["ms"] / d, 4)
+            print(json.dumps(row), flush=True)
+        del a, b, mags, pos, ops
+    if out_path:
+        os.makedirs(os.path.dirname(out_path) or ".", exist_ok=True)
+        with open(out_path, "w") as fh:
+            json.dump(rows, fh, indent=1)
 
 
 def main(out_path):
@@ -136,7 +219,7 @@ def module_ms(trace_dir):
             if line.name != "XLA Modules":
                 continue
             for e in line.events:
-                m = re.match(r"jit_(pc_\w+)\(", e.name)
+                m = re.match(r"jit_(p[cs]_\w+)\(", e.name)
                 if m:
                     s = sums.setdefault(m.group(1), [0.0, 0])
                     s[0] += e.duration_ns / 1e6
@@ -145,4 +228,8 @@ def module_ms(trace_dir):
 
 
 if __name__ == "__main__":
-    main(sys.argv[1] if len(sys.argv) > 1 else None)
+    args = sys.argv[1:]
+    if args[:1] == ["--pair-sums"]:
+        sweep_pair_sums(args[1] if len(args) > 1 else None)
+    else:
+        main(args[0] if args else None)

@@ -162,9 +162,10 @@ def test_pair_counts_bodies_parity_at_a_shard(rng, r1, r2):
     assert want[0, 0] == want[0, r2 // 2] == 1 << 20
 
 
-def kernel_listing(r1, r2, words=1024):
+def kernel_listing(r1, r2, words=1024, masked=False):
     """Equations of the kernel's jaxpr, its loops' bodies included: what
-    is traced, lowered and serialized once a shape and a process."""
+    is traced, lowered and serialized once a shape and a process
+    (``masked``: the VPU body that ANDs a plane of 17 into ``a``)."""
     import jax
     from jax.extend import core as jcore
 
@@ -180,8 +181,14 @@ def kernel_listing(r1, r2, words=1024):
         return n
 
     spec = [jax.ShapeDtypeStruct((r, words), np.uint32) for r in (r1, r2)]
-    closed = jax.make_jaxpr(
-        lambda a, b: G._pair_counts_traced(a, b, True))(*spec)
+    if masked:
+        spec += [jax.ShapeDtypeStruct((17, words), np.uint32),
+                 jax.ShapeDtypeStruct((1,), np.int32)]
+        closed = jax.make_jaxpr(
+            lambda a, b, m, k: G._pair_counts_vpu(a, b, True, m, k))(*spec)
+    else:
+        closed = jax.make_jaxpr(
+            lambda a, b: G._pair_counts_traced(a, b, True))(*spec)
     call, = [e for e in closed.jaxpr.eqns
              if e.primitive.name == "pallas_call"]
     return count(call.params["jaxpr"])
@@ -202,6 +209,15 @@ MAX_LISTING = 500
 def test_the_vpu_body_lists_a_bounded_program(r1, r2):
     assert G.pallas_body(r1, r2) == "vpu"
     assert kernel_listing(r1, r2) <= MAX_LISTING
+
+
+@pytest.mark.parametrize("r1,r2", [(16, 32), (16, 256), (14, 25), (65, 32)])
+def test_the_masked_vpu_body_lists_what_the_plain_one_does_and_a_little(
+        r1, r2):
+    """``pair_sums``' masked body adds one listing of the mask's rotation
+    and AND a sub-step, whatever the heights: no program grows with them."""
+    plain = kernel_listing(r1, r2)
+    assert plain < kernel_listing(r1, r2, masked=True) <= plain + 60
 
 
 def test_the_vpu_body_does_not_unroll_over_rows():
@@ -393,6 +409,93 @@ def test_pair_sums_keeps_the_scan_the_roofline_reads(rng):
     assert body.count("pallas_call") == 1
     # the second operand reaches the kernel as the program's own argument
     assert "scan" not in body and "while" not in body
+
+
+@pytest.mark.parametrize("r1,r2,d,w", [
+    (7, 25, 17, 1000),   # r1 odd, a width no multiple of the word block
+    (8, 32, 17, 1536),   # SF-10's call: 16 x 32 a step
+    (7, 232, 5, 1024),   # a ragged last row group of the second operand
+    (8, 256, 17, 1024),  # SF-1's call: 16 x 256 a step, four row groups
+    (8, 25, 3, 512),     # fewer planes than the mask's sublane tile
+    (65, 32, 4, 512),    # 2 * r1 > 128: two masked calls a step
+])
+def test_pair_sums_kernel_that_masks_in_vmem_is_exact(rng, r1, r2, d, w):
+    """The VPU body ANDs each magnitude plane into its operand itself:
+    its answer equals the XLA route's and numpy's, with values of both
+    signs and one plane all zero."""
+    assert G._pair_sums_fuses_mask(r1, r2)
+    a, b, mags, pos, neg = sum_operands(rng, r1, r2, d, w)
+    mags[d // 2] = 0
+    assert pos.any() and neg.any()
+    got = G._pair_sums_pallas(a, b, mags, pos, neg, interpret=True)
+    want = pair_sums_numpy(a, b, mags, pos, neg)
+    for g, x, n in zip(got, G._pair_sums_xla(a, b, mags, pos, neg), want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(x))
+        np.testing.assert_array_equal(np.asarray(g), n)
+    assert not np.asarray(got[0])[d // 2].any()
+
+
+def fused_mask_count(kernel):
+    return M.REGISTRY.value(M.METRIC_OPS_PALLAS_FUSED_MASK, kernel=kernel)
+
+
+@pytest.mark.parametrize("r1,r2,d,w,fused", [
+    (8, 256, 3, WORDS, True),   # the VPU body takes the mask itself
+    (70, 140, 2, 40, False),    # the MXU body is given the masked operand
+])
+def test_pair_sums_counts_the_dispatches_whose_kernel_took_the_mask(
+        rng, forced, r1, r2, d, w, fused):
+    assert G._pair_sums_fuses_mask(r1, r2) == fused
+    ops = sum_operands(rng, r1, r2, d, w)
+    before = dispatch_count("pair_sums"), fused_mask_count("pair_sums")
+    got = G.pair_sums(*ops)
+    assert (dispatch_count("pair_sums"), fused_mask_count("pair_sums")) == (
+        before[0] + 1, before[1] + fused)
+    for g, n in zip(got, pair_sums_numpy(*ops)):
+        np.testing.assert_array_equal(np.asarray(g), n)
+
+
+def test_the_mask_block_counts_in_the_word_block_rule():
+    # SF-10's step: 16 + 32 rows fit the widest block, 8 more do not
+    words = 58 * 32768
+    assert G._vpu_block_words(16, 32, words) == G._VPU_MAX_BW
+    assert G._vpu_block_words(16, 32, words, masked=True) == 8192
+    for r1, tr2 in [(16, 256), (14, 32), (1, 80)]:
+        bw = G._vpu_block_words(r1, tr2, 1 << 20, masked=True)
+        assert 2 * 4 * (r1 + tr2 + G._MASK_ROWS) * bw <= G._VPU_INPUT_BYTES
+
+
+#: sha1 of ``_pair_counts_pallas``' jaxpr (interpreted, the kernel's
+#: source location left out) before the kernel learnt to take a mask: a
+#: caller that passes none runs the program it ran then
+UNMASKED_JAXPRS = {
+    (24, 16, 4096): "bff9c5e14a53",   # a taxi Q4 call
+    (1, 80, 4096): "c108e228a8b3",    # TopN: one filter row
+    (16, 256, 1024): "9e21da09e51c",  # SF-1's pair_sums step, unmasked
+    (8, 1000, 1024): "47c7b4a12f24",  # four row tiles of the second
+    (25, 232, 1536): "e4873b3cf1a6",  # a ragged last row group
+    (100, 17, 1024): "ed394c9d1d47",  # swapped: the shorter goes first
+    (128, 256, 1024): "274bcf04ec3b", # the MXU body
+    (48, 1, 4096): "b6274f740e00",    # plane popcounts against a filter
+}
+
+
+@pytest.mark.parametrize("shape", sorted(UNMASKED_JAXPRS))
+def test_pair_counts_without_a_mask_lowers_to_the_program_it_did(shape):
+    import hashlib
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    r1, r2, w = shape
+    text = str(jax.make_jaxpr(
+        lambda a, b: G._pair_counts_pallas.__wrapped__(a, b, interpret=True)
+    )(jax.ShapeDtypeStruct((r1, w), jnp.uint32),
+      jax.ShapeDtypeStruct((r2, w), jnp.uint32)))
+    text = re.sub(r"name_and_src_info=\S+ at \S+", "", text)
+    assert hashlib.sha1(text.encode()).hexdigest()[:12] == (
+        UNMASKED_JAXPRS[shape])
 
 
 def test_pair_sums_mesh_sharded_operand_takes_xla(rng, forced, monkeypatch):

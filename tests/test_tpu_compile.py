@@ -119,3 +119,63 @@ def test_key_rows_compiles_for_v5e_with_the_face_the_reader_matches(one_chip):
                     calls[0]), calls[0]
     cost = kernel_cost.family("key_rows", manifest.BENCH)
     assert cost(calls[0]) == (words * 32.0 * 16, 4.0 * words * (16 + 32))
+
+
+@pytest.mark.parametrize("rows,words", [
+    (128, 32768),   # chip_smoke's key-rows step on four chips: one shard
+    (256, 32768),
+    (512, 8192),    # narrower than a word block, taller than VMEM takes
+])
+def test_key_rows_compiles_for_blocks_taller_than_sf10s(one_chip, rows, words):
+    """A block of more rows than SF-10's 32 takes fewer words a grid
+    step, so that its double-buffered blocks fit VMEM: Mosaic refused
+    128 x 16,384 words (out of VMEM) before the word block knew the
+    rows."""
+    from pilosa_tpu.ops import keyrows as K
+
+    keys = jax.ShapeDtypeStruct((16, words), jnp.uint32, sharding=one_chip)
+    first = jax.ShapeDtypeStruct((1,), jnp.int32, sharding=one_chip)
+    text = K._key_rows_pallas.__wrapped__.lower(
+        keys, first, rows=rows, planes=9, interpret=False).compile().as_text()
+    assert len([ln for ln in text.splitlines()
+                if "tpu_custom_call" in ln]) == 1
+    assert 2 * 4 * (rows + 16) * K._word_block(words, rows, 16) <= (
+        K.BLOCK_BYTES)
+
+
+@pytest.mark.parametrize("r2,words", [
+    (32, 58 * 32768),   # SF-10: a derived 32-row block of the brand stack
+    (256, SSB_WORDS),   # SF-1: a 256-row block
+])
+def test_pair_sums_masks_each_plane_inside_the_kernel(one_chip, r2, words):
+    """A Q2.x Sum's ``pair_sums`` call (8 years, 17 magnitude planes):
+    still one ``%while`` that ``pair_sums_roofline`` reads as the same
+    work, whose step hands the kernel the whole ``mags`` beside the plane
+    index, so no masked ``u32[16, W]`` is made in the loop."""
+    from benchmark.harness import kernel_cost
+
+    def planes(rows):
+        return jax.ShapeDtypeStruct((rows, words), jnp.uint32,
+                                    sharding=one_chip)
+
+    sign = jax.ShapeDtypeStruct((words,), jnp.uint32, sharding=one_chip)
+    text = G._pair_sums_pallas.__wrapped__.lower(
+        planes(8), planes(r2), planes(17), sign, sign,
+        interpret=False).compile().as_text()
+    loops = [ln.strip() for ln in text.splitlines()
+             if re.match(r"\s*%while(\.\d+)? = .*\bwhile\(", ln)]
+    assert len(loops) == 1, loops
+    assert kernel_cost.pair_sums_from_text(loops[0]) == (
+        kernel_cost.pair_sums(17, 8, r2, words))
+    body_name = re.search(r"body=(%[\w.\-]+)", loops[0]).group(1)
+    body = re.search(r"\n" + re.escape(body_name) + r" .*?\n}\n", text,
+                     re.S).group(0)
+    calls = [ln for ln in body.splitlines() if "tpu_custom_call" in ln]
+    assert len(calls) == 1, calls
+    layouts = calls[0].split("operand_layout_constraints=")[1].split("}}")[0]
+    assert re.findall(r"u32\[(\d+),(\d+)\]", layouts) == [
+        ("16", str(words)), (str(r2), str(words)), ("17", str(words))]
+    made = [ln.strip() for ln in body.splitlines()
+            if re.search(rf"= u32\[16,{words}\]", ln)
+            and "get-tuple-element(" not in ln]
+    assert not made, made
