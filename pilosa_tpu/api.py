@@ -21,7 +21,7 @@ from pilosa_tpu.pql.executor import Executor
 from pilosa_tpu.obs import ExecutionRequestsAPI, get_tracer
 from pilosa_tpu.obs import metrics as M
 from pilosa_tpu.obs.tenants import current_tenant_id
-from pilosa_tpu.obs.tracing import annotate
+from pilosa_tpu.obs.stages import query_stage
 from pilosa_tpu.pql.result import result_to_json
 from pilosa_tpu.storage import save_holder_data
 from pilosa_tpu.storage.txn import TxFactory
@@ -391,7 +391,7 @@ class API:
         t0 = _time.monotonic()
         try:
             if isinstance(pql, str):
-                with annotate("pql.parse"), \
+                with query_stage("parse"), \
                         get_tracer().start_span("pql.parse"):
                     parsed = parse(pql)
             else:
@@ -546,8 +546,10 @@ class API:
         cache = self.cache
         if cache is not None:
             cache.take_stale_flag()  # clear any untagged leftover
-        results = [result_to_json(r) for r in self.query(
-            index, pql, priority=priority, deadline_ms=deadline_ms)]
+        raw = self.query(index, pql, priority=priority,
+                         deadline_ms=deadline_ms)
+        with query_stage("render"):
+            results = [result_to_json(r) for r in raw]
         out = {"results": results}
         if cache is not None and cache.take_stale_flag():
             # brownout: served past the version fingerprint — the

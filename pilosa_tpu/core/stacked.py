@@ -713,14 +713,13 @@ class KeyedSet(StackedSet):
         BUDGET.release((self.serial, self._KEYS))
 
     @contextlib.contextmanager
-    def _deriving(self, kind: str, rows: int, **tags):
+    def _deriving(self, rows: int, **tags):
         """Yield the key planes to derive ``rows`` rows from, inside the
-        ``stack.derive`` span and profiler leaf; ticks the counters."""
+        ``stack.derive`` span and profiler leaf; counts the bytes."""
         from pilosa_tpu.obs.tracing import annotate, get_tracer
 
         keys = self._ensure_keys()
         nbytes = rows * _row_bytes(self.total_words)
-        M.REGISTRY.count(M.METRIC_STACK_KEY_ROWS, kind=kind)
         M.REGISTRY.count(M.METRIC_STACK_KEY_ROWS_BYTES, nbytes)
         with get_tracer().start_span(
                 "stack.derive", rows=rows, key_planes=keys.shape[0],
@@ -735,7 +734,7 @@ class KeyedSet(StackedSet):
         if blk is not None:
             BUDGET.touch((self.serial, bi))
             return blk
-        with self._deriving("block", self.block_rows, block=bi) as keys:
+        with self._deriving(self.block_rows, block=bi) as keys:
             blk = keyrows.key_rows(keys, bi * self.block_rows,
                                    self.block_rows, self.bits)
         held = device_bytes(blk)
@@ -776,7 +775,7 @@ class KeyedSet(StackedSet):
         return parts[0] if len(parts) == 1 else jnp.concatenate(parts)
 
     def _rows_at(self, slots: Sequence[int]) -> jax.Array:
-        with self._deriving("rows", len(slots)) as keys:
+        with self._deriving(len(slots)) as keys:
             return keyrows.key_rows_at(keys, slots)
 
     def row_plane(self, row: int) -> jax.Array:

@@ -220,11 +220,9 @@ METRIC_DEVICE_BUDGET_EVICTIONS = "device_budget_evictions_total"
 # and sent to the devices, in the form they went: what a budget too
 # small for the working set costs per read
 METRIC_STACK_BUILD_BYTES = "stack_build_bytes_total"
-# key-plane stacks (core/stacked.py KeyedSet, ops/keyrows.py): rows
-# derived on the device from a mutex field's key planes, a whole block
-# for a walk or the few rows of a point read (kind=block|rows), and the
-# bytes of dense rows so derived
-METRIC_STACK_KEY_ROWS = "stack_key_rows_total"
+# key-plane stacks (core/stacked.py KeyedSet, ops/keyrows.py): bytes of
+# dense rows derived on the device from a mutex field's key planes, a
+# whole block for a walk or the few rows of a point read
 METRIC_STACK_KEY_ROWS_BYTES = "stack_key_rows_bytes_total"
 # compressed-residency plane (ops/ctiles.py): blocks stored in
 # compressed-tile form (labelled kind=set|bsi), blocks kept dense and
@@ -244,11 +242,9 @@ METRIC_COMPRESS_TILES_SKIPPED = "device_compress_tiles_skipped_total"
 # counts in neither
 METRIC_COMPRESS_DECODE = "device_compress_decode_total"
 METRIC_COMPRESS_DECODE_BYTES = "device_compress_decode_bytes_total"
-# GroupBy (pql/executor.py): which body served a GroupBy of how many
-# fields (route=dense|fold, fields=1|2|3|4+), trips to the host for
-# counts, and bytes of group planes (ANDs of rows of several fields)
-# materialised on the device
-METRIC_GROUPBY_ROUTE = "groupby_route_total"
+# GroupBy (pql/executor.py): trips to the host for counts, and bytes of
+# group planes (ANDs of rows of several fields) materialised on the
+# device
 METRIC_GROUPBY_HOST_FETCHES = "groupby_host_fetches_total"
 METRIC_GROUPBY_GROUP_PLANE_BYTES = "groupby_group_plane_bytes_total"
 # cluster health plane (obs/timeline.py + slo.py + flight.py): samples
@@ -315,6 +311,15 @@ KERNEL_DISPATCH_BUCKETS_US = (50.0, 100.0, 250.0, 500.0, 1000.0,
 METRIC_INGEST_STAGE_SECONDS = "ingest_stage_seconds_total"
 METRIC_INGEST_STAGE_ROWS = "ingest_stage_rows_total"
 METRIC_INGEST_STAGE_BYTES = "ingest_stage_bytes_total"
+# read stage accounting (obs/stages.py query_stage, always on): wall
+# seconds of a read's host stages between device programs, labelled
+# stage=parse|plan|emit|render|encode|write, each the region of one
+# profiler leaf (encode and write: every JSON response)
+METRIC_QUERY_STAGE_SECONDS = "query_stage_seconds_total"
+# requests the HTTP server holds (every route but the scrape and health
+# probes, server/http.py), and the seconds during which it held none
+METRIC_HTTP_IN_FLIGHT = "http_requests_in_flight"
+METRIC_HTTP_SERVER_IDLE_SECONDS = "http_server_idle_seconds_total"
 # streaming ingest plane (stream/): rows/batches through the pipelined
 # path, hand-off credits + consumer lag gauges, shed device-stage
 # admissions (backpressure retries), and push-endpoint 429 rejections

@@ -41,7 +41,8 @@ from pilosa_tpu.core.stacked import (StackedBSI, StackedSet,
                                      planes_per_block, stacked_bsi,
                                      stacked_set, sync_part, writer_wait)
 from pilosa_tpu.obs import metrics as M
-from pilosa_tpu.obs.tracing import get_tracer
+from pilosa_tpu.obs.stages import query_stage
+from pilosa_tpu.obs.tracing import active_span, get_tracer
 from pilosa_tpu.ops import bitmap as B
 from pilosa_tpu.ops import bsi as S
 from pilosa_tpu.ops import topk as T
@@ -185,7 +186,9 @@ class _Deferred:
         self.finalize = finalize
 
     def resolve(self):
-        return self.finalize(*[np.asarray(a) for a in self.arrays])
+        fetched = [np.asarray(a) for a in self.arrays]
+        with query_stage("emit"):
+            return self.finalize(*fetched)
 
 
 def _resolve(value):
@@ -200,9 +203,6 @@ def _concat(parts, axis=0):
 #: capacities, times the magnitude planes of a Sum) are counted whole on
 #: the device and fetched once; above it the pruning fold takes over
 _DENSE_MAX_CELLS = 1 << 24
-
-#: the ``fields`` label of ``groupby_route_total``: a bounded set
-_FIELDS_LABEL = ("1", "2", "3", "4+")
 
 
 def _group_block(total_words: int) -> int:
@@ -262,17 +262,21 @@ class Executor:
 
     def execute(self, index: str, query, shards: Optional[Sequence[int]] = None
                 ) -> List[Any]:
-        idx = self.holder.index(index)
         if isinstance(query, str):
-            query = parse(query)
-        if isinstance(query, Call):
-            query = Query([query])
-        if has_write_calls(query):
+            with query_stage("parse"):
+                query = parse(query)
+        with query_stage("plan"):
+            idx = self.holder.index(index)
+            if isinstance(query, Call):
+                query = Query([query])
+            writes = has_write_calls(query)
+            cache = None if writes else self.cache
+            if cache is not None:
+                key = self.cache_key(idx, query, shards)
+        if writes:
             with self.holder.write_lock:
                 return self._execute_query(idx, query, shards)
-        cache = self.cache
         if cache is not None:
-            key = self.cache_key(idx, query, shards)
             if key is None:
                 cache.bypass()
             else:
@@ -828,7 +832,8 @@ class Executor:
             if isinstance(res, R.RowResult):
                 return len(res.columns or res.keys or [])
             return len(res)
-        shard_list = self._shards(idx, shards)
+        with query_stage("plan"):
+            shard_list = self._shards(idx, shards)
         if not shard_list:
             return 0
         # warm path: ops + popcount + cross-shard psum in ONE compiled
@@ -856,13 +861,14 @@ class Executor:
 
     def _execute_bsi_agg(self, idx: Index, call: Call, shards,
                          mask: Optional[ShardMask] = None) -> Any:
-        fname = call.arg("field") or call.arg("_field")
-        if fname is None:
-            raise PQLError(f"{call.name} requires field=")
-        field = idx.field(fname)
-        if not field.options.type.is_bsi:
-            raise PQLError(f"field {fname!r} is not an int-like field")
-        shard_list = self._shards(idx, shards)
+        with query_stage("plan"):
+            fname = call.arg("field") or call.arg("_field")
+            if fname is None:
+                raise PQLError(f"{call.name} requires field=")
+            field = idx.field(fname)
+            if not field.options.type.is_bsi:
+                raise PQLError(f"field {fname!r} is not an int-like field")
+            shard_list = self._shards(idx, shards)
         if call.name == "Sum":
             if not shard_list:
                 return R.ValCount(val=0, count=0)
@@ -908,10 +914,10 @@ class Executor:
 
     def _execute_topn(self, idx: Index, call: Call, shards,
                       mask: Optional[ShardMask] = None) -> Any:
-        fname = self._field_name(call)
-        field = idx.field(fname)
-        n = call.arg("n") or call.arg("k")
-        shard_list = self._shards(idx, shards)
+        with query_stage("plan"):
+            field = idx.field(self._field_name(call))
+            n = call.arg("n") or call.arg("k")
+            shard_list = self._shards(idx, shards)
         if not shard_list:
             return self._pairs_field(field, [])
         filt = (self._eval_all(idx, call.children[0], shard_list, mask)
@@ -1066,8 +1072,9 @@ class Executor:
         field = idx.field(self._field_name(call))
         rows = self._rows_list(idx, call, shards, mask)
         if field.options.keys and not self.remote:
-            m = field.translate.translate_ids(rows)
-            return [m.get(r, str(r)) for r in rows]
+            with query_stage("emit"):
+                m = field.translate.translate_ids(rows)
+                return [m.get(r, str(r)) for r in rows]
         return rows
 
     # -- Distinct (reference: executor.go:1952-2153) ---------------------------
@@ -1123,25 +1130,26 @@ class Executor:
 
     def _execute_groupby(self, idx: Index, call: Call, shards,
                          mask: Optional[ShardMask] = None) -> Any:
-        if not call.children:
-            raise PQLError("GroupBy requires at least one Rows child")
-        rows_calls = [c for c in call.children if c.name == "Rows"]
-        if len(rows_calls) != len(call.children):
-            raise PQLError("GroupBy children must be Rows calls")
-        fields = [idx.field(self._field_name(c)) for c in rows_calls]
-        filter_call = call.arg("filter")
-        agg_call = call.arg("aggregate")
-        agg_field = None
-        if agg_call is not None:
-            if not isinstance(agg_call, Call) or agg_call.name not in ("Sum", "Count"):
-                raise PQLError("GroupBy aggregate must be Sum(...) or Count(...)")
-            if agg_call.name == "Sum":
-                agg_field = idx.field(agg_call.arg("field") or agg_call.arg("_field"))
-        limit = call.arg("limit")
-        if self.remote:
-            limit = None
-
-        shard_list = self._shards(idx, shards)
+        with query_stage("plan"):
+            if not call.children:
+                raise PQLError("GroupBy requires at least one Rows child")
+            rows_calls = [c for c in call.children if c.name == "Rows"]
+            if len(rows_calls) != len(call.children):
+                raise PQLError("GroupBy children must be Rows calls")
+            fields = [idx.field(self._field_name(c)) for c in rows_calls]
+            filter_call = call.arg("filter")
+            agg_call = call.arg("aggregate")
+            agg_field = None
+            if agg_call is not None:
+                if not isinstance(agg_call, Call) or \
+                        agg_call.name not in ("Sum", "Count"):
+                    raise PQLError(
+                        "GroupBy aggregate must be Sum(...) or Count(...)")
+                if agg_call.name == "Sum":
+                    agg_field = idx.field(agg_call.arg("field")
+                                          or agg_call.arg("_field"))
+            limit = None if self.remote else call.arg("limit")
+            shard_list = self._shards(idx, shards)
         if not shard_list:
             return []
         sts = [stacked_set(f, shard_list, timeq.VIEW_STANDARD) for f in fields]
@@ -1157,9 +1165,8 @@ class Executor:
         agg_st = stacked_bsi(agg_field, shard_list) if agg_field is not None else None
 
         dense = self._groupby_dense_ok(sts, agg_st)
-        M.REGISTRY.count(M.METRIC_GROUPBY_ROUTE,
-                         route="dense" if dense else "fold",
-                         fields=_FIELDS_LABEL[min(len(sts), 4) - 1])
+        # the sampled tree says which body served the GroupBy
+        active_span().set_tag("groupby_route", "dense" if dense else "fold")
         if dense:
             return self._groupby_dense(fields, sts, filt, agg_field, agg_st, limit)
         return self._groupby_fold(fields, sts, filt, agg_field, agg_st, limit)
@@ -1386,8 +1393,9 @@ class Executor:
             keys = [(r,) for r in sts[0].row_ids[lo:hi]]
             keyed_all.extend(self._fold_levels(
                 sts, group_planes, keys, agg_st))
-        keyed_all.sort(key=lambda kv: kv[0])
-        return self._groupby_emit(fields, keyed_all, agg_field, limit)
+        with query_stage("emit"):
+            keyed_all.sort(key=lambda kv: kv[0])
+            return self._groupby_emit(fields, keyed_all, agg_field, limit)
 
     def _fold_levels(self, sts, group_planes, keys, agg_st) -> List[Tuple]:
         """Fold one batch of level-0 group planes through the remaining

@@ -33,6 +33,7 @@ import jax.numpy as jnp
 from pilosa_tpu import platform
 from pilosa_tpu.core import timeq
 from pilosa_tpu.obs import devprof
+from pilosa_tpu.obs.stages import query_stage
 from pilosa_tpu.core.stacked import stacked_set
 from pilosa_tpu.ops import bitmap as B
 from pilosa_tpu.ops import pallas_util as PU
@@ -105,22 +106,29 @@ def scratch_plane(total_words: int) -> jnp.ndarray:
 
 
 # ---------------------------------------------------------------------------
-# Lowering: call tree -> (tape, leaves). Mirrors executor._eval_all /
-# _eval_row bit-for-bit for the families it accepts; everything else
-# bails. Leaf refs are ("L", i) and op refs ("O", j) during lowering and
-# are remapped to flat register indices afterwards (leaves occupy
-# registers [0, n); op j lands at n + j).
+# Lowering: call tree -> (tape, leaf sources), then sources -> leaf planes.
+# Mirrors executor._eval_all / _eval_row bit-for-bit for the families it
+# accepts; everything else bails. The first half is host work alone (the
+# read's ``plan`` stage): it walks the tree, resolves fields, keys and
+# views, and says what each leaf plane is made from; the second looks the
+# stacks up and slices or computes the planes on the device. Leaf refs
+# are ("L", i) and op refs ("O", j) during lowering and are remapped to
+# flat register indices afterwards (leaves occupy registers [0, n); op j
+# lands at n + j).
 # ---------------------------------------------------------------------------
 
 
-def _lower_root(ex, idx, call, shard_list: List[int]):
-    total_words = len(shard_list) * WORDS_PER_SHARD
-    leaves: List = []
+def _plan_root(ex, idx, call) -> Tuple[Tuple, List[Tuple]]:
+    """``(tape, sources)``: one source a leaf register, in register
+    order: ``("row", field, view, row)``, ``("bsi", field, value)``,
+    ``("exists",)`` or ``("zeros",)``. Raises ``_Bail`` for a tree the
+    tape cannot express."""
+    sources: List[Tuple] = []
     tape_raw: List[Tuple] = []
 
-    def leaf(plane):
-        leaves.append(plane)
-        return ("L", len(leaves) - 1)
+    def leaf(*source):
+        sources.append(source)
+        return ("L", len(sources) - 1)
 
     def emit(op, a, b):
         tape_raw.append((op, a, b))
@@ -137,22 +145,20 @@ def _lower_root(ex, idx, call, shard_list: List[int]):
         if isinstance(value, Condition) or field.options.type.is_bsi:
             # the BSI compare circuit is one jitted program of its own;
             # its output plane composes as a leaf
-            return leaf(ex._eval_bsi_row(field, value, shard_list))
+            return leaf("bsi", field, value)
         row = ex._row_id(field, value)
         if row is None:  # unknown key -> empty row
-            return leaf(B.device_zeros(total_words))
+            return leaf("zeros")
         from_a, to_a = c.arg("from"), c.arg("to")
         if from_a is not None or to_a is not None:
             views = field.range_views(
                 _parse_ts(from_a) if from_a is not None else None,
                 _parse_ts(to_a) if to_a is not None else None)
-            out = leaf(B.device_zeros(total_words))
+            out = leaf("zeros")
             for v in views:
-                st = stacked_set(field, shard_list, v)
-                out = emit("or", out, leaf(st.row_plane(row)))
+                out = emit("or", out, leaf("row", field, v, row))
             return out
-        st = stacked_set(field, shard_list, timeq.VIEW_STANDARD)
-        return leaf(st.row_plane(row))
+        return leaf("row", field, timeq.VIEW_STANDARD, row)
 
     def lower(c):
         name = c.name
@@ -160,7 +166,7 @@ def _lower_root(ex, idx, call, shard_list: List[int]):
             return lower_row(c)
         if name in ("Union", "Xor"):
             if not c.children:
-                return leaf(B.device_zeros(total_words))
+                return leaf("zeros")
             refs = [lower(ch) for ch in c.children]
             out = refs[0]
             opn = "or" if name == "Union" else "xor"
@@ -185,14 +191,14 @@ def _lower_root(ex, idx, call, shard_list: List[int]):
         if name == "Not":
             if len(c.children) != 1:
                 raise _Bail
-            ex_ref = leaf(ex._existence_all(idx, shard_list))
+            ex_ref = leaf("exists")
             return emit("andnot", ex_ref, lower(c.children[0]))
         if name == "All":
-            return leaf(ex._existence_all(idx, shard_list))
+            return leaf("exists")
         raise _Bail
 
     root = lower(call)
-    n = len(leaves)
+    n = len(sources)
 
     def remap(ref):
         return ref[1] if ref[0] == "L" else n + ref[1]
@@ -203,7 +209,36 @@ def _lower_root(ex, idx, call, shard_list: List[int]):
         # the program returns the LAST register; or(x, x) == x pins the
         # root there when it isn't already (bare-leaf roots)
         tape = tape + (("or", root_idx, root_idx),)
-    return tape, leaves
+    return tape, sources
+
+
+def _leaf_planes(ex, idx, sources: List[Tuple], shard_list: List[int]):
+    """The leaf planes ``sources`` name, in register order: slices of
+    the resident stacks, BSI compares, the existence row, zeros."""
+    total_words = len(shard_list) * WORDS_PER_SHARD
+    planes = []
+    for kind, *args in sources:
+        if kind == "row":
+            field, view, row = args
+            planes.append(stacked_set(field, shard_list, view).row_plane(row))
+        elif kind == "bsi":
+            planes.append(ex._eval_bsi_row(*args, shard_list))
+        elif kind == "exists":
+            planes.append(ex._existence_all(idx, shard_list))
+        else:
+            planes.append(B.device_zeros(total_words))
+    return planes
+
+
+def _lower(ex, idx, call, shard_list: List[int]):
+    """``(tape, leaf planes)``, or None when the tree bails; the host
+    half runs as the read's ``plan`` stage."""
+    with query_stage("plan"):
+        try:
+            tape, sources = _plan_root(ex, idx, call)
+        except _Bail:
+            return None
+    return tape, _leaf_planes(ex, idx, sources, shard_list)
 
 
 # ---------------------------------------------------------------------------
@@ -229,10 +264,10 @@ def run_count(ex, idx, call, shard_list: List[int], mask) -> Optional[object]:
     or None when lowering bails/is disabled."""
     if not ENABLED or not shard_list:
         return None
-    try:
-        tape, leaves = _lower_root(ex, idx, call, shard_list)
-    except _Bail:
+    lowered = _lower(ex, idx, call, shard_list)
+    if lowered is None:
         return None
+    tape, leaves = lowered
     total_words = len(shard_list) * WORDS_PER_SHARD
     masked = mask is not None
     fn = _program("count", tape, len(leaves), masked, total_words)
@@ -260,10 +295,10 @@ def run_plane(ex, idx, call, shard_list: List[int], mask) -> Optional[object]:
     program with donated scratch, or None when lowering bails."""
     if not ENABLED or not shard_list:
         return None
-    try:
-        tape, leaves = _lower_root(ex, idx, call, shard_list)
-    except _Bail:
+    lowered = _lower(ex, idx, call, shard_list)
+    if lowered is None:
         return None
+    tape, leaves = lowered
     total_words = len(shard_list) * WORDS_PER_SHARD
     masked = mask is not None
     fn = _program("plane", tape, len(leaves), masked, total_words)

@@ -33,8 +33,9 @@ from typing import Optional, Tuple
 from pilosa_tpu.api import API
 from pilosa_tpu.errors import (AdmissionError, ClusterStateError,
                                QueryDeadlineError)
+from pilosa_tpu.analysis import locktrace
 from pilosa_tpu.obs import metrics as M
-from pilosa_tpu.obs.stages import record_stage
+from pilosa_tpu.obs.stages import query_stage, record_stage
 from pilosa_tpu.obs.tracing import annotate
 
 _ROUTES = [
@@ -196,6 +197,55 @@ _ROUTES = [
 _AUTH_EXEMPT = {"get_login", "get_redirect", "get_logout",
                 "get_version", "get_health", "get_userinfo"}
 
+# A monitor's scrapes and probes are not requests the server holds for a
+# user: they leave http_requests_in_flight and the idle seconds alone.
+_PROBES = {"get_metrics", "get_metrics_json", "get_health", "get_status",
+           "get_version", "get_info"}
+
+
+class InFlight:
+    """Requests the process's HTTP servers hold, and the seconds during
+    which they held none: ``http_requests_in_flight`` (gauge) and
+    ``http_server_idle_seconds_total``. An idle stretch is counted when
+    it closes, and ``flush`` adds the stretch still open, so a scrape
+    that flushes first reads the seconds up to itself. A counter and
+    not a profiler leaf: a thread blocked reading the next keep-alive
+    request would own every other thread's seconds."""
+
+    def __init__(self, clock=time.perf_counter,
+                 registry: Optional[M.MetricsRegistry] = None) -> None:
+        self._clock = clock
+        self._reg = registry if registry is not None else M.REGISTRY
+        self._lock = locktrace.tracked_lock("server.http.in_flight")
+        self._held = 0
+        self._idle_since = clock()
+
+    def enter(self) -> None:
+        with self._lock:
+            if self._held == 0:
+                self._reg.count(M.METRIC_HTTP_SERVER_IDLE_SECONDS,
+                                self._clock() - self._idle_since)
+            self._held += 1
+            self._reg.gauge(M.METRIC_HTTP_IN_FLIGHT, self._held)
+
+    def leave(self) -> None:
+        with self._lock:
+            self._held -= 1
+            if self._held == 0:
+                self._idle_since = self._clock()
+            self._reg.gauge(M.METRIC_HTTP_IN_FLIGHT, self._held)
+
+    def flush(self) -> None:
+        with self._lock:
+            if self._held == 0:
+                now = self._clock()
+                self._reg.count(M.METRIC_HTTP_SERVER_IDLE_SECONDS,
+                                now - self._idle_since)
+                self._idle_since = now
+
+
+IN_FLIGHT = InFlight()
+
 
 def _token_cookies(access: str, refresh: str, expire: bool = False,
                    secure: bool = False):
@@ -293,9 +343,9 @@ class Handler(BaseHTTPRequestHandler):
             if isinstance(payload, dict):
                 payload = dict(payload)
                 payload["trace"] = sp.to_json()
-        with annotate("http.encode"):
+        with query_stage("encode"):
             data = json.dumps(payload).encode()
-        with annotate("http.write"):
+        with query_stage("write"):
             self.send_response(code)
             self.send_header("Content-Type", "application/json")
             self.send_header("Content-Length", str(len(data)))
@@ -392,6 +442,9 @@ class Handler(BaseHTTPRequestHandler):
                     sp = self._trace_span
                     if sp is not None and sp.recording:
                         sp.set_tag("tenant", tenant)
+                held = name not in _PROBES
+                if held:
+                    IN_FLIGHT.enter()
                 try:
                     if self.auth is not None and name not in _AUTH_EXEMPT:
                         self._check_auth(name, match)
@@ -420,6 +473,8 @@ class Handler(BaseHTTPRequestHandler):
                 except Exception as e:  # pragma: no cover - last resort
                     self._send(500, {"error": f"{type(e).__name__}: {e}"})
                 finally:
+                    if held:
+                        IN_FLIGHT.leave()
                     # a span _send never consumed (handler wrote its own
                     # response) must still finish, or its scope would
                     # leak into the next keep-alive request
@@ -530,13 +585,16 @@ class Handler(BaseHTTPRequestHandler):
 
             with get_tracer().profile("sql.profile") as root:
                 res = self.api.sql(text, parsed=parsed)
-            out = res.to_json()
+            with query_stage("render"):
+                out = res.to_json()
             out["profile"] = root.to_json()
             if cache is not None and cache.take_stale_flag():
                 out["stale"] = True
             self._send(200, out)
             return
-        out = self.api.sql(text, parsed=parsed).to_json()
+        res = self.api.sql(text, parsed=parsed)
+        with query_stage("render"):
+            out = res.to_json()
         if cache is not None and cache.take_stale_flag():
             # brownout: SELECT served past its version fingerprint
             out["stale"] = True
@@ -729,6 +787,7 @@ class Handler(BaseHTTPRequestHandler):
     def get_metrics(self):
         from pilosa_tpu.obs.metrics import REGISTRY
 
+        IN_FLIGHT.flush()
         body = REGISTRY.prometheus_text().encode()
         self.send_response(200)
         self.send_header("Content-Type", "text/plain; version=0.0.4")
@@ -739,6 +798,7 @@ class Handler(BaseHTTPRequestHandler):
     def get_metrics_json(self):
         from pilosa_tpu.obs.metrics import REGISTRY
 
+        IN_FLIGHT.flush()
         self._send(200, REGISTRY.as_json())
 
     def get_query_history(self):

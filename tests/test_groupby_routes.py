@@ -1,8 +1,8 @@
 """GroupBy of one to four fields on time-ordered, skewed data, both routes
 (the dense count tensor below the cell cap, the pruning fold above it)
 held to one plain numpy reference written here, and what the tracing
-says of them: the route counter, the ``groupby.level`` spans, the host
-fetches, the group-plane bytes and the decode counters."""
+says of them: the route tag on the sampled tree, the ``groupby.level``
+spans, the host fetches, the group-plane bytes and the decode counters."""
 
 import numpy as np
 import pytest
@@ -11,6 +11,7 @@ from pilosa_tpu.api import API
 from pilosa_tpu.core import FieldOptions, FieldType, Holder
 from pilosa_tpu.core import stacked as stx
 from pilosa_tpu.obs import metrics as M
+from pilosa_tpu.obs import tracing as T
 from pilosa_tpu.ops import ctiles
 from pilosa_tpu.ops import pallas_util as PU
 from pilosa_tpu.pql import Executor
@@ -123,9 +124,6 @@ def test_groupby_equals_the_numpy_reference(n_fields, mode, monkeypatch,
     names = ORDER[:n_fields]
     # a Sum over three or more fields is the fold's at any size
     route = "fold" if above or (with_sum and n_fields > 2) else "dense"
-    label = str(n_fields) if n_fields < 4 else "4+"
-    before = M.REGISTRY.value(M.METRIC_GROUPBY_ROUTE, route=route,
-                              fields=label)
     text = "GroupBy(" + ", ".join(f"Rows({f})" for f in names)
     # the filter names a field the GroupBy does not group by
     by = "w" if n_fields < 4 else "y"
@@ -138,11 +136,17 @@ def test_groupby_equals_the_numpy_reference(n_fields, mode, monkeypatch,
         text += ", aggregate=Sum(field=v)"
     if limit is not None:
         text += f", limit={limit}"
-    got = answer(Executor(h).execute("t", text + ")")[0])
+    tracer = T.Tracer(enabled=True, sample_rate=1.0)
+    prev = T.get_tracer()
+    T.set_tracer(tracer)
+    try:
+        with tracer.start_trace("query.pql") as root:
+            got = answer(Executor(h).execute("t", text + ")")[0])
+    finally:
+        T.set_tracer(prev)
     assert got == reference(slots, v, names, where, with_sum, limit)
     assert len(got) > 1
-    assert M.REGISTRY.value(M.METRIC_GROUPBY_ROUTE, route=route,
-                            fields=label) == before + 1
+    assert root.tags["groupby_route"] == route
     # a two-field Sum is pair_sums': one kernel dispatch a pair of blocks
     assert M.REGISTRY.value(
         M.METRIC_OPS_PALLAS_DISPATCH, kernel="pair_sums") - sums0 == (

@@ -269,16 +269,26 @@ def test_key_form_answers_as_the_dense_form_and_numpy(monkeypatch, one_chip):
     # every walk
     monkeypatch.setattr(stx, "BUDGET", stx.DeviceBudget(80 << 20))
     api = serve(d)
-    k0 = M.REGISTRY.value(M.METRIC_STACK_KEY_ROWS, kind="block")
-    r0 = M.REGISTRY.value(M.METRIC_STACK_KEY_ROWS, kind="rows")
+    # a walk's whole blocks and a point read's few rows are both derived
+    derived = {"block": 0, "rows": 0}
+
+    def counted(kind, fn):
+        def derive(*args):
+            derived[kind] += 1
+            return fn(*args)
+        return derive
+
+    monkeypatch.setattr(K, "key_rows", counted("block", K.key_rows))
+    monkeypatch.setattr(K, "key_rows_at", counted("rows", K.key_rows_at))
+    b0 = M.REGISTRY.value(M.METRIC_STACK_KEY_ROWS_BYTES)
     keyed = answers(api, d)
     st = brand_stack(api)
     assert type(st) is stx.KeyedSet
     assert (st.block_rows, st.cap, st.bits) == (128, 1024, 11)
     assert st._keys.shape == (16, SHARDS * WORDS_PER_SHARD)
     assert keyed == dense
-    assert M.REGISTRY.value(M.METRIC_STACK_KEY_ROWS, kind="block") > k0
-    assert M.REGISTRY.value(M.METRIC_STACK_KEY_ROWS, kind="rows") > r0
+    assert derived["block"] > 0 and derived["rows"] > 0
+    assert M.REGISTRY.value(M.METRIC_STACK_KEY_ROWS_BYTES) > b0
     assert stx.BUDGET.used <= stx.BUDGET.cap
     stx.BUDGET.audit()
 

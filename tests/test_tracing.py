@@ -19,12 +19,14 @@ import urllib.request
 import pytest
 
 from pilosa_tpu.obs import metrics as M
+from pilosa_tpu.obs import stages
 from pilosa_tpu.obs.metrics import MetricsRegistry
 from pilosa_tpu.obs import tracing as T
 from pilosa_tpu.obs.tracing import (NOP_SPAN, NopTracer, Span, TraceStore,
                                     Tracer, current_span,
                                     current_traceparent, format_traceparent,
                                     parse_traceparent, span_scope)
+from pilosa_tpu.server.http import InFlight
 
 
 @pytest.fixture
@@ -484,7 +486,8 @@ class TestMetricsExposition:
 #: of the sampled tree only, and waits (a reader behind the write lock, a
 #: writer behind a reader's build) are counters only: both would take an
 #: idle gap's seconds from the event that did the work
-READ_LEAVES = ("http.read", "pql.parse", "http.encode", "http.write")
+READ_LEAVES = ("http.read", "pql.parse", "pql.plan", "pql.emit",
+               "pql.render", "http.encode", "http.write")
 WRITE_LEAVES = ("import.decode", "import.key_translate",
                 "import.fragment_advance", "import.wal_commit",
                 "checkpoint.serialize", "checkpoint.fsync",
@@ -500,6 +503,12 @@ class TestAnnotateNopPath:
                id(t.start_trace("query.pql")), id(NOP_SPAN)}
         assert got == {id(NOP_SPAN)}
 
+    @pytest.mark.parametrize("stage", ["plan", "emit", "render"])
+    def test_a_read_stage_leaf_is_the_shared_span(self, nop_global, stage):
+        assert T.annotate(stages.QUERY_STAGE_LEAVES[stage]) is NOP_SPAN
+        with stages.query_stage(stage) as region:
+            assert region._leaf is NOP_SPAN
+
     def test_no_session_allocates_nothing(self, nop_global):
         import tracemalloc
 
@@ -508,8 +517,11 @@ class TestAnnotateNopPath:
         def request():
             with T.annotate("http.read"):
                 pass
-            with T.annotate("pql.parse"), t.start_span("pql.parse"):
+            with stages.query_stage("parse"), t.start_span("pql.parse"):
                 pass
+            for stage in ("plan", "emit", "render", "encode", "write"):
+                with stages.query_stage(stage):
+                    pass
             with t.start_span("pql.fetch"):
                 pass
 
@@ -522,7 +534,8 @@ class TestAnnotateNopPath:
             after = tracemalloc.take_snapshot()
         finally:
             tracemalloc.stop()
-        here = [tracemalloc.Filter(True, T.__file__)]
+        here = [tracemalloc.Filter(True, T.__file__),
+                tracemalloc.Filter(True, stages.__file__)]
         grown = sum(s.size_diff for s in after.filter_traces(here)
                     .compare_to(before.filter_traces(here), "filename"))
         assert grown == 0
@@ -547,12 +560,28 @@ class TestAnnotateNopPath:
             pass
 
 
+def _settled():
+    """Wait until the server holds no request: a response's last stage
+    is counted after the client has read it."""
+    deadline = time.monotonic() + 10.0
+    while M.REGISTRY.value(M.METRIC_HTTP_IN_FLIGHT) and \
+            time.monotonic() < deadline:
+        time.sleep(0.01)
+
+
+def _stage_seconds():
+    return {s: M.REGISTRY.value(M.METRIC_QUERY_STAGE_SECONDS, stage=s)
+            for s in stages.QUERY_STAGE_LEAVES}
+
+
 @pytest.fixture(scope="module")
-def host_trace(tmp_path_factory):
+def host_session(tmp_path_factory):
     """One ``jax.profiler`` session on the CPU backend over a served
-    import (keyed set field and int field), two served reads and a
-    checkpoint. Returns {thread line: [(name, start_ns, end_ns), ...]}
-    of the host plane."""
+    import (keyed set field, set field and int field), served reads (a
+    Count, a Sum, a keyed GroupBy of two fields with a Sum, a TopN, a
+    SQL count) and a checkpoint. Returns ({thread line: [(name,
+    start_ns, end_ns), ...]} of the host plane, {stage: seconds the
+    ``query_stage_seconds_total`` counter grew by over the session})."""
     import glob
 
     import jax
@@ -579,26 +608,44 @@ def host_trace(tmp_path_factory):
         post("/index/i/import", {
             "field": "f", "cols": [c + offset for c in cols],
             "rowKeys": [f"k{c % 7}" for c in cols]})
+        post("/index/i/import", {
+            "field": "g", "cols": [c + offset for c in cols],
+            "rows": [c % 3 for c in cols]})
         post("/index/i/import-values", {
             "field": "v", "cols": [c + offset for c in cols],
             "values": [c % 1000 for c in cols]})
         return (post("/index/i/query", "Count(Row(f=k1))")["results"],
                 post("/index/i/query", "Sum(field=v)")["results"][0]["count"])
 
+    def reads():
+        groups, = post("/index/i/query", "GroupBy(Rows(f), Rows(g), "
+                       "aggregate=Sum(field=v))")["results"]
+        pairs, = post("/index/i/query", "TopN(f, n=3)")["results"]
+        sql = post("/sql", "SELECT COUNT(*) FROM i WHERE v < 500")["data"]
+        return (len(groups), sum(g["count"] for g in groups),
+                len(pairs["rows"]), sql)
+
     try:
         post("/index/i", {})
         post("/index/i/field/f", {"options": {"type": "set", "keys": True}})
+        post("/index/i/field/g", {"options": {"type": "set"}})
         post("/index/i/field/v",
              {"options": {"type": "int", "min": 0, "max": 1000}})
         load(0)  # compiles outside the session
+        reads()
+        _settled()
+        before = _stage_seconds()
         opts = jax.profiler.ProfileOptions()
         opts.python_tracer_level = 0
         jax.profiler.start_trace(str(root / "trace"), profiler_options=opts)
         try:
             assert load(len(cols)) == ([2 * 572], 2 * len(cols))
+            assert reads() == (21, 2 * len(cols), 3, [[2 * 2000]])
             api.holder.checkpoint()
+            _settled()
         finally:
             jax.profiler.stop_trace()
+        after = _stage_seconds()
     finally:
         srv.shutdown()
         srv.server_close()
@@ -613,7 +660,12 @@ def host_trace(tmp_path_factory):
                       for e in line.events]
             if events:
                 lines[f"{line.name}#{i}"] = events
-    return lines
+    return lines, {s: after[s] - before[s] for s in after}
+
+
+@pytest.fixture(scope="module")
+def host_trace(host_session):
+    return host_session[0]
 
 
 def _ours(events):
@@ -644,7 +696,8 @@ class TestAnnotationsOnTheProfilersClock:
     def test_no_annotation_of_ours_encloses_a_jax_call(self, host_trace):
         # import.fragment_advance may enclose the device scatter's
         # PjitFunction (accepted: it is the stage's own dispatch)
-        jax_named = ("PjitFunction(", "np.asarray(", "ArrayImpl.")
+        jax_named = ("PjitFunction(", "np.asarray(", "ArrayImpl.",
+                     "shard_args")
         for line, events in host_trace.items():
             inner = [e for e in events if e[0].startswith(jax_named)]
             for name, start, end in _ours(events):
@@ -653,3 +706,113 @@ class TestAnnotationsOnTheProfilersClock:
                 for j, j_start, j_end in inner:
                     assert not (start <= j_start and j_end <= end), \
                         f"{name} encloses {j} on {line}"
+
+
+class TestReadStageCounters:
+    #: the counter's clock starts before its leaf and stops after it; the
+    #: profiler's and ``perf_counter``'s clocks agree to far better than 1%
+    TOLERANCE = 0.01
+
+    @pytest.mark.parametrize("stage", sorted(stages.QUERY_STAGE_LEAVES))
+    def test_counter_holds_at_least_what_its_leaf_shows(self, host_session,
+                                                        stage):
+        lines, grown = host_session
+        leaf = stages.QUERY_STAGE_LEAVES[stage]
+        traced = [(end - start) / 1e9 for events in lines.values()
+                  for name, start, end in events if name == leaf]
+        assert traced, f"no {leaf} in the session"
+        assert grown[stage] >= sum(traced) * (1 - self.TOLERANCE)
+
+
+class TestServerIdleSeconds:
+    def test_counts_only_while_nothing_is_held(self):
+        reg = MetricsRegistry()
+        now = [100.0]
+        held = InFlight(clock=lambda: now[0], registry=reg)
+
+        def idle():
+            held.flush()
+            return reg.value(M.METRIC_HTTP_SERVER_IDLE_SECONDS)
+
+        now[0] += 2.0
+        assert idle() == 2.0           # the open stretch, up to the flush
+        now[0] += 1.0
+        held.enter()                   # closes the stretch: 3 s in all
+        assert reg.value(M.METRIC_HTTP_IN_FLIGHT) == 1
+        held.enter()
+        now[0] += 5.0
+        held.leave()
+        assert idle() == 3.0           # one request is still held
+        now[0] += 4.0
+        held.leave()
+        assert reg.value(M.METRIC_HTTP_IN_FLIGHT) == 0
+        now[0] += 0.5
+        assert idle() == 3.5
+
+    @pytest.fixture
+    def served(self, tmp_path, monkeypatch):
+        from pilosa_tpu.api import API
+        from pilosa_tpu.server import http as H
+
+        release = threading.Event()
+        entered = threading.Event()
+        real = H.Handler.get_schema
+
+        def held_schema(handler):
+            entered.set()
+            release.wait(10.0)
+            real(handler)
+
+        monkeypatch.setattr(H.Handler, "get_schema", held_schema)
+        srv, _ = H.serve(API(str(tmp_path / "data")), port=0,
+                         background=True)
+        base = f"http://127.0.0.1:{srv.server_address[1]}"
+        yield base, entered, release
+        release.set()
+        srv.shutdown()
+        srv.server_close()
+
+    @staticmethod
+    def _scraped(base):
+        with urllib.request.urlopen(base + "/metrics") as r:
+            text = r.read().decode()
+        idle = held = None
+        for line in text.splitlines():
+            if line.startswith("pilosa_" + M.METRIC_HTTP_SERVER_IDLE_SECONDS):
+                idle = float(line.split()[-1])
+            if line.startswith("pilosa_" + M.METRIC_HTTP_IN_FLIGHT + " "):
+                held = float(line.split()[-1])
+        return idle, held
+
+    def test_a_scrape_includes_the_open_stretch(self, served):
+        base, _, _ = served
+        first, _ = self._scraped(base)
+        time.sleep(0.3)
+        second, held = self._scraped(base)
+        # scrapes are probes: the server held nothing in between
+        assert second - first >= 0.29
+        assert not held
+
+    def test_stands_still_while_a_request_is_held(self, served):
+        base, entered, release = served
+        got = []
+        t = threading.Thread(target=lambda: got.append(
+            urllib.request.urlopen(base + "/schema").status))
+        t.start()
+        try:
+            assert entered.wait(10.0)
+            first, held = self._scraped(base)
+            assert held == 1
+            time.sleep(0.3)
+            second, held = self._scraped(base)
+            assert held == 1
+            assert second == first
+        finally:
+            release.set()
+            t.join(10.0)
+        assert got == [200]
+        _settled()
+        time.sleep(0.2)
+        third, held = self._scraped(base)
+        assert held == 0
+        assert third - second >= 0.19
