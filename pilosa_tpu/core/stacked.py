@@ -551,11 +551,14 @@ class StackedSet:
             return blk.decode(rows=[slot % self.block_rows])[0]
         return blk[slot % self.block_rows]
 
-    def take_rows(self, rows: Sequence[int]) -> jax.Array:
+    def take_rows(self, rows: Sequence[int],
+                  pad_to: Optional[int] = None) -> jax.Array:
         """Device ``[len(rows), S*W]`` gather of the given row ids (zero
-        planes for absent rows), assembled block-locally."""
+        planes for absent rows), assembled block-locally. With ``pad_to``
+        the gather has ``pad_to`` rows, those past ``len(rows)`` zero, and
+        every block it reads gathers that many: its programs' shapes
+        depend on ``pad_to`` alone, not on how the rows fall in blocks."""
         n = len(rows)
-        out_parts: List[Tuple[np.ndarray, jax.Array]] = []
         by_block: Dict[int, Tuple[List[int], List[int]]] = {}
         missing: List[int] = []
         for i, r in enumerate(rows):
@@ -566,6 +569,8 @@ class StackedSet:
             dst, src = by_block.setdefault(slot // self.block_rows, ([], []))
             dst.append(i)
             src.append(slot % self.block_rows)
+        if pad_to is not None:
+            return self._take_padded(by_block, pad_to)
         if len(by_block) == 1 and not missing:
             bi, (dst, src) = next(iter(by_block.items()))
             blk = self._ensure_block(bi)
@@ -575,6 +580,25 @@ class StackedSet:
         for bi, (dst, src) in by_block.items():
             sel = _take(self._ensure_block(bi), src)
             out = out.at[jnp.asarray(dst, dtype=jnp.int32)].set(sel)
+        return out
+
+    def _take_padded(self, by_block, n: int) -> jax.Array:
+        """``take_rows(..., pad_to=n)``: a block's rows gathered ``n`` at a
+        time (index 0 where the row is not the block's) and selected
+        where they belong over zeros, so however many blocks the rows
+        fall in, each costs the same three programs; the gather keeps
+        the block's word sharding."""
+        out = None
+        for bi, (dst, src) in by_block.items():
+            idx = np.zeros(n, dtype=np.int32)
+            idx[dst] = src
+            keep = np.zeros((n, 1), dtype=bool)
+            keep[dst] = True
+            sel = _take(self._ensure_block(bi), idx)
+            out = jnp.where(keep, sel,
+                            jnp.zeros_like(sel) if out is None else out)
+        if out is None:
+            return jnp.zeros((n, self.total_words), dtype=jnp.uint32)
         return out
 
     def rows_plane(self, rows: Sequence[int]) -> jax.Array:
@@ -784,16 +808,21 @@ class KeyedSet(StackedSet):
             return self.zero_plane()
         return self._rows_at([slot])[0]
 
-    def take_rows(self, rows: Sequence[int]) -> jax.Array:
+    def take_rows(self, rows: Sequence[int],
+                  pad_to: Optional[int] = None) -> jax.Array:
+        """Only the rows asked for are derived (``_rows_at``); absent
+        rows and the pad are zero planes (with ``pad_to``, selected
+        whether or not there are any, so the programs are the same)."""
+        n = len(rows) if pad_to is None else pad_to
         slots = [self.row_index.get(r) for r in rows]
-        present = [s for s in slots if s is not None]
-        if not present:
-            return jnp.zeros((len(rows), self.total_words), dtype=jnp.uint32)
+        slots += [None] * (n - len(slots))
+        keep = np.array([s is not None for s in slots], dtype=bool)[:, None]
+        if not keep.any():
+            return jnp.zeros((n, self.total_words), dtype=jnp.uint32)
         out = self._rows_at([0 if s is None else s for s in slots])
-        if len(present) < len(slots):
-            absent = [i for i, s in enumerate(slots) if s is None]
-            out = out.at[jnp.asarray(absent, dtype=jnp.int32)].set(0)
-        return out
+        if pad_to is None and keep.all():
+            return out
+        return jnp.where(keep, out, 0)
 
     def rows_plane(self, rows: Sequence[int]) -> jax.Array:
         slots = sorted({self.row_index[r] for r in rows

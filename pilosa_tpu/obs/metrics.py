@@ -247,6 +247,10 @@ METRIC_COMPRESS_DECODE_BYTES = "device_compress_decode_bytes_total"
 # device
 METRIC_GROUPBY_HOST_FETCHES = "groupby_host_fetches_total"
 METRIC_GROUPBY_GROUP_PLANE_BYTES = "groupby_group_plane_bytes_total"
+# rows of a dense two-field Sum GroupBy's last field that pair_sums took
+# (state=summed, pad rows not counted) or left out because no group
+# counted a record in them (state=skipped), a pass over each group block
+METRIC_GROUPBY_SUM_ROWS = "groupby_sum_rows_total"
 # cluster health plane (obs/timeline.py + slo.py + flight.py): samples
 # appended to the in-memory timeline ring, per-objective error-budget
 # burn rate over the fast/slow windows (gauge {slo=,window=}), and

@@ -42,7 +42,7 @@ from pilosa_tpu.core.stacked import (StackedBSI, StackedSet,
                                      stacked_set, sync_part, writer_wait)
 from pilosa_tpu.obs import metrics as M
 from pilosa_tpu.obs.stages import query_stage
-from pilosa_tpu.obs.tracing import active_span, get_tracer
+from pilosa_tpu.obs.tracing import active_span, annotate, get_tracer
 from pilosa_tpu.ops import bitmap as B
 from pilosa_tpu.ops import bsi as S
 from pilosa_tpu.ops import topk as T
@@ -219,6 +219,11 @@ def _tag_route(span, planes, blk) -> None:
     route, body = pair_counts_route(planes, blk)
     span.set_tag("route", route)
     span.set_tag("body", body)
+
+
+def _pow2_rows(n: int) -> int:
+    """``n`` rows padded to a power of two of at least a sublane tile."""
+    return max(8, 1 << (n - 1).bit_length())
 
 
 def _made(planes):
@@ -1181,9 +1186,11 @@ class Executor:
         block at a time, but the dense OUTPUT is unbounded by either).
         The product chooses the route of a count, whatever the number of
         fields. A Sum over three or more fields stays with the fold:
-        dense, ``pair_sums`` scans the magnitude planes of every cell,
-        live or not, and the fold sums the live groups only (SSB Q3.1
-        keeps 25 of its 625 leading pairs behind its region filters)."""
+        dense, ``pair_sums`` scans the magnitude planes of every group
+        plane of the leading fields, live or not (it skips only dead rows
+        of the last field, :meth:`_live_sums`), and the fold sums the live
+        groups only (SSB Q3.1 keeps 25 of its 625 leading pairs behind
+        its region filters)."""
         cells = 1
         for st in sts:
             cells *= st.cap
@@ -1266,32 +1273,50 @@ class Executor:
             return _Deferred(arrays, fin1)
 
         if agg_st is not None:
-            mags, pos_m, neg_m = self._agg_masks(agg_st)
+            masks = self._agg_masks(agg_st)
         last = sts[-1]
-        slot_rows, count_rows, p_rows, ng_rows = [], [], [], []
+        slot_rows, count_rows, sum_parts = [], [], []
 
         def count(slots, planes):
-            c_cols, p_cols, ng_cols = [], [], []
-            for _, b_blk in last.iter_blocks():
-                c_cols.append(sync_part(pair_counts(planes, b_blk)))
-                if agg_st is not None:
-                    p, ng = pair_sums(planes, b_blk, mags, pos_m, neg_m)
-                    p_cols.append(sync_part(p))
-                    ng_cols.append(ng)
+            c = _concat([sync_part(pair_counts(planes, b_blk))
+                         for _, b_blk in last.iter_blocks()], axis=1)
             slot_rows.append(slots)
-            count_rows.append(_concat(c_cols, axis=1))
-            if agg_st is not None:
-                p_rows.append(_concat(p_cols, axis=2))
-                ng_rows.append(_concat(ng_cols, axis=2))
+            if agg_st is None:
+                count_rows.append(c)
+                return
+            # the counts say which rows of the last field any group
+            # holds: pair_sums runs over those alone
+            with annotate("groupby.live"):
+                c = np.asarray(c)
+            M.REGISTRY.count(M.METRIC_GROUPBY_HOST_FETCHES)
+            g0 = sum(r.shape[0] for r in count_rows)
+            count_rows.append(c)
+            sum_parts.extend((g0, cols, p, ng) for cols, p, ng in
+                             self._live_sums(last, planes, c, *masks))
 
         self._group_blocks(sts[:-1], filt, count)
-        counts = _concat(count_rows, axis=0)  # [groups, capLast]
-        arrays = [counts]
-        if agg_st is not None:
-            arrays += [_concat(p_rows, axis=1), _concat(ng_rows, axis=1)]
+        if agg_st is None:
+            arrays = [_concat(count_rows, axis=0)]  # [groups, capLast]
+        else:
+            arrays = [a for *_, p, ng in sum_parts for a in (p, ng)]
 
-        def fin(counts_np, p_np=None, ng_np=None):
-            M.REGISTRY.count(M.METRIC_GROUPBY_HOST_FETCHES)
+        def fin(*fetched):
+            if fetched:
+                M.REGISTRY.count(M.METRIC_GROUPBY_HOST_FETCHES)
+            if agg_st is None:
+                counts_np, p_np = fetched[0], None
+            else:
+                counts_np = np.concatenate(count_rows)
+                # each part's columns back to the slots they were taken
+                # from; a column no part took holds no group
+                shape = (masks[0].shape[0],) + counts_np.shape  # [D, G, cap]
+                p_np = np.zeros(shape, dtype=np.int64)
+                ng_np = np.zeros(shape, dtype=np.int64)
+                for (g0, cols, *_), p, ng in zip(sum_parts, fetched[0::2],
+                                                 fetched[1::2]):
+                    at = (slice(None), slice(g0, g0 + p.shape[1]), cols)
+                    p_np[at] = p[:, :, :len(cols)]
+                    ng_np[at] = ng[:, :, :len(cols)]
             slots = np.concatenate(slot_rows)  # [groups, fields - 1]
             gi, gj = np.nonzero(counts_np[:, :len(last.row_ids)])
             ids = [np.asarray(st.row_ids, dtype=np.uint64) for st in sts]
@@ -1309,6 +1334,41 @@ class Executor:
             return self._groupby_emit(fields, keyed, agg_field, limit)
 
         return _Deferred(arrays, fin)
+
+    @staticmethod
+    def _live_sums(last, planes, counts, mags, pos_m, neg_m):
+        """``[(slots, pos, neg)]``: :func:`pair_sums` of ``planes`` against
+        the rows of ``last`` whose column of ``counts`` (``[G, cap]``,
+        fetched) is nonzero; ``pos`` / ``neg`` are ``int32[D, G, r]``,
+        their first ``len(slots)`` columns those slots'. A row no group
+        holds sums to 0 and is left out. Where every row is live (an
+        unfiltered Sum) the blocks go as they are; else the live rows are
+        gathered (``take_rows``), whatever their order, in chunks of at
+        most a block padded with zero rows to a power of two of at least
+        8. A block all live among dead ones is gathered too, so a query's
+        programs depend on how many rows it leaves live, not on where in
+        the blocks they fall."""
+        n = len(last.row_ids)
+        live = np.flatnonzero(counts[:, :n].any(axis=0))
+        M.REGISTRY.count(M.METRIC_GROUPBY_SUM_ROWS, live.size,
+                         state="summed")
+        M.REGISTRY.count(M.METRIC_GROUPBY_SUM_ROWS, n - live.size,
+                         state="skipped")
+
+        def sums(rows):
+            p, ng = pair_sums(planes, rows, mags, pos_m, neg_m)
+            return sync_part(p), ng
+
+        if live.size == n:
+            return [(live[lo:lo + last.block_rows], *sums(blk))
+                    for lo, blk in last.iter_blocks()]
+        out = []
+        for at in range(0, live.size, last.block_rows):
+            slots = live[at:at + last.block_rows]
+            out.append((slots, *sums(last.take_rows(
+                [last.row_ids[s] for s in slots],
+                pad_to=_pow2_rows(slots.size)))))
+        return out
 
     def _group_blocks(self, sts, filt, sink) -> None:
         """Feed ``sink(slots, planes)`` the group planes of the row product

@@ -55,18 +55,19 @@ def load(holder):
     return cols, slots, v
 
 
-def reference(slots, v, names, where=None, with_sum=False, limit=None):
+def reference(slots, v, names, where=None, with_sum=False, limit=None,
+              rows=ROWS):
     """[(row ids, count, sum)] of the non-empty groups in row-id order:
     a bincount over ravel_multi_index, nothing from the program."""
     sel = np.ones(v.size, dtype=bool) if where is None else where
-    dims = [len(ROWS[f]) for f in names]
+    dims = [len(rows[f]) for f in names]
     flat = np.ravel_multi_index([slots[f][sel] for f in names], dims)
     counts = np.bincount(flat, minlength=int(np.prod(dims)))
     sums = np.bincount(flat, minlength=int(np.prod(dims)),
                        weights=v[sel].astype(np.float64))
     out = []
     for g in np.flatnonzero(counts):
-        key = tuple(ROWS[f][s] for f, s in
+        key = tuple(rows[f][s] for f, s in
                     zip(names, np.unravel_index(g, dims)))
         out.append((key, int(counts[g]), int(sums[g]) if with_sum else None))
     return out[:limit]
@@ -206,6 +207,166 @@ def test_groupby_on_a_mesh_of_four_equals_the_one_device_answer(
     assert (m1 == m0).all()         # one device: never the mesh program
     assert (d4 - d1 == d1 - d0).all() and (m4 - m1 == d1 - d0).all()
     assert (f4 == f1).all()
+
+
+#: the brand-like last field of the live-row cases: 40 rows in blocks of 8
+#: when paged, a category of 8 brands each and a parity beside it
+BRAND_ROWS = {"y": ROWS["y"], "b": list(range(40))}
+#: name: (filter, brand rows it leaves live, rows of each pair_sums call,
+#: calls on gathered rows)
+LIVE = {
+    # non-adjacent rows in four blocks: one gather, padded to 8
+    "scattered": ("Union(Row(b=3), Row(b=12), Row(b=29), Row(b=38))",
+                  [3, 12, 29, 38], [8], 1),
+    # block 2 all live among dead blocks is gathered with the two rows
+    # of others: the chunks of 8 and 2 are those of any 10 live rows
+    "block-and-rows": ("Union(Row(c=2), Row(b=5), Row(b=30))",
+                       [5, *range(16, 24), 30], [8, 8], 2),
+    # 20 rows, none a whole block: chunks of 8, 8 and 4 padded to 8
+    "odd": ("Row(o=1)", list(range(1, 40, 2)), [8, 8, 8], 3),
+    # no filter: every block as it is, today's calls
+    "all": (None, list(range(40)), [8] * 5, 0),
+    # no row live: no call at all
+    "none": ("Intersect(Row(b=3), Row(b=4))", [], [], 0),
+}
+
+
+def load_brands(holder):
+    """Seeded records over SHARDS shards: a mutex brand ``b`` of 40 rows
+    with its category ``c`` (``b // 8``) and parity ``o``, the year ``y``
+    as :func:`rides` has it, and an int ``v``."""
+    rng = np.random.default_rng(41)
+    n = SHARDS * PER_SHARD
+    place = np.arange(n)
+    cols = (place // PER_SHARD) * SHARD_WIDTH + place % PER_SHARD
+    b = rng.integers(0, 40, n)
+    slots = {"y": np.minimum(place * 4 // n, 3), "b": b}
+    v = rng.integers(-40, 500, n)
+    idx = holder.create_index("t")
+    for name, ids in (("y", [ROWS["y"][s] for s in slots["y"]]),
+                      ("b", b), ("c", b // 8), ("o", b % 2)):
+        opts = FieldOptions(type=FieldType.MUTEX) if name == "b" else None
+        idx.create_field(name, opts).import_bits(
+            np.asarray(ids).tolist(), cols.tolist())
+    idx.create_field("v", FieldOptions(type=FieldType.INT)).set_values(
+        cols.tolist(), v.tolist())
+    return slots, v
+
+
+def _live_query(case):
+    filt, live, *_ = LIVE[case]
+    text = "GroupBy(Rows(y), Rows(b)"
+    if filt is not None:
+        text += f", filter={filt}"
+    return text + ", aggregate=Sum(field=v))", live
+
+
+def _sum_rows():
+    return np.array([M.REGISTRY.value(M.METRIC_GROUPBY_SUM_ROWS, state=k)
+                     for k in ("summed", "skipped")])
+
+
+@pytest.mark.parametrize("form", ["dense", "keyed"])
+@pytest.mark.parametrize("case", sorted(LIVE))
+def test_a_sum_groupby_sums_the_live_rows_of_its_last_field(
+        case, form, monkeypatch, per_device):
+    """A dense two-field Sum counts first and runs pair_sums over the
+    rows of the last field that some group holds: the blocks as they
+    are where every row is live, else the live rows gathered (on a
+    key-plane stack, derived by ``key_rows_at`` alone) in chunks of at
+    most a block padded to a power of two of 8 or more. The answer is
+    the numpy reference's and the fold's; dead rows are counted as
+    skipped and cost no call."""
+    from pilosa_tpu.ops import keyrows as K
+
+    text, live = _live_query(case)
+    *_, calls, gathers = LIVE[case]
+    monkeypatch.setattr(stx, "_BLOCK_BYTES", per_device(4 << 20))
+    # the brand stack is 40 rows x 384 KB: a budget under that holds it
+    # as key planes
+    budget = (14 if form == "keyed" else 64) << 20
+    monkeypatch.setattr(stx, "BUDGET", stx.DeviceBudget(per_device(budget)))
+    h = Holder()
+    slots, v = load_brands(h)
+    seen, derived = [], []
+
+    def pair_sums(a, b, *rest):
+        seen.append((a.shape[0], b.shape[0]))
+        return real_sums(a, b, *rest)
+
+    def key_rows_at(keys, at):
+        derived.append(len(at))
+        return real_at(keys, at)
+
+    real_sums, real_at = ex.pair_sums, K.key_rows_at
+    monkeypatch.setattr(ex, "pair_sums", pair_sums)
+    monkeypatch.setattr(K, "key_rows_at", key_rows_at)
+    rows0 = _sum_rows()
+    tracer = T.Tracer(enabled=True, sample_rate=1.0)
+    prev = T.get_tracer()
+    T.set_tracer(tracer)
+    try:
+        with tracer.start_trace("query.pql") as root:
+            got = answer(Executor(h).execute("t", text)[0])
+    finally:
+        T.set_tracer(prev)
+    st = stx.stacked_set(h.index("t").field("b"), list(range(SHARDS)),
+                         "standard")
+    assert type(st) is (stx.KeyedSet if form == "keyed" else stx.StackedSet)
+    assert (st.block_rows, st.n_blocks) == (8, 5)
+    assert root.tags["groupby_route"] == "dense"
+    want = reference(slots, v, ("y", "b"), np.isin(slots["b"], live), True,
+                     rows=BRAND_ROWS)
+    assert got == want and (len(got) > 1) == bool(live)
+    assert [b for _, b in seen] == calls and len({a for a, _ in seen}) <= 1
+    assert (_sum_rows() - rows0).tolist() == [len(live), 40 - len(live)]
+    # the gathered rows alone are derived a chunk at a time (a filter's
+    # Row(b=..) derives its one row)
+    assert derived.count(8) == (gathers if form == "keyed" else 0)
+    monkeypatch.setattr(ex, "_DENSE_MAX_CELLS", 2)
+    assert answer(Executor(h).execute("t", text)[0]) == want
+
+
+@pytest.mark.parametrize("case", ["scattered", "block-and-rows"])
+def test_live_rows_gathered_on_a_mesh_of_four_keep_the_mesh_route(
+        case, pallas_as_compiled, monkeypatch):
+    """On an engine mesh of four devices the gathered rows keep the
+    stack's word sharding (whether they come from one block or several),
+    so every pair_sums call takes the kernel's mesh route: the
+    one-device answer, and one mesh dispatch a call."""
+    import jax
+
+    from pilosa_tpu.parallel import mesh as PM
+
+    text, live = _live_query(case)
+    calls = len(LIVE[case][2])
+    h = Holder()
+    slots, v = load_brands(h)
+
+    def ticks():
+        return np.array([M.REGISTRY.value(name, kernel="pair_sums", **lbl)
+                         for name, lbl in (
+                             (M.METRIC_OPS_PALLAS_DISPATCH, {}),
+                             (M.METRIC_OPS_PALLAS_MESH_DISPATCH, {}),
+                             (M.METRIC_OPS_PALLAS_FALLBACK, {"why": "mesh"}),
+                             (M.METRIC_OPS_PALLAS_FALLBACK,
+                              {"why": "error"}))])
+
+    answers = []
+    try:
+        for devices in (1, 4):
+            PM.set_engine_mesh(PM.analytics_mesh(jax.devices()[:devices]))
+            # blocks of 8 brand rows on either mesh
+            monkeypatch.setattr(stx, "_BLOCK_BYTES", (4 << 20) // devices)
+            t0 = ticks()
+            answers.append(answer(Executor(h).execute("t", text)[0]))
+            assert (ticks() - t0).tolist() == [
+                calls, calls if devices == 4 else 0, 0, 0]
+    finally:
+        PM.set_engine_mesh(None)
+    assert answers[0] == answers[1] == reference(
+        slots, v, ("y", "b"), np.isin(slots["b"], live), True,
+        rows=BRAND_ROWS)
 
 
 def _profiled(api, text):

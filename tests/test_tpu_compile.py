@@ -146,6 +146,11 @@ def test_key_rows_compiles_for_blocks_taller_than_sf10s(one_chip, rows, words):
 @pytest.mark.parametrize("r2,words", [
     (32, 58 * 32768),   # SF-10: a derived 32-row block of the brand stack
     (256, SSB_WORDS),   # SF-1: a 256-row block
+    # the live brand rows gathered: Q2.1's last chunk and Q2.2-Q4.1's at
+    # SF-10, Q2.1's 40 rows padded to 64 and the others' 8 at SF-1
+    (8, 58 * 32768),
+    (64, SSB_WORDS),
+    (8, SSB_WORDS),
 ])
 def test_pair_sums_masks_each_plane_inside_the_kernel(one_chip, r2, words):
     """A Q2.x Sum's ``pair_sums`` call (8 years, 17 magnitude planes):
@@ -173,8 +178,12 @@ def test_pair_sums_masks_each_plane_inside_the_kernel(one_chip, r2, words):
     calls = [ln for ln in body.splitlines() if "tpu_custom_call" in ln]
     assert len(calls) == 1, calls
     layouts = calls[0].split("operand_layout_constraints=")[1].split("}}")[0]
+    # the kernel lists the shorter operand first, and masks that one: a
+    # plane ANDed into either side counts the same pairs
+    short, tall = sorted((16, r2))
     assert re.findall(r"u32\[(\d+),(\d+)\]", layouts) == [
-        ("16", str(words)), (str(r2), str(words)), ("17", str(words))]
+        (str(short), str(words)), (str(tall), str(words)),
+        ("17", str(words))]
     made = [ln.strip() for ln in body.splitlines()
             if re.search(rf"= u32\[16,{words}\]", ln)
             and "get-tuple-element(" not in ln]
